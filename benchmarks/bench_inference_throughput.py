@@ -29,10 +29,11 @@ dispatch amortization + batched FFT, the big win from the codesign fold):
 - ``latency_under_load``: p50/p99 submit-to-result latency of the
   continuous-batching fleet under open-loop Poisson arrivals at ~50% of
   measured capacity (the open-loop complement to the closed-loop rows).
-- ``multi_device``: subprocess on a forced 4-device host platform —
-  dp=4 engine vs single-device engine outputs (rtol <= 1e-5) and req/s
-  (host devices oversubscribe 2 cores, so scaling is not expected to be
-  linear *here*; the row pins layout correctness + agreement).
+- ``multi_device``: batch-DP engine vs single-device engine outputs
+  (rtol <= 1e-5) and req/s — on CPU in a subprocess with 4 forced host
+  devices (which oversubscribe the cores, so scaling is not expected to
+  be linear there; the row pins layout correctness + agreement), on TPU
+  in-process over the chips present.
 
 Every family checks frozen outputs bit-identical to the training-path
 (eval) forward.  Rows persist to
@@ -42,21 +43,17 @@ Every family checks frozen outputs bit-identical to the training-path
 """
 from __future__ import annotations
 
-import json
 import os
-import pathlib
-import subprocess
-import sys
 import time
 
 import jax
 import numpy as np
 
-from benchmarks.common import row, write_bench_json
+from benchmarks.common import (
+    mesh_dims, row, run_multi_device_cell, write_bench_json,
+)
 from repro.core import DONNConfig, build_model
 from repro.runtime.inference import InferenceEngine, MicroBatcher, freeze
-
-REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
 def _requests(count, shape, seed=0):
@@ -288,22 +285,8 @@ def _bench_latency_under_load(rows) -> dict:
             "capacity_rps": round(cap_rps, 1)}
 
 
-def _bench_multi_device(rows) -> dict:
-    """dp=4 vs single device in a forced-4-device subprocess."""
-    code = """
-import json, time
-import jax, numpy as np
-from repro.core import DONNConfig, build_model
-from repro.runtime.inference import freeze, InferenceEngine
-
-cfg = DONNConfig(name="inf-dp", n=64, depth=8, distance=0.05, det_size=8,
-                 codesign="qat")
-model = build_model(cfg)
-params = model.init(jax.random.PRNGKey(0))
-dep = freeze(model, params)
-reqs = np.random.default_rng(3).random((64, 28, 28), np.float32)
-
-def loop(engine, bucket=32):
+def _serving_loop(engine, reqs, bucket) -> float:
+    """Best-of-2 closed-loop req/s through a warmed engine."""
     engine.warmup()
     best = None
     for _ in range(2):
@@ -314,98 +297,90 @@ def loop(engine, bucket=32):
         best = dt if best is None else min(best, dt)
     return reqs.shape[0] / best
 
-e1 = InferenceEngine(dep, buckets=(32,))
-e4 = InferenceEngine(dep, buckets=(32,), mesh_devices=4, dp_min_bucket=8)
-rps1, rps4 = loop(e1), loop(e4)
-a, b = e1.infer(reqs[:32]), e4.infer(reqs[:32])
-rel = float(np.max(np.abs(a - b)) / np.max(np.abs(a)))
-print("RESULT " + json.dumps({"rps_single": rps1, "rps_dp4": rps4,
-                              "rel_err": rel}))
-"""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = str(REPO / "src")
-    r = subprocess.run([sys.executable, "-c", code], env=env,
-                       capture_output=True, text=True, timeout=560)
-    if r.returncode != 0:
-        raise RuntimeError(f"multi-device cell failed:\n{r.stderr}")
-    res = json.loads(r.stdout.split("RESULT ")[1])
+
+def multi_device_cell(n_devices: int) -> dict:
+    """Batch-DP engine over ``n_devices`` vs the single-device engine."""
+    assert jax.device_count() >= n_devices, jax.device_count()
+    cfg = DONNConfig(name="inf-dp", n=64, depth=8, distance=0.05,
+                     det_size=8, codesign="qat")
+    model = build_model(cfg)
+    dep = freeze(model, model.init(jax.random.PRNGKey(0)))
+    reqs = _requests(64, (28, 28), seed=3)
+    e1 = InferenceEngine(dep, buckets=(32,))
+    edp = InferenceEngine(dep, buckets=(32,), mesh_devices=n_devices,
+                          dp_min_bucket=8)
+    rps1, rpsdp = _serving_loop(e1, reqs, 32), _serving_loop(edp, reqs, 32)
+    a, b = e1.infer(reqs[:32]), edp.infer(reqs[:32])
+    rel = float(np.max(np.abs(a - b)) / np.max(np.abs(a)))
+    return {"rps_single": rps1, "rps_dp": rpsdp, "rel_err": rel,
+            "devices": n_devices}
+
+
+def _bench_multi_device(rows) -> dict:
+    """Batch-DP engine vs single device (4 forced host devices on CPU)."""
+    res = run_multi_device_cell("benchmarks.bench_inference_throughput",
+                                "multi_device_cell", cpu_devices=4)
     ok = res["rel_err"] <= 1e-5
-    name = "infer/multi_device/dp4_vs_single"
+    nd = res["devices"]
+    name = f"infer/multi_device/dp{nd}_vs_single"
     derived = (f"rps_single={res['rps_single']:.1f},"
-               f"rps_dp4={res['rps_dp4']:.1f},"
+               f"rps_dp{nd}={res['rps_dp']:.1f},"
                f"rel_err={res['rel_err']:.2e},within_1e-5={ok},"
-               "host_devices=4_on_2_cores")
-    row(name, 1e6 / res["rps_dp4"], derived)
-    rows.append({"name": name, "us": 1e6 / res["rps_dp4"],
+               f"devices={nd},backend={jax.default_backend()}")
+    row(name, 1e6 / res["rps_dp"], derived)
+    rows.append({"name": name, "us": 1e6 / res["rps_dp"],
                  "derived": derived})
     if not ok:
-        raise AssertionError(f"dp4 rel err {res['rel_err']} > 1e-5")
+        raise AssertionError(f"dp{nd} rel err {res['rel_err']} > 1e-5")
     return {"rel_err": res["rel_err"],
             "rps_single": round(res["rps_single"], 1),
-            "rps_dp4": round(res["rps_dp4"], 1)}
+            f"rps_dp{nd}": round(res["rps_dp"], 1)}
+
+
+def sharded_serving_cell(n_devices: int) -> dict:
+    """Row-sharded (data x model) engine vs the single-device engine."""
+    assert jax.device_count() >= n_devices, jax.device_count()
+    data, mp = mesh_dims(n_devices)
+    cfg = DONNConfig(name="inf-mp", n=256, depth=4, det_size=16,
+                     codesign="qat")
+    model = build_model(cfg)
+    dep = freeze(model, model.init(jax.random.PRNGKey(0)))
+    reqs = _requests(32, (28, 28), seed=5)
+    e1 = InferenceEngine(dep, buckets=(8,))
+    emp = InferenceEngine(dep, buckets=(8,), mesh_devices=data,
+                          model_devices=mp, dp_min_bucket=8)
+    rps1, rpsmp = _serving_loop(e1, reqs, 8), _serving_loop(emp, reqs, 8)
+    a, b = e1.infer(reqs[:8]), emp.infer(reqs[:8])
+    rel = float(np.max(np.abs(a - b)) / np.max(np.abs(a)))
+    bit = bool(np.array_equal(b, emp.infer(reqs[:8])))
+    return {"rps_single": rps1, "rps_sharded": rpsmp, "rel_err": rel,
+            "bit_consistent": bit, "mesh": [data, mp],
+            "rows_per_device": cfg.n // mp}
 
 
 def _bench_sharded_serving(rows) -> dict:
-    """Row-sharded serving: 2-data x 4-model mesh vs single device.
+    """Row-sharded serving: (data x model) mesh vs single device.
 
-    ``InferenceEngine(model_devices=4)`` shards the frozen modulation
+    ``InferenceEngine(model_devices=k)`` shards the frozen modulation
     stacks, TF planes and detector masks over the ``model`` axis (each
-    device serves from a quarter-plane pencil, pencil-FFT hops) while
+    device serves from a 1/k-plane pencil, pencil-FFT hops) while
     buckets >= ``dp_min_bucket`` also shard the batch over ``data`` —
-    the ISSUE-10 serving row.  Checks rtol <= 1e-5 vs the single-device
-    engine and bit-consistency across repeated sharded calls.
+    the ISSUE-10 serving row (2 data x 4 model on 8 forced host devices
+    on CPU; the chips present on TPU).  Checks rtol <= 1e-5 vs the
+    single-device engine and bit-consistency across repeated sharded
+    calls.
     """
-    code = """
-import json, time
-import jax, numpy as np
-from repro.core import DONNConfig, build_model
-from repro.runtime.inference import freeze, InferenceEngine
-
-assert jax.device_count() == 8, jax.device_count()
-cfg = DONNConfig(name="inf-mp", n=256, depth=4, det_size=16,
-                 codesign="qat")
-model = build_model(cfg)
-params = model.init(jax.random.PRNGKey(0))
-dep = freeze(model, params)
-reqs = np.random.default_rng(5).random((32, 28, 28), np.float32)
-
-def loop(engine, bucket=8):
-    engine.warmup()
-    best = None
-    for _ in range(2):
-        t0 = time.perf_counter()
-        for lo in range(0, reqs.shape[0], bucket):
-            engine.infer(reqs[lo:lo + bucket])
-        dt = time.perf_counter() - t0
-        best = dt if best is None else min(best, dt)
-    return reqs.shape[0] / best
-
-e1 = InferenceEngine(dep, buckets=(8,))
-emp = InferenceEngine(dep, buckets=(8,), mesh_devices=2, model_devices=4,
-                      dp_min_bucket=8)
-rps1, rpsmp = loop(e1), loop(emp)
-a, b = e1.infer(reqs[:8]), emp.infer(reqs[:8])
-rel = float(np.max(np.abs(a - b)) / np.max(np.abs(a)))
-bit = bool(np.array_equal(b, emp.infer(reqs[:8])))
-print("RESULT " + json.dumps({"rps_single": rps1, "rps_sharded": rpsmp,
-                              "rel_err": rel, "bit_consistent": bit}))
-"""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = str(REPO / "src")
-    r = subprocess.run([sys.executable, "-c", code], env=env,
-                       capture_output=True, text=True, timeout=560)
-    if r.returncode != 0:
-        raise RuntimeError(f"sharded-serving cell failed:\n{r.stderr}")
-    res = json.loads(r.stdout.split("RESULT ")[1])
+    res = run_multi_device_cell("benchmarks.bench_inference_throughput",
+                                "sharded_serving_cell", cpu_devices=8)
     ok = res["rel_err"] <= 1e-5 and res["bit_consistent"]
-    name = "infer/sharded_serving/2data_x_4model_vs_single"
+    data, mp = res["mesh"]
+    name = f"infer/sharded_serving/{data}data_x_{mp}model_vs_single"
     derived = (f"rps_single={res['rps_single']:.1f},"
                f"rps_sharded={res['rps_sharded']:.1f},"
                f"rel_err={res['rel_err']:.2e},"
                f"bit_consistent={res['bit_consistent']},n=256,"
-               "rows_per_device=64,host_devices=8")
+               f"rows_per_device={res['rows_per_device']},"
+               f"backend={jax.default_backend()}")
     row(name, 1e6 / res["rps_sharded"], derived)
     rows.append({"name": name, "us": 1e6 / res["rps_sharded"],
                  "derived": derived})

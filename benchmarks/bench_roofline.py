@@ -4,10 +4,10 @@ For every tier-1 bench suite this builds one *representative* jitted
 computation (the suite's steady-state hot program, at reduced problem
 size where the full protocol would be slow), compiles it, and reads the
 XLA cost model off the compiled executable
-(``repro.compat.compiled_cost_analysis``: ``flops`` and ``bytes
-accessed``).  Dividing by measured wall time gives achieved GFLOP/s and
-GB/s; dividing those by *measured* machine peaks gives the roofline
-fraction and which roof (compute vs memory) the cell sits under.
+(``Compiled.cost_analysis()``: ``flops`` and ``bytes accessed``).
+Dividing by measured wall time gives achieved GFLOP/s and GB/s; dividing
+those by *measured* machine peaks gives the roofline fraction and which
+roof (compute vs memory) the cell sits under.
 
 Peaks are calibrated live by two microbenchmarks — a large f32 matmul
 (compute roof) and a large strided saxpy (memory roof) — on the same
@@ -30,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import row, time_fn, write_bench_json
-from repro.compat import compiled_cost_analysis
 from repro.core import DONNConfig, LayerSpec, build_model
 from repro.core.train_utils import mse_softmax_loss
 from repro.kernels import ops as kops
@@ -176,7 +175,7 @@ CELLS = (
 def _bench_cell(name: str, make, peaks: dict, rows: list) -> dict:
     fn, args = make()
     compiled = jax.jit(fn).lower(*args).compile()
-    cost = compiled_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     us = min(time_fn(compiled, *args, warmup=2, iters=5) for _ in range(3))
     flops = cost.get("flops")
     nbytes = cost.get("bytes accessed")

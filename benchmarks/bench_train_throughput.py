@@ -35,18 +35,15 @@ practice bit-exact).  Rows persist to
 """
 from __future__ import annotations
 
-import json
-import os
-import pathlib
-import subprocess
-import sys
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import row, write_bench_json
+from benchmarks.common import (
+    mesh_dims, row, run_multi_device_cell, write_bench_json,
+)
 from repro.core import DONNConfig, build_model
 from repro.core.train_utils import (
     accuracy, make_train_chunk, mse_softmax_loss,
@@ -54,8 +51,6 @@ from repro.core.train_utils import (
 from repro.data import batch_iterator, synth_digits, synth_seg
 from repro.data.pipeline import device_prefetch, stack_batches
 from repro.optim import AdamW
-
-REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
 def _seed_style_step(model, optimizer, num_classes: int,
@@ -255,67 +250,62 @@ def _bench_rng_codesign(rows: list) -> dict:
             "speedup": round(dt_ref / dt_new, 3)}
 
 
+def large_plane_cell(n_devices: int) -> dict:
+    """Train the n=1024 plane on a (data, model) mesh of ``n_devices``."""
+    from repro.nn import init_params
+    from repro.runtime import donn_steps as ds
+    from repro.runtime import sharding as shd
+
+    assert jax.device_count() >= n_devices, jax.device_count()
+    data, model = mesh_dims(n_devices)
+    cfg = DONNConfig(name="tt-1024", n=1024, depth=2, det_size=64)
+    mesh = shd.make_mesh_2d(data=data, model=model)
+    B = 4
+    fn, s_shard, b_shard, sspecs = ds.compile_donn_train_step_sharded(
+        cfg, mesh, optimizer=AdamW(lr=0.1), global_batch=B)
+    state = jax.device_put(init_params(sspecs, jax.random.PRNGKey(0)),
+                           s_shard)
+    r = np.random.default_rng(0)
+    batch = jax.device_put(
+        {"images": r.random((B, 28, 28)).astype(np.float32),
+         "labels": r.integers(0, 10, (B,)).astype(np.int32)}, b_shard)
+    state, m = fn(state, batch)  # compile + warm
+    jax.block_until_ready(state)
+    losses, steps = [float(m["loss"])], 2
+    t0 = time.perf_counter()
+    for _ in range(steps):
+        state, m = fn(state, batch)
+        losses.append(float(m["loss"]))
+    dt = time.perf_counter() - t0
+    return {"steps_per_sec": steps / dt, "losses": losses,
+            "mesh": [data, model], "rows_per_device": cfg.n // model,
+            "finite": bool(np.all(np.isfinite(losses)))}
+
+
 def _bench_large_plane(rows: list) -> dict:
-    """n=1024 plane, 4-way-spatial x 2-way-data on 8 forced host devices.
+    """n=1024 plane, spatial x data-parallel on a 2-D mesh.
 
     The ISSUE-10 acceptance cell: a field too large for one chip's plane
     budget trains through ``compile_donn_train_step_sharded`` on the 2-D
-    ``(data, model)`` mesh — each device holds a 256-row pencil of every
-    1024^2 plane (fields, TF stacks, phases, optimizer moments).  The
-    single-device row is recorded as skipped: at the per-chip budget this
-    cell models (1/4 of the plane stack per device), no single device can
-    materialize the full 1024^2 TF + phase + moment stacks, so the
-    sharded path is the only runnable one.
+    ``(data, model)`` mesh — on 8 forced host devices (2 data x 4 model)
+    each device holds a 256-row pencil of every 1024^2 plane (fields, TF
+    stacks, phases, optimizer moments); on TPU the mesh spans the chips
+    present.  The single-device row is recorded as skipped: at the
+    per-chip budget this cell models (1/4 of the plane stack per device),
+    no single device can materialize the full 1024^2 TF + phase + moment
+    stacks, so the sharded path is the only runnable one.
     """
-    code = """
-import json, time
-import jax, numpy as np
-from repro.core import DONNConfig
-from repro.nn import init_params
-from repro.optim import AdamW
-from repro.runtime import donn_steps as ds
-from repro.runtime import sharding as shd
-
-assert jax.device_count() == 8, jax.device_count()
-cfg = DONNConfig(name="tt-1024", n=1024, depth=2, det_size=64)
-mesh = shd.make_mesh_2d(data=2, model=4)
-B = 4
-fn, s_shard, b_shard, sspecs = ds.compile_donn_train_step_sharded(
-    cfg, mesh, optimizer=AdamW(lr=0.1), global_batch=B)
-state = jax.device_put(init_params(sspecs, jax.random.PRNGKey(0)), s_shard)
-r = np.random.default_rng(0)
-batch = jax.device_put(
-    {"images": r.random((B, 28, 28)).astype(np.float32),
-     "labels": r.integers(0, 10, (B,)).astype(np.int32)}, b_shard)
-state, m = fn(state, batch)  # compile + warm
-jax.block_until_ready(state)
-losses, steps = [float(m["loss"])], 2
-t0 = time.perf_counter()
-for _ in range(steps):
-    state, m = fn(state, batch)
-    losses.append(float(m["loss"]))
-dt = time.perf_counter() - t0
-rows_dev = cfg.n // mesh.shape["model"]
-print("RESULT " + json.dumps({
-    "steps_per_sec": steps / dt, "losses": losses,
-    "rows_per_device": rows_dev,
-    "finite": bool(np.all(np.isfinite(losses)))}))
-"""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = str(REPO / "src")
-    r = subprocess.run([sys.executable, "-c", code], env=env,
-                       capture_output=True, text=True, timeout=560)
-    if r.returncode != 0:
-        raise RuntimeError(f"large-plane cell failed:\n{r.stderr}")
-    res = json.loads(r.stdout.split("RESULT ")[1])
+    res = run_multi_device_cell("benchmarks.bench_train_throughput",
+                                "large_plane_cell", cpu_devices=8)
     if not res["finite"]:
         raise AssertionError(f"non-finite losses: {res['losses']}")
     sps = res["steps_per_sec"]
-    name = "train/large_plane_n1024/sharded_2x4"
-    derived = (f"steps_per_sec={sps:.3f},mesh=2data_x_4model,n=1024,"
-               f"depth=2,batch=4,rows_per_device={res['rows_per_device']},"
-               f"finite={res['finite']},host_devices=8")
+    data, model = res["mesh"]
+    name = f"train/large_plane_n1024/sharded_{data}x{model}"
+    derived = (f"steps_per_sec={sps:.3f},mesh={data}data_x_{model}model,"
+               f"n=1024,depth=2,batch=4,"
+               f"rows_per_device={res['rows_per_device']},"
+               f"finite={res['finite']},backend={jax.default_backend()}")
     row(name, 1e6 / sps, derived)
     rows.append({"name": name, "us": 1e6 / sps, "derived": derived})
     name1 = "train/large_plane_n1024/single_device"
@@ -325,7 +315,7 @@ print("RESULT " + json.dumps({
                 "sharded path runs")
     row(name1, 0.0, derived1)
     rows.append({"name": name1, "us": 0.0, "derived": derived1})
-    return {"steps_per_sec": round(sps, 3), "mesh": "2x4",
+    return {"steps_per_sec": round(sps, 3), "mesh": f"{data}x{model}",
             "rows_per_device": res["rows_per_device"],
             "single_device": "skipped"}
 

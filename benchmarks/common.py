@@ -7,13 +7,18 @@ tooling can diff numbers across PRs.
 """
 from __future__ import annotations
 
+import importlib
 import json
+import os
 import pathlib
+import subprocess
+import sys
 import time
 
 import jax
 
-ARTIFACTS = pathlib.Path(__file__).resolve().parent.parent / "artifacts" / "bench"
+REPO = pathlib.Path(__file__).resolve().parent.parent
+ARTIFACTS = REPO / "artifacts" / "bench"
 
 
 def time_fn(fn, *args, warmup: int = 2, iters: int = 5) -> float:
@@ -51,3 +56,32 @@ def write_bench_json(suite: str, rows: list, meta: dict | None = None):
     ))
     print(f"# wrote {path}", flush=True)
     return path
+
+
+def mesh_dims(n_devices: int) -> tuple:
+    """(data, model) split of ``n_devices``: model takes half, data the rest."""
+    model = max(n_devices // 2, 1)
+    return n_devices // model, model
+
+
+def run_multi_device_cell(module: str, fn: str, cpu_devices: int) -> dict:
+    """Run ``module.fn(n_devices) -> dict`` for a cell that needs a mesh.
+
+    On TPU the cell runs in this process on the real chips: this process
+    already holds them, so a child process could not reach them.  On CPU
+    it runs in a child with ``cpu_devices`` forced host devices, because
+    the host device count is fixed when the backend starts.
+    """
+    if jax.default_backend() == "tpu":
+        return getattr(importlib.import_module(module), fn)(jax.device_count())
+    code = (f"import json; from {module} import {fn}; "
+            f"print('RESULT ' + json.dumps({fn}({cpu_devices})))")
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={cpu_devices}"
+    env["PYTHONPATH"] = os.pathsep.join((str(REPO / "src"), str(REPO)))
+    env["JAX_PLATFORMS"] = "cpu"
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=560)
+    if r.returncode != 0:
+        raise RuntimeError(f"{module}.{fn} failed:\n{r.stderr}")
+    return json.loads(r.stdout.split("RESULT ")[1])

@@ -78,6 +78,9 @@ def write_summary(started_at: float, failed: list) -> pathlib.Path:
 
 
 def main() -> None:
+    from repro.core.cache import use_persistent_compile_cache
+
+    use_persistent_compile_cache()
     from benchmarks import (
         bench_dse,
         bench_dse_batched,

@@ -1,118 +1,21 @@
-"""Version-compatibility layer over the JAX surface this repo uses.
+"""The one JAX spelling this repo pins: all-Auto mesh axes.
 
-The codebase targets the newest JAX API names (``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh(..., axis_types=...)``,
-``Compiled.cost_analysis()`` returning a flat dict).  Older releases --
-notably 0.4.x, which the container ships -- spell these differently:
-
-- ``shard_map`` lives in ``jax.experimental.shard_map`` and its replication
-  check is called ``check_rep`` instead of ``check_vma``;
-- ``jax.make_mesh`` has no ``axis_types`` parameter and
-  ``jax.sharding.AxisType`` does not exist;
-- ``Compiled.cost_analysis()`` returns a one-element *list* of dicts
-  (one per partition) rather than the dict itself.
-
-Everything here degrades gracefully: on a new JAX the wrappers are thin
-pass-throughs, on an old one they translate.  All repo code (and the
-subprocess test suites) should import these names instead of reaching for
-``jax.*`` directly.
+The installed JAX (0.9.x) has ``jax.shard_map``, ``jax.lax.axis_size``
+and a dict-valued ``Compiled.cost_analysis()``, so the code calls those
+directly.  What it changed is the default of ``jax.make_mesh``: its axes
+are now ``AxisType.Explicit``, under which sharding-in-types rejects
+gathers and contractions that GSPMD partitions by itself.  Every mesh in
+this repo wants all-Auto axes, so ``make_mesh`` asks for them.
 """
 from __future__ import annotations
 
-import inspect
-from typing import Any, Optional, Sequence
+from typing import Sequence
 
 import jax
-
-# ``AxisType`` only exists on newer JAX; None signals "not supported".
-AxisType = getattr(jax.sharding, "AxisType", None)
-
-_NEW_SHARD_MAP = getattr(jax, "shard_map", None)
-if _NEW_SHARD_MAP is None:
-    from jax.experimental.shard_map import shard_map as _OLD_SHARD_MAP
-else:
-    _OLD_SHARD_MAP = None
+from jax.sharding import AxisType
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: Optional[bool] = None,
-              **kwargs):
-    """``jax.shard_map`` with the ``check_vma`` knob on every JAX version.
-
-    On old JAX the knob is forwarded as ``check_rep`` (its former name).
-    """
-    if _NEW_SHARD_MAP is not None:
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return _NEW_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kwargs)
-    if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    return _OLD_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
-
-
-_MAKE_MESH_PARAMS = inspect.signature(jax.make_mesh).parameters
-
-
-def _resolve_axis_types(axis_types: Sequence[Any]):
-    """Map "auto"/"explicit"/"manual" strings (or AxisType members) to enums."""
-    if AxisType is None:
-        return None
-    out = []
-    for t in axis_types:
-        if isinstance(t, str):
-            t = getattr(AxisType, t.capitalize())
-        out.append(t)
-    return tuple(out)
-
-
-def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
-              axis_types: Optional[Sequence[Any]] = None, **kwargs):
-    """``jax.make_mesh`` that tolerates a missing ``axis_types`` parameter.
-
-    ``axis_types`` entries may be ``jax.sharding.AxisType`` members or the
-    strings "auto" / "explicit" / "manual"; on JAX versions without mesh
-    axis types the argument is dropped (those versions behave as all-Auto,
-    which is what every call site here wants).
-    """
-    if axis_types is not None and "axis_types" in _MAKE_MESH_PARAMS:
-        resolved = _resolve_axis_types(axis_types)
-        if resolved is not None:
-            kwargs["axis_types"] = resolved
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-
-
-def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` with a psum fallback for JAX versions without it.
-
-    Must be called under a manual axis binding (shard_map / pmap), like the
-    real thing.
-    """
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def compiled_cost_analysis(compiled) -> dict:
-    """Normalized ``Compiled.cost_analysis()``: always a flat dict.
-
-    Some JAX versions return a list with one dict per partition; single-
-    partition programs get a one-element list.  Multi-partition lists are
-    summed key-wise (keys are additive cost counters).
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, dict):
-        return ca
-    if not ca:
-        return {}
-    if len(ca) == 1:
-        return dict(ca[0])
-    out: dict = {}
-    for part in ca:
-        for k, v in part.items():
-            if isinstance(v, (int, float)):
-                out[k] = out.get(k, 0) + v
-            else:
-                out.setdefault(k, v)
-    return out
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names))

@@ -173,7 +173,8 @@ class Detector:
 
             return kops.intensity_readout(u.real, u.imag, jnp.asarray(self.masks))
         inten = df.intensity(u)
-        return jnp.einsum("...hw,chw->...c", inten, jnp.asarray(self.masks))
+        return jnp.einsum("...hw,chw->...c", inten, jnp.asarray(self.masks),
+                          precision=df.READOUT_PRECISION)
 
     def intensity_image(self, u: jax.Array) -> jax.Array:
         return df.intensity(u)

@@ -44,7 +44,8 @@ def channel_readout(u: jax.Array, masks, use_pallas: bool) -> jax.Array:
         from repro.kernels import ops as kops
 
         return kops.channel_intensity_readout(u.real, u.imag, masks)
-    return jnp.einsum("...dhw,chw->...c", df.intensity(u), masks)
+    return jnp.einsum("...dhw,chw->...c", df.intensity(u), masks,
+                      precision=df.READOUT_PRECISION)
 
 
 def _build_layers(cfg: DONNConfig, gamma: float):

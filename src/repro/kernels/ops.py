@@ -2,8 +2,9 @@
 
 Handles: zero-padding to block multiples (zero is the identity element for
 every kernel here), block-size selection (128-lane / 8-sublane alignment),
-broadcasting, and backend dispatch (interpret=True off-TPU so the kernels
-are exercised everywhere; compiled Mosaic path on TPU).
+broadcasting, and backend dispatch (compiled Mosaic path on TPU,
+interpret mode on CPU so the tests exercise the kernels; any other backend
+is refused rather than silently interpreted).
 """
 from __future__ import annotations
 
@@ -21,7 +22,16 @@ from repro.kernels import ref
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on TPU or interpreted on CPU; the "
+        f"{backend!r} backend is neither (build the config with "
+        f"use_pallas=False)"
+    )
 
 
 def _ceil_to(x: int, m: int) -> int:

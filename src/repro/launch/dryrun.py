@@ -11,7 +11,6 @@ import time
 
 import jax.numpy as jnp
 
-from repro.compat import compiled_cost_analysis
 from repro.configs import DONN_ARCHS, LM_ARCHS
 from repro.core.config import DONNConfig
 from repro.launch import mesh as mesh_mod
@@ -150,7 +149,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: pathlib.Path,
 
     mem = compiled.memory_analysis()
     print(mem)  # proves it fits (per-device bytes)
-    xla_cost = compiled_cost_analysis(compiled)
+    xla_cost = compiled.cost_analysis()
     print({k: xla_cost[k] for k in ("flops", "bytes accessed") if k in xla_cost})
     hlo = analyze(compiled.as_text())
 

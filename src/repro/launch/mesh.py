@@ -22,8 +22,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     return _compat_make_mesh(shape, axes)
 
 
-def make_mesh(shape, axes, axis_types=None):
-    return _compat_make_mesh(shape, axes, axis_types=axis_types)
+def make_mesh(shape, axes):
+    return _compat_make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):

@@ -39,6 +39,7 @@ import jax
 import numpy as np
 
 from repro.core import DONNConfig, build_model
+from repro.core.cache import use_persistent_compile_cache
 from repro.runtime.inference import (
     DEFAULT_BUCKETS, InferenceEngine, MicroBatcher, freeze,
 )
@@ -63,6 +64,7 @@ def build_cfg(args) -> DONNConfig:
 
 
 def main(argv=None):
+    use_persistent_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--family", default="classify",
                     choices=("classify", "rgb", "segmentation"))

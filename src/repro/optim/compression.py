@@ -16,8 +16,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 BLOCK = 2048
 
 
@@ -56,7 +54,7 @@ def compressed_psum_mean(x: jax.Array, axis_name: str) -> jax.Array:
     Must run inside shard_map with ``axis_name`` a manual axis.  Payload:
     int8 blocks + f32 scales (~ x.nbytes/4 + x.nbytes/(4*BLOCK)).
     """
-    g = compat.axis_size(axis_name)
+    g = jax.lax.axis_size(axis_name)
     q, scale, n = quantize_int8(x)
     qs = jax.lax.all_gather(q, axis_name)  # (g, nb, BLOCK) int8
     ss = jax.lax.all_gather(scale, axis_name)  # (g, nb)

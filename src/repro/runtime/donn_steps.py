@@ -167,7 +167,7 @@ def compile_donn_train_step_shardmap(cfg: DONNConfig, mesh, optimizer=None,
     (local FFTs), and only the (tiny, phase-sized) gradients are psum'd —
     the textbook DP layout for a small-parameter model.
     """
-    from repro.compat import shard_map
+    from jax import shard_map
 
     optimizer = optimizer or AdamW(lr=0.01)
     sspecs = donn_state_specs(cfg)
@@ -293,7 +293,7 @@ def make_donn_sharded_loss(cfg: DONNConfig, mesh, rules=None):
     loss to rtol <= 1e-5 for all families (tests/test_distributed.py).
     See ``compile_donn_train_step_sharded`` for the compiled step.
     """
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core import diffraction as df
     from repro.core import propagation as pp
     from repro.core.laser import data_to_cplex
@@ -377,7 +377,8 @@ def make_donn_sharded_loss(cfg: DONNConfig, mesh, rules=None):
                 if plan.det_grid != cur:
                     u = df.resample_field(u, cur, plan.det_grid)
                     u = shd.constrain(u, field_axes)
-                logits = jnp.einsum("...hw,chw->...c", df.intensity(u), masks)
+                logits = jnp.einsum("...hw,chw->...c", df.intensity(u),
+                                    masks, precision=df.READOUT_PRECISION)
                 return _mse(logits, batch["labels"], cfg.num_classes)
 
         return loss_fn
@@ -458,13 +459,15 @@ def make_donn_sharded_loss(cfg: DONNConfig, mesh, rules=None):
         phi_spec = rp(("layers", "channel", "field_h", "field_w"))
         u_spec = rp(("batch", "channel", "field_h", "field_w"))
         readout = lambda u, m: jnp.einsum("...dhw,chw->...c",
-                                          df.intensity(u), m)
+                                          df.intensity(u), m,
+                                          precision=df.READOUT_PRECISION)
     else:
         host = model
         phi_spec = plane
         u_spec = rp(("batch", "field_h", "field_w"))
         readout = lambda u, m: jnp.einsum("...hw,chw->...c",
-                                          df.intensity(u), m)
+                                          df.intensity(u), m,
+                                          precision=df.READOUT_PRECISION)
     plan = host.plan
     tf_a, tf_b = _plan_tf_stacks(plan)
     masks = jnp.asarray(host.detector.masks)

@@ -379,7 +379,7 @@ class InferenceEngine:
             return dep.forward(x, frozen=frozen)
 
         if self.mp > 1:
-            from repro.compat import shard_map
+            from jax import shard_map
             from repro.runtime.donn_steps import _plan_tf_stacks
             from repro.runtime.pencil_fft import local_spectral_pair
 
@@ -416,7 +416,8 @@ class InferenceEngine:
                 u = plan.forward(None, u, tfs=(a, b), spectral=spectral,
                                  frozen=fz)
                 u = plan.propagate_final(u, tfs=(a, b), spectral=spectral)
-                part = jnp.einsum("...hw,chw->...c", df.intensity(u), m)
+                part = jnp.einsum("...hw,chw->...c", df.intensity(u), m,
+                                  precision=df.READOUT_PRECISION)
                 return jax.lax.psum(part, "model")
 
             sharded = shard_map(
@@ -431,7 +432,7 @@ class InferenceEngine:
 
             fn = run
         elif dp:
-            from repro.compat import shard_map
+            from jax import shard_map
 
             mesh = self._mesh
             x_spec = shd.dim0_pspec("data", self._x_ndim())

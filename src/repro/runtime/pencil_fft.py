@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.runtime import sharding as shd
 
 

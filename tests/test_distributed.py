@@ -1,5 +1,8 @@
 """Multi-device tests (run in a subprocess with 8 host-platform devices so
-the main pytest process keeps a single device — see the dry-run rules)."""
+the main pytest process keeps a single device — see the dry-run rules).
+
+The seed LM's sharded asserts and the DONN sharded asserts run in separate
+subprocesses, so a failure in one family cannot sink the other's tests."""
 import json
 import textwrap
 
@@ -54,11 +57,81 @@ SUITE = textwrap.dedent("""
         np.allclose(losses_sharded, losses_single, rtol=5e-4, atol=5e-4))
     results["losses"] = [losses_sharded, losses_single]
 
+    # 3. compressed psum over a pod axis (shard_map)
+    from jax import shard_map
+    rr = np.random.default_rng(1)
+    from repro.optim.compression import compressed_psum_mean
+    mesh_pod = make_mesh((2, 4), ("pod", "data"))
+    x = jnp.asarray(rr.normal(size=(2, 256)), jnp.float32)  # per-pod rows
+    f = shard_map(lambda v: compressed_psum_mean(v, "pod"),
+                  mesh=mesh_pod, in_specs=P("pod", None),
+                  out_specs=P("pod", None), check_vma=False)
+    got = f(x)
+    want = jnp.broadcast_to(jnp.mean(x, 0, keepdims=True), x.shape)
+    err = float(jnp.max(jnp.abs(got - want)))
+    results["compressed_psum_err"] = err
+    results["compressed_psum_ok"] = bool(err < np.abs(x).max() / 100)
+
+    # 4. elastic checkpoint: save under mesh A, restore under mesh B
+    import tempfile, pathlib
+    from repro import checkpoint as ckpt
+    d = tempfile.mkdtemp()
+    ckpt.save(d, 5, state_sh)
+    meshB = make_mesh((4, 2), ("data", "model"))
+    s_shardB = shd.tree_shardings(sspecs, meshB)
+    restored = ckpt.restore(d, 5, shd.abstract_like(sspecs),
+                            shardings=s_shardB)
+    ok = True
+    for a, b in zip(jax.tree.leaves(state_sh), jax.tree.leaves(restored)):
+        ok &= bool(jnp.allclose(jnp.asarray(a, jnp.float32),
+                                jnp.asarray(b, jnp.float32)))
+    results["elastic_reshard_ok"] = ok
+
+    # 5. decode step under sharding: runs + finite
+    fn_d, p_sh, c_sh, cspecs = steps_mod.compile_decode_step(cfg, mesh, 4, 32)
+    params = jax.device_put(lm.init(cfg, jax.random.PRNGKey(0)), p_sh)
+    cache = jax.device_put(lm.init_cache(cfg, 4, 32), c_sh)
+    logits, cache = fn_d(params, cache, jnp.zeros((4, 1), jnp.int32),
+                         jnp.int32(0))
+    results["sharded_decode_finite"] = bool(
+        jnp.all(jnp.isfinite(logits.astype(jnp.float32))))
+
+    print("RESULTS:" + json.dumps(results))
+""")
+
+
+def _results(code: str) -> dict:
+    proc = run_subprocess(code, device_count=8)
+    assert proc.returncode == 0, f"stderr:\n{proc.stderr[-3000:]}"
+    line = [l for l in proc.stdout.splitlines() if l.startswith("RESULTS:")]
+    assert line, proc.stdout
+    return json.loads(line[0][len("RESULTS:"):])
+
+
+@pytest.fixture(scope="module")
+def suite_results():
+    return _results(SUITE)
+
+
+# ---------------------------------------------------------------------------
+# DONN suite: pencil FFT (standalone, gradients) and the spatially-sharded
+# training loss and step against the single-device reference.
+# ---------------------------------------------------------------------------
+DONN_SUITE = textwrap.dedent("""
+    import json
+    import numpy as np
+    import jax, jax.numpy as jnp
+    results = {}
+
+    assert len(jax.devices()) == 8, len(jax.devices())
+    from repro.launch.mesh import make_mesh
+    from repro.runtime import sharding as shd
+
     # 2. pencil FFT vs fft2 — the supported in-scan entry
     # (local_spectral_pair composed under an explicit shard_map); the
     # standalone pencil_fft2 wrapper is deprecated but works one cycle
     import warnings
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.runtime.pencil_fft import local_spectral_pair, pencil_fft2
     mesh8 = make_mesh((8,), ("model",))
     rr = np.random.default_rng(1)
@@ -175,86 +248,44 @@ SUITE = textwrap.dedent("""
         np.allclose(sp_losses, ref_losses, rtol=1e-5, atol=1e-7)
         and results["spatial_step_param_rel_err"] <= 2e-3)
 
-    # 3. compressed psum over a pod axis (shard_map)
-    from repro.compat import shard_map
-    from repro.optim.compression import compressed_psum_mean
-    mesh_pod = make_mesh((2, 4), ("pod", "data"))
-    x = jnp.asarray(rr.normal(size=(2, 256)), jnp.float32)  # per-pod rows
-    f = shard_map(lambda v: compressed_psum_mean(v, "pod"),
-                  mesh=mesh_pod, in_specs=P("pod", None),
-                  out_specs=P("pod", None), check_vma=False)
-    got = f(x)
-    want = jnp.broadcast_to(jnp.mean(x, 0, keepdims=True), x.shape)
-    err = float(jnp.max(jnp.abs(got - want)))
-    results["compressed_psum_err"] = err
-    results["compressed_psum_ok"] = bool(err < np.abs(x).max() / 100)
-
-    # 4. elastic checkpoint: save under mesh A, restore under mesh B
-    import tempfile, pathlib
-    from repro import checkpoint as ckpt
-    d = tempfile.mkdtemp()
-    ckpt.save(d, 5, state_sh)
-    meshB = make_mesh((4, 2), ("data", "model"))
-    s_shardB = shd.tree_shardings(sspecs, meshB)
-    restored = ckpt.restore(d, 5, shd.abstract_like(sspecs),
-                            shardings=s_shardB)
-    ok = True
-    for a, b in zip(jax.tree.leaves(state_sh), jax.tree.leaves(restored)):
-        ok &= bool(jnp.allclose(jnp.asarray(a, jnp.float32),
-                                jnp.asarray(b, jnp.float32)))
-    results["elastic_reshard_ok"] = ok
-
-    # 5. decode step under sharding: runs + finite
-    fn_d, p_sh, c_sh, cspecs = steps_mod.compile_decode_step(cfg, mesh, 4, 32)
-    params = jax.device_put(lm.init(cfg, jax.random.PRNGKey(0)), p_sh)
-    cache = jax.device_put(lm.init_cache(cfg, 4, 32), c_sh)
-    logits, cache = fn_d(params, cache, jnp.zeros((4, 1), jnp.int32),
-                         jnp.int32(0))
-    results["sharded_decode_finite"] = bool(
-        jnp.all(jnp.isfinite(logits.astype(jnp.float32))))
-
     print("RESULTS:" + json.dumps(results))
 """)
 
 
 @pytest.fixture(scope="module")
-def suite_results():
-    proc = run_subprocess(SUITE, device_count=8)
-    assert proc.returncode == 0, f"stderr:\n{proc.stderr[-3000:]}"
-    line = [l for l in proc.stdout.splitlines() if l.startswith("RESULTS:")]
-    assert line, proc.stdout
-    return json.loads(line[0][len("RESULTS:"):])
+def donn_suite_results():
+    return _results(DONN_SUITE)
 
 
 def test_dp_tp_matches_single_device(suite_results):
     assert suite_results["dp_tp_matches_single"], suite_results["losses"]
 
 
-def test_pencil_fft_matches_fft2(suite_results):
-    assert suite_results["pencil_fft_ok"]
-    assert suite_results["pencil_ifft_ok"]
+def test_pencil_fft_matches_fft2(donn_suite_results):
+    assert donn_suite_results["pencil_fft_ok"]
+    assert donn_suite_results["pencil_ifft_ok"]
 
 
-def test_pencil_fft2_standalone_deprecated_but_working(suite_results):
-    assert suite_results["pencil_fft2_deprecated"]
+def test_pencil_fft2_standalone_deprecated_but_working(donn_suite_results):
+    assert donn_suite_results["pencil_fft2_deprecated"]
 
 
-def test_pencil_fft_gradients_match_single_device(suite_results):
-    assert suite_results["pencil_grad_ok"], (
-        suite_results["pencil_grad_val_rel_err"],
-        suite_results["pencil_grad_max_rel_err"],
+def test_pencil_fft_gradients_match_single_device(donn_suite_results):
+    assert donn_suite_results["pencil_grad_ok"], (
+        donn_suite_results["pencil_grad_val_rel_err"],
+        donn_suite_results["pencil_grad_max_rel_err"],
     )
 
 
-def test_spatial_train_loss_and_grads_match(suite_results):
-    assert suite_results["spatial_loss_grads_ok"], (
-        suite_results["spatial_loss_rel_err"],
-        suite_results["spatial_grad_max_rel_err"],
+def test_spatial_train_loss_and_grads_match(donn_suite_results):
+    assert donn_suite_results["spatial_loss_grads_ok"], (
+        donn_suite_results["spatial_loss_rel_err"],
+        donn_suite_results["spatial_grad_max_rel_err"],
     )
 
 
-def test_spatial_train_step_tracks_reference(suite_results):
-    assert suite_results["spatial_step_ok"], suite_results[
+def test_spatial_train_step_tracks_reference(donn_suite_results):
+    assert donn_suite_results["spatial_step_ok"], donn_suite_results[
         "spatial_step_param_rel_err"]
 
 
@@ -417,11 +448,7 @@ SUITE2 = textwrap.dedent("""
 
 @pytest.fixture(scope="module")
 def suite2_results():
-    proc = run_subprocess(SUITE2, device_count=8)
-    assert proc.returncode == 0, f"stderr:\n{proc.stderr[-3000:]}"
-    line = [l for l in proc.stdout.splitlines() if l.startswith("RESULTS:")]
-    assert line, proc.stdout
-    return json.loads(line[0][len("RESULTS:"):])
+    return _results(SUITE2)
 
 
 @pytest.mark.parametrize("family", ["cls", "rgb", "seg", "het"])

@@ -8,7 +8,6 @@ import jax
 import jax.numpy as jnp
 
 from conftest import run_subprocess
-from repro.compat import compiled_cost_analysis
 from repro.runtime.hlo_analysis import analyze, parse_hlo
 
 
@@ -21,7 +20,7 @@ class TestLoopFree:
             jax.ShapeDtypeStruct((128, 256), jnp.float32),
             jax.ShapeDtypeStruct((256, 512), jnp.float32),
         ).compile()
-        xla = compiled_cost_analysis(c)
+        xla = c.cost_analysis()
         mine = analyze(c.as_text())
         assert abs(mine.flops - xla["flops"]) / xla["flops"] < 0.05
         assert abs(mine.bytes - xla["bytes accessed"]) / xla[
@@ -83,7 +82,7 @@ class TestTripCounting:
             jax.ShapeDtypeStruct((32, 32), jnp.float32),
             jax.ShapeDtypeStruct((8, 32), jnp.float32),
         ).compile()
-        xla = compiled_cost_analysis(c)["flops"]
+        xla = c.cost_analysis()["flops"]
         mine = analyze(c.as_text()).dot_flops
         assert mine > 10 * xla  # mine trip-counts, XLA doesn't
 
@@ -95,7 +94,7 @@ COLLECTIVE_SUITE = textwrap.dedent("""
     from repro.compat import make_mesh
     from repro.runtime.hlo_analysis import analyze
 
-    mesh = make_mesh((4,), ("model",), axis_types=("auto",))
+    mesh = make_mesh((4,), ("model",))
     results = {}
 
     # per scan iteration the model-sharded dot output (32,16) is gathered

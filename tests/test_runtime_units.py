@@ -143,3 +143,36 @@ class TestNNModule:
     def test_logical_axes_rank_check(self):
         with pytest.raises(ValueError):
             ParamSpec((4, 8), jnp.float32, ("embed",))
+
+
+class TestPersistentCompileCache:
+    """Entry points keep XLA's compile cache at one fixed, ignored path."""
+
+    @pytest.fixture
+    def restore_cache_dir(self):
+        before = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_unset_env_uses_repo_dir(self, monkeypatch, restore_cache_dir):
+        from repro.core.cache import (
+            REPO_COMPILE_CACHE, use_persistent_compile_cache,
+        )
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = use_persistent_compile_cache()
+        assert path == str(REPO_COMPILE_CACHE)
+        assert jax.config.jax_compilation_cache_dir == path
+        repo = REPO_COMPILE_CACHE.parent
+        assert (repo / "src" / "repro").is_dir()
+        ignored = (repo / ".gitignore").read_text().split()
+        assert f"{REPO_COMPILE_CACHE.name}/" in ignored
+
+    def test_env_dir_is_left_to_jax(self, monkeypatch, tmp_path,
+                                    restore_cache_dir):
+        from repro.core.cache import use_persistent_compile_cache
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert use_persistent_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
