@@ -10,7 +10,6 @@ Prints ``name,us_per_call,derived`` CSV rows.  Mapping (DESIGN.md §7):
   inference_throughput -> bench_inference_throughput (deployment engine)
   resilience  -> bench_resilience (overload shed, cold-start, noise curves)
   serving_fleet -> bench_serving_fleet (Poisson fleet latency, failover, swap)
-  roofline    -> bench_roofline (measured achieved-vs-peak per tier-1 cell)
 
 Usage: ``python benchmarks/run.py [--check] [filter ...]`` — any number
 of substring filters selects the suites to run (all when none given).
@@ -35,7 +34,7 @@ import traceback
 # suites whose cells gate CI: they must be fresh in the uploaded summary
 TIER1_SUITES = ("propagation_plan", "dse_batched", "hetero",
                 "train_throughput", "inference_throughput", "resilience",
-                "serving_fleet", "kernel_breakdown", "roofline")
+                "serving_fleet", "kernel_breakdown")
 
 
 def stale_tier1(summary: dict) -> list:
@@ -92,7 +91,6 @@ def main() -> None:
         bench_regularization,
         bench_resilience,
         bench_rgb,
-        bench_roofline,
         bench_runtime,
         bench_scaling,
         bench_segmentation,
@@ -119,7 +117,6 @@ def main() -> None:
         ("table4_energy", bench_energy.main),
         ("table5_rgb", bench_rgb.main),
         ("fig13_segmentation", bench_segmentation.main),
-        ("roofline", bench_roofline.main),
     ]
     started_at = time.time()
     failed: list = []

@@ -254,6 +254,13 @@ def resample_field(u: jax.Array, grid_in: Grid, grid_out: Grid) -> jax.Array:
     """
     if grid_in == grid_out:
         return u
+    from repro.core.propagation import stage
+
+    with stage("stitch"):
+        return _resample(u, grid_in, grid_out)
+
+
+def _resample(u: jax.Array, grid_in: Grid, grid_out: Grid) -> jax.Array:
     if _is_exact_crop_pad(grid_in, grid_out):
         # centered grids coincide: output[o] = input[o + (n_in - n_out)/2]
         # (zero outside the input aperture) — pure slicing / padding,
@@ -287,6 +294,20 @@ def phase_to_field(phi: jax.Array) -> jax.Array:
 def intensity(u: jax.Array) -> jax.Array:
     """|U|^2 — detector-plane light intensity."""
     return (u.real**2 + u.imag**2).astype(jnp.float32)
+
+
+def readout(u: jax.Array, masks, channel_axis: bool = False) -> jax.Array:
+    """Detector readout: |U|^2 pooled over (K, n, n) region masks.
+
+    ``u`` is (..., n, n), or (..., C, n, n) with ``channel_axis``, whose
+    channels add incoherently on the shared detector; returns (..., K).
+    """
+    from repro.core.propagation import stage
+
+    with stage("readout"):
+        fields = "...dhw" if channel_axis else "...hw"
+        return jnp.einsum(f"{fields},chw->...c", intensity(u), masks,
+                          precision=READOUT_PRECISION)
 
 
 # Precision of the jnp detector readouts (|U|^2 contracted with the 0/1

@@ -169,12 +169,13 @@ class Detector:
     def __call__(self, u: jax.Array) -> jax.Array:
         """Field (..., n, n) -> per-class intensities (..., C)."""
         if self.use_pallas:
+            from repro.core.propagation import stage
             from repro.kernels import ops as kops
 
-            return kops.intensity_readout(u.real, u.imag, jnp.asarray(self.masks))
-        inten = df.intensity(u)
-        return jnp.einsum("...hw,chw->...c", inten, jnp.asarray(self.masks),
-                          precision=df.READOUT_PRECISION)
+            with stage("readout"):
+                return kops.intensity_readout(u.real, u.imag,
+                                              jnp.asarray(self.masks))
+        return df.readout(u, jnp.asarray(self.masks))
 
     def intensity_image(self, u: jax.Array) -> jax.Array:
         return df.intensity(u)

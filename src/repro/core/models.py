@@ -43,9 +43,9 @@ def channel_readout(u: jax.Array, masks, use_pallas: bool) -> jax.Array:
     if use_pallas:
         from repro.kernels import ops as kops
 
-        return kops.channel_intensity_readout(u.real, u.imag, masks)
-    return jnp.einsum("...dhw,chw->...c", df.intensity(u), masks,
-                      precision=df.READOUT_PRECISION)
+        with pp.stage("readout"):
+            return kops.channel_intensity_readout(u.real, u.imag, masks)
+    return df.readout(u, masks, channel_axis=True)
 
 
 def _build_layers(cfg: DONNConfig, gamma: float):
@@ -128,6 +128,7 @@ class DONN:
         return init_params(self.param_specs(), key)
 
     # --- forward ---
+    @pp.stage("encode")
     def encode(self, x: jax.Array) -> jax.Array:
         u = data_to_cplex(x, self.in_grid.n)
         return u * jnp.asarray(self.source)
@@ -222,8 +223,7 @@ class MultiChannelDONN:
         phis = cm.plan.stack_phases(
             params["phase"][f"layer_{i}"] for i in range(len(cm.layers))
         )
-        u = data_to_cplex(x, cm.in_grid.n) * jnp.asarray(cm.source)
-        u = cm.plan.apply(phis, u, rng)
+        u = cm.plan.apply(phis, cm.encode(x), rng)
         return channel_readout(u, cm.detector.masks, self.cfg.use_pallas)
 
 
@@ -282,7 +282,8 @@ class SegmentationDONN:
         self, params, x, rng: Optional[jax.Array] = None, train: bool = False
     ) -> jax.Array:
         """Images (..., h, w) -> per-pixel intensity map (..., n, n)."""
-        u = data_to_cplex(x, self.in_grid.n) * jnp.asarray(self.source)
+        with pp.stage("encode"):
+            u = data_to_cplex(x, self.in_grid.n) * jnp.asarray(self.source)
         skip_u = None
         if self.cfg.engine == "eager":
             rngs = (
@@ -439,22 +440,25 @@ def cached_apply(cfg: DONNConfig):
     skey = ("donn_apply", config_static_key(cfg))
 
     def run(params, x, rng=None):
-        x = jnp.asarray(x)
-        if rng is None:
+        # input transfer, executable lookup (a compile on a miss) and launch
+        with jax.profiler.TraceAnnotation(pp.DISPATCH_SPAN):
+            x = jnp.asarray(x)
+            if rng is None:
+                ex = pp.cached_executable(
+                    skey + ("norng",), lambda p, xx: model.apply(p, xx),
+                    params, x,
+                )
+                return ex(params, x)
             ex = pp.cached_executable(
-                skey + ("norng",), lambda p, xx: model.apply(p, xx),
-                params, x,
+                skey + ("rng",), lambda p, xx, r: model.apply(p, xx, r),
+                params, x, rng,
             )
-            return ex(params, x)
-        ex = pp.cached_executable(
-            skey + ("rng",), lambda p, xx, r: model.apply(p, xx, r),
-            params, x, rng,
-        )
-        return ex(params, x, rng)
+            return ex(params, x, rng)
 
     return run
 
 
+@pp.stage("masks")
 def _stack_phases(params, depth: int, pad_to: Optional[int] = None) -> jax.Array:
     """(L, ...) phase stack; zero-padded along L to ``pad_to`` if given."""
     phis = jnp.stack(
@@ -648,10 +652,12 @@ def emulate_batch(cfgs: Sequence[DONNConfig], params, x, rng=None,
         )
 
     def fn(inp):
-        u0 = data_to_cplex(inp["x"], n)  # shared encoded input batch
+        with pp.stage("encode"):
+            u0 = data_to_cplex(inp["x"], n)  # shared encoded input batch
 
         def candidate(a, b, src, p, r=None, sa=None, sb=None, m=None):
-            u = u0 * src
+            with pp.stage("encode"):
+                u = u0 * src
             tfs = (a, b)
             if family == "seg":
                 rngs_l = (jax.random.split(r, template.depth)

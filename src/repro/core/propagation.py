@@ -52,12 +52,24 @@ separate unbatched stacks.  This module replaces that loop with a
     keep the single-segment ``PropagationPlan`` (identical HLO and cache
     keys as before).
 
+7.  **Stage scopes** — every stage of the forward (``STAGES``: encode,
+    masks, fft, tf_mul, ifft, modulate, fused_hop, readout, stitch) runs
+    under one ``jax.named_scope`` named ``donn.<stage>``, written where the
+    stage's work is, so every path (train, emulate, DSE, serve; jnp and
+    Pallas) carries it.  Scopes are metadata only: ``stage_map()`` reads
+    them back from each cached executable's HLO, which is how a profiler
+    trace's device ops are joined to stages.  ``compile_stats()`` counts
+    the process's backend compiles and persistent-cache loads; the host
+    spans ``donn.compile`` and ``donn.dispatch`` (``TraceAnnotation``s,
+    which do nothing while no profiler runs) mark compiles and launches.
+
 The eager path remains available via ``DONNConfig(engine="eager")`` and
 must agree with the plan path to rtol <= 1e-5
 (tests/test_propagation_plan.py, tests/test_hetero.py).
 """
 from __future__ import annotations
 
+import re
 from typing import Callable, Optional
 
 import jax
@@ -92,6 +104,57 @@ _EXEC_STATS = {"hits": 0, "misses": 0}
 # shared bounded-LRU implementation (repro.core.cache)
 _cache_get = lru_get
 _cache_put = lru_put
+
+
+# --------------------------------------------------------------------------
+# Stages of the forward (named scopes) and host spans
+# --------------------------------------------------------------------------
+# No JAX primitive names a scope "donn.*" (JAX's own FFT adds
+# "jit(fft)/fft" to the path), so the innermost "donn.<stage>" component of
+# a compiled op's op_name is the stage that op belongs to.
+STAGE_PREFIX = "donn."
+STAGES = ("encode", "masks", "fft", "tf_mul", "ifft", "modulate",
+          "fused_hop", "readout", "stitch")
+COMPILE_SPAN = STAGE_PREFIX + "compile"
+DISPATCH_SPAN = STAGE_PREFIX + "dispatch"
+
+
+def stage(name: str):
+    """The named scope of one stage of the forward (metadata only)."""
+    if name not in STAGES:
+        raise ValueError(f"unknown stage {name!r} (expected one of {STAGES})")
+    return jax.named_scope(STAGE_PREFIX + name)
+
+
+# Process-wide compile counter, fed by JAX's monitoring events.  JAX records
+# the backend-compile duration around every program build, a persistent-
+# cache load included, and a cache-hit event for each load.
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_COMPILE_STATS = {"builds": 0, "cache_loads": 0}
+
+
+def _on_event(event: str, **_) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _COMPILE_STATS["cache_loads"] += 1
+
+
+def _on_duration(event: str, _duration: float, **_) -> None:
+    if event == _BACKEND_COMPILE_EVENT:
+        _COMPILE_STATS["builds"] += 1
+
+
+jax.monitoring.register_event_listener(_on_event)
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def compile_stats() -> dict:
+    """Programs this process compiled with XLA (``compiles``) and loaded
+    from the persistent compilation cache (``cache_loads``), every jit in
+    the process counted, not only the plan's."""
+    loads = _COMPILE_STATS["cache_loads"]
+    return {"compiles": _COMPILE_STATS["builds"] - loads,
+            "cache_loads": loads}
 
 
 def tf_cache_key(grid: df.Grid, z: float, wavelength: float, method: str,
@@ -196,11 +259,55 @@ def cached_executable(static_key: tuple, fn: Callable, *args,
     key = (static_key, donate_argnums, _aval_key(args))
     compiled = _cache_get(_EXEC_CACHE, key, _EXEC_STATS)
     if compiled is None:
-        compiled = jax.jit(
-            fn, donate_argnums=donate_argnums
-        ).lower(*args).compile()
+        with jax.profiler.TraceAnnotation(COMPILE_SPAN):
+            compiled = jax.jit(
+                fn, donate_argnums=donate_argnums
+            ).lower(*args).compile()
         _cache_put(_EXEC_CACHE, key, compiled, _EXEC_CACHE_MAX)
     return compiled
+
+
+_HLO_MODULE = re.compile(r"^HloModule ([^\s,]+)")
+# "%fusion.12 = f32[8,8]{1,0} fusion(%a), kind=kLoop, ..., metadata={...}"
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?(?:^|\s)"
+                        r"([a-z][\w\-]*)\(")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_STAGE_IN_OP_NAME = re.compile(re.escape(STAGE_PREFIX) + r"(\w+)")
+# ops that only hold other ops: their own time is loop control, not a stage
+_CONTAINER_OPS = frozenset({"while", "conditional", "call"})
+
+
+def hlo_stage_map(hlo_text: str) -> dict:
+    """{(module name, instruction name): stage} of one compiled module's
+    text, from the innermost ``donn.<stage>`` scope of each instruction's
+    op_name.  Containers (while, conditional, call) and instructions
+    without a stage are left out."""
+    lines = hlo_text.splitlines()
+    head = _HLO_MODULE.match(lines[0]) if lines else None
+    if head is None:
+        raise ValueError("not the text of an HLO module")
+    module, out = head.group(1), {}
+    for line in lines:
+        instr = _HLO_INSTR.match(line)
+        op_name = _HLO_OP_NAME.search(line)
+        if instr is None or op_name is None:
+            continue
+        if instr.group(2) in _CONTAINER_OPS:
+            continue
+        stages = [s for s in _STAGE_IN_OP_NAME.findall(op_name.group(1))
+                  if s in STAGES]
+        if stages:
+            out[(module, instr.group(1))] = stages[-1]
+    return out
+
+
+def stage_map() -> dict:
+    """{(module name, instruction name): stage} over every executable in
+    the executable cache, built from their HLO text when called."""
+    out = {}
+    for compiled in list(_EXEC_CACHE.values()):
+        out.update(hlo_stage_map(compiled.as_text()))
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -243,6 +350,7 @@ def quantize_frozen_planes(pair, plane_dtype: str = "float32") -> tuple:
     return (qs[0], qs[1], ss[0], ss[1])
 
 
+@stage("masks")
 def dequant_frozen_layer(leaves) -> tuple:
     """One layer's frozen-plane leaves -> f32 ``(a, b)`` (f32 accumulation).
 
@@ -388,6 +496,7 @@ class PropagationPlan:
                 self._const(self._plane_keys[1]))
 
     # --- elementwise sites ---
+    @stage("tf_mul")
     def _spectral_mul(self, s: jax.Array, pair) -> jax.Array:
         """Multiply a spectrum (or far-field plane) by one layer's TF pair."""
         a, b = pair
@@ -400,6 +509,7 @@ class PropagationPlan:
         tr, ti = kops.phase_tf_apply(s.real, s.imag, a, b)  # (theta, amp)
         return jax.lax.complex(tr, ti)
 
+    @stage("modulate")
     def _modulate(self, u: jax.Array, phi: jax.Array) -> jax.Array:
         """gamma * u * exp(j phi); phi (N, N) or per-channel (C, N, N)."""
         if not self.use_pallas:
@@ -410,6 +520,7 @@ class PropagationPlan:
         ur, ui = kops.phase_tf_apply(u.real, u.imag, phi, amp)
         return jax.lax.complex(ur, ui)
 
+    @stage("fused_hop")
     def _fused_layer(self, u: jax.Array, tf_pair, mod=None,
                      phi=None) -> jax.Array:
         """One whole modulated layer as the fused spectral-hop kernel.
@@ -436,6 +547,7 @@ class PropagationPlan:
                                          th_m, amp_m)
         return jax.lax.complex(ur, ui)
 
+    @stage("modulate")
     def _modulate_frozen(self, u: jax.Array, pair) -> jax.Array:
         """Modulate by one layer's *precomputed* modulation plane pair.
 
@@ -477,6 +589,7 @@ class PropagationPlan:
         (accuracy deltas measured in BENCH_inference_throughput).
         """
 
+        @stage("masks")
         def fold(p):
             eff = self._codesign_stack(p, None)
             if self.use_pallas:
@@ -503,18 +616,22 @@ class PropagationPlan:
                     "spectrum methods only (no fraunhofer, no pad)"
                 )
             fft2, ifft2 = spectral
-            return ifft2(self._spectral_mul(fft2(u), pair))
+        else:
+            fft2, ifft2 = jnp.fft.fft2, jnp.fft.ifft2
         if self.method == df.FRAUNHOFER:
-            spec = jnp.fft.fftshift(jnp.fft.fft2(u), axes=(-2, -1))
+            with stage("fft"):
+                spec = jnp.fft.fftshift(fft2(u), axes=(-2, -1))
             return self._spectral_mul(spec, pair)
-        if self.pad:
-            n = self.grid.n
-            up = df.pad_field(u, n)
-            out = jnp.fft.ifft2(self._spectral_mul(jnp.fft.fft2(up), pair))
-            return df.crop_field(out, n)
-        return jnp.fft.ifft2(self._spectral_mul(jnp.fft.fft2(u), pair))
+        n = self.grid.n
+        with stage("fft"):
+            spec = fft2(df.pad_field(u, n) if self.pad else u)
+        spec = self._spectral_mul(spec, pair)
+        with stage("ifft"):
+            out = ifft2(spec)
+            return df.crop_field(out, n) if self.pad else out
 
     # --- codesign ---
+    @stage("masks")
     def _codesign_stack(self, phis: jax.Array, rngs) -> jax.Array:
         """Per-layer hardware quantization on a stacked phase tensor.
 
@@ -548,6 +665,7 @@ class PropagationPlan:
         """Global layer-index ranges of each fused scan segment."""
         return ((0, self.depth),)
 
+    @stage("masks")
     def stack_phases(self, phases) -> jax.Array:
         """Per-layer phase arrays -> the (L, ...) stack ``forward`` scans."""
         return jnp.stack(list(phases))
@@ -722,10 +840,14 @@ class PropagationPlan:
         ``forward(None, u, start=1, frozen=frozen)``.
         """
         hr, hi = self._rfft_half()
-        s = jnp.fft.rfft2(x)
+        with stage("fft"):
+            s = jnp.fft.rfft2(x)
+        with stage("tf_mul"):
+            sr, si = s * hr, s * hi
         n = (self.grid.n, self.grid.n)
-        u = jax.lax.complex(jnp.fft.irfft2(s * hr, s=n),
-                            jnp.fft.irfft2(s * hi, s=n))
+        with stage("ifft"):
+            u = jax.lax.complex(jnp.fft.irfft2(sr, s=n),
+                                jnp.fft.irfft2(si, s=n))
         mod = dequant_frozen_layer(tuple(f[0] for f in tuple(frozen)))
         return self._modulate_frozen(u, mod)
 
@@ -870,6 +992,7 @@ class SegmentedPlan:
     def segment_slices(self) -> tuple:
         return self.slices
 
+    @stage("masks")
     def stack_phases(self, phases) -> tuple:
         """Per-layer phase arrays -> per-segment stacks (ragged pytree)."""
         phases = list(phases)

@@ -19,6 +19,8 @@ leaves, so per-layer plane sizes need no special casing
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -364,7 +366,8 @@ def make_donn_sharded_loss(cfg: DONNConfig, mesh, rules=None):
                 phis = plan.stack_phases(
                     [params["phase"][f"layer_{i}"] for i in range(depth)]
                 )
-                u = data_to_cplex(batch["images"], in_n) * source
+                with pp.stage("encode"):
+                    u = data_to_cplex(batch["images"], in_n) * source
                 u = shd.constrain(u, field_axes)
                 cur = plan.input_grid
                 for j, seg in enumerate(plan.segments):
@@ -377,8 +380,7 @@ def make_donn_sharded_loss(cfg: DONNConfig, mesh, rules=None):
                 if plan.det_grid != cur:
                     u = df.resample_field(u, cur, plan.det_grid)
                     u = shd.constrain(u, field_axes)
-                logits = jnp.einsum("...hw,chw->...c", df.intensity(u),
-                                    masks, precision=df.READOUT_PRECISION)
+                logits = df.readout(u, masks)
                 return _mse(logits, batch["labels"], cfg.num_classes)
 
         return loss_fn
@@ -440,10 +442,11 @@ def make_donn_sharded_loss(cfg: DONNConfig, mesh, rules=None):
 
         def loss_fn(params, batch):
             with shd.activation_sharding(mesh, rules):
-                phis = jnp.stack(
+                phis = plan.stack_phases(
                     [params["phase"][f"layer_{i}"] for i in range(depth)]
                 )
-                u0 = data_to_cplex(batch["images"], in_n) * source
+                with pp.stage("encode"):
+                    u0 = data_to_cplex(batch["images"], in_n) * source
                 inten = fwd(phis, u0)
                 if cfg.layer_norm:  # train=True semantics (the step's loss)
                     mean = jnp.mean(inten, axis=(-2, -1), keepdims=True)
@@ -458,16 +461,12 @@ def make_donn_sharded_loss(cfg: DONNConfig, mesh, rules=None):
         host = model.channel_model
         phi_spec = rp(("layers", "channel", "field_h", "field_w"))
         u_spec = rp(("batch", "channel", "field_h", "field_w"))
-        readout = lambda u, m: jnp.einsum("...dhw,chw->...c",
-                                          df.intensity(u), m,
-                                          precision=df.READOUT_PRECISION)
+        readout = functools.partial(df.readout, channel_axis=True)
     else:
         host = model
         phi_spec = plane
         u_spec = rp(("batch", "field_h", "field_w"))
-        readout = lambda u, m: jnp.einsum("...hw,chw->...c",
-                                          df.intensity(u), m,
-                                          precision=df.READOUT_PRECISION)
+        readout = df.readout
     plan = host.plan
     tf_a, tf_b = _plan_tf_stacks(plan)
     masks = jnp.asarray(host.detector.masks)
@@ -490,10 +489,11 @@ def make_donn_sharded_loss(cfg: DONNConfig, mesh, rules=None):
 
     def loss_fn(params, batch):
         with shd.activation_sharding(mesh, rules):
-            phis = jnp.stack(
+            phis = plan.stack_phases(
                 [params["phase"][f"layer_{i}"] for i in range(depth)]
             )
-            u0 = data_to_cplex(batch["images"], in_n) * source
+            with pp.stage("encode"):
+                u0 = data_to_cplex(batch["images"], in_n) * source
             logits = sharded_logits(phis, tf_a, tf_b, masks, u0)
             return _mse(logits, batch["labels"], cfg.num_classes)
 

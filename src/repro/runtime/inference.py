@@ -55,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import diffraction as df
+from repro.core import propagation as pp
 from repro.core.laser import data_to_cplex, data_to_real
 from repro.data.pipeline import bucket_for, pad_batch
 from repro.runtime import sharding as shd
@@ -78,8 +79,6 @@ class DeployedDONN:
     def __init__(self, cfg, family: str, plan, frozen, source, in_n: int,
                  detector=None, skip_from=None, skip_hop=None,
                  out_grid=None, rfft_first: bool = False):
-        from repro.core import propagation as pp
-
         self.cfg = cfg
         self.family = family  # "cls" | "multi" | "seg"
         self.plan = plan
@@ -131,11 +130,13 @@ class DeployedDONN:
             # real-to-complex entry: amplitude-encoded data through a real
             # source keeps the field real, so layer 0 runs as half-spectrum
             # rFFTs (plan.first_layer_real); the scan continues at layer 1
-            xr = data_to_real(x, self.in_n) * self.source.real
+            with pp.stage("encode"):
+                xr = data_to_real(x, self.in_n) * self.source.real
             u = self.plan.first_layer_real(xr, frozen)
             start = 1
         else:
-            u = data_to_cplex(x, self.in_n) * self.source
+            with pp.stage("encode"):
+                u = data_to_cplex(x, self.in_n) * self.source
             start = 0
         if self.family == "seg":
             plan = self.plan
@@ -365,8 +366,6 @@ class InferenceEngine:
 
     # --- compiled program per bucket ---
     def _executable(self, xp: jax.Array):
-        from repro.core import propagation as pp
-
         pin_key = (tuple(xp.shape), jnp.result_type(xp).name)
         pinned = self._compiled.get(pin_key)
         if pinned is not None:
@@ -416,8 +415,7 @@ class InferenceEngine:
                 u = plan.forward(None, u, tfs=(a, b), spectral=spectral,
                                  frozen=fz)
                 u = plan.propagate_final(u, tfs=(a, b), spectral=spectral)
-                part = jnp.einsum("...hw,chw->...c", df.intensity(u), m,
-                                  precision=df.READOUT_PRECISION)
+                part = df.readout(u, m)
                 return jax.lax.psum(part, "model")
 
             sharded = shard_map(
@@ -427,7 +425,8 @@ class InferenceEngine:
             )
 
             def run(x, frozen):
-                u = data_to_cplex(x, dep.in_n) * dep.source
+                with pp.stage("encode"):
+                    u = data_to_cplex(x, dep.in_n) * dep.source
                 return sharded(u, tf_a, tf_b, masks, tuple(frozen))
 
             fn = run

@@ -1,0 +1,123 @@
+"""Stage scopes of the forward, the stage map of compiled programs, the
+compile counter and the program's host spans."""
+import dataclasses
+import glob
+
+import jax
+import numpy as np
+import pytest
+
+from repro.core import DONNConfig, LayerSpec, build_model
+from repro.core import propagation as pp
+from repro.core.models import cached_apply, clear_emulation_caches
+
+TINY = dict(n=64, depth=3, distance=0.05, det_size=8, codesign="qat",
+            device_levels=256)
+# every stage a path runs, by path
+JNP_STAGES = {"encode", "masks", "fft", "tf_mul", "ifft", "modulate",
+              "readout"}
+PALLAS_STAGES = {"encode", "masks", "fft", "tf_mul", "ifft", "fused_hop",
+                 "readout"}
+
+
+def _images(b=4, seed=0):
+    return np.random.default_rng(seed).random((b, 28, 28)).astype(np.float32)
+
+
+def _stages_of(cfg, x):
+    clear_emulation_caches()
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    out = cached_apply(cfg)(params, x)
+    return set(pp.stage_map().values()), out, params
+
+
+@pytest.mark.parametrize("use_pallas,expected", [(False, JNP_STAGES),
+                                                 (True, PALLAS_STAGES)])
+def test_stage_map_names_every_stage_of_the_path(use_pallas, expected):
+    cfg = DONNConfig(**TINY, use_pallas=use_pallas)
+    stages, _, _ = _stages_of(cfg, _images())
+    assert stages == expected
+
+
+def test_segmented_plan_names_its_stitch():
+    cfg = DONNConfig(n=48, depth=3, distance=0.05, det_size=6, layers=(
+        LayerSpec(distance=0.04, size=48),
+        LayerSpec(distance=0.05, size=32, pixel_size=54e-6),
+        LayerSpec(distance=0.05, size=32, pixel_size=54e-6),
+    ))
+    stages, _, _ = _stages_of(cfg, _images())
+    assert {"stitch", "fft", "ifft", "modulate", "readout"} <= stages
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_scoped_outputs_agree_with_eager(use_pallas):
+    cfg = DONNConfig(**TINY, use_pallas=use_pallas)
+    x = _images()
+    _, got, params = _stages_of(cfg, x)
+    want = build_model(dataclasses.replace(cfg, engine="eager")).apply(
+        params, x)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def test_compile_counter_counts_new_shapes_only():
+    clear_emulation_caches()
+    cfg = DONNConfig(**TINY)
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    apply = cached_apply(cfg)
+    np.asarray(apply(params, _images(4)))
+    c0 = pp.compile_stats()
+    np.asarray(apply(params, _images(4, seed=1)))  # same shape
+    assert pp.compile_stats() == c0
+    np.asarray(apply(params, _images(5)))  # a new batch size
+    c1 = pp.compile_stats()
+    assert c1["compiles"] == c0["compiles"] + 1
+    assert c1["cache_loads"] == c0["cache_loads"]
+
+
+def test_compile_and_dispatch_spans_reach_the_trace(tmp_path):
+    clear_emulation_caches()
+    cfg = DONNConfig(**TINY)
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        np.asarray(cached_apply(cfg)(params, _images(3)))
+    finally:
+        jax.profiler.stop_trace()
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events}
+    assert {pp.COMPILE_SPAN, pp.DISPATCH_SPAN} <= names
+
+
+HLO = """HloModule jit_run, entry_computation_layout={()->f32[2]}
+
+%body (p: (s32[], c64[2,8])) -> (s32[], c64[2,8]) {
+  %fft.3 = c64[2,8]{1,0} fft(%x), fft_type=FFT, metadata={op_name="jit(run)/while/body/closed_call/donn.fft/jit(fft)/fft"}
+  %multiply_fusion.1 = c64[2,8]{1,0:T(8,128)} fusion(%fft.3), kind=kLoop, metadata={op_name="jit(run)/while/body/donn.modulate/donn.tf_mul/mul"}
+  %copy.2 = c64[2,8]{1,0} copy(%multiply_fusion.1), metadata={op_name="jit(run)/while/body/closed_call/mul"}
+}
+
+ENTRY %main () -> f32[2] {
+  %while.5 = (s32[], c64[2,8]{1,0}) while(%tuple), condition=%cond, body=%body, metadata={op_name="jit(run)/donn.masks/while"}
+  ROOT %reduce.7 = f32[2]{0} reduce(%a, %b), metadata={op_name="jit(run)/transpose(jvp(donn.readout))/reduce_sum"}
+  %add.9 = f32[2]{0} add(%a, %b)
+}
+"""
+
+
+def test_hlo_stage_map_takes_the_innermost_program_scope():
+    assert pp.hlo_stage_map(HLO) == {
+        ("jit_run", "fft.3"): "fft",
+        ("jit_run", "multiply_fusion.1"): "tf_mul",
+        ("jit_run", "reduce.7"): "readout",
+    }
+    with pytest.raises(ValueError):
+        pp.hlo_stage_map("not hlo")
+
+
+def test_unknown_stage_is_refused():
+    with pytest.raises(ValueError):
+        pp.stage("fftx")
+    assert all(s.isidentifier() for s in pp.STAGES)

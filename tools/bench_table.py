@@ -64,16 +64,6 @@ def _serving_fleet_headline(meta: dict) -> str:
     return ", ".join(parts)
 
 
-def _roofline_headline(meta: dict) -> str:
-    """Peak fraction + binding roof per measured cell."""
-    parts = []
-    for cell, v in sorted(meta.get("cells", {}).items()):
-        frac = v.get("fraction") if isinstance(v, dict) else None
-        if isinstance(frac, (int, float)):
-            parts.append(f"{cell} {frac:.2f}({v.get('bound', '?')})")
-    return ", ".join(parts)
-
-
 # suite -> (PR, headline metric extractor, description)
 HEADLINES = {
     "propagation_plan": (
@@ -100,9 +90,6 @@ HEADLINES = {
     "kernel_breakdown": (
         "8", lambda m: _fmt_map(_pick(m), "x"),
         "per-operator batched-jit vs per-sample numpy (Fig. 9)"),
-    "roofline": (
-        "8", _roofline_headline,
-        "achieved vs measured machine peak per tier-1 cell"),
 }
 
 
