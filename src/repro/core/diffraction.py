@@ -14,7 +14,10 @@ a uniform sampling grid, plus the propagation primitive
 All transfer functions are precomputed with numpy at model-build time (they
 depend only on static geometry) and embedded as constants, so jit'd forward
 passes contain only FFT2 / complex-multiply / iFFT2 — the three operators the
-paper identifies as the DONN hot spots (Fig. 9).
+paper identifies as the DONN hot spots (Fig. 9).  Because every transfer
+function here is even in each frequency axis, the same hop also runs as
+real-DFT matmuls (``real_dft_matrices``), which the propagation plan uses
+on a TPU.
 
 Optional band-limiting (Matsushima & Shimobaba 2009) suppresses aliasing of
 the angular spectrum for long propagation distances; optional 2x zero-padding
@@ -127,6 +130,67 @@ def propagate_tf(u: jax.Array, h: jax.Array) -> jax.Array:
     spec = jnp.fft.fft2(u)
     out = jnp.fft.ifft2(spec * h)
     return out
+
+
+# --------------------------------------------------------------------------
+# Packed real-DFT hop: the angular-spectrum hop as matmuls
+# --------------------------------------------------------------------------
+# Every transfer function above is even in each frequency axis (a function
+# of fx^2 and fy^2, band limit included), so the hop ifft2(H . fft2(u)) is a
+# symmetric convolution (Martucci 1994) and runs on real transforms.  Row k
+# of the real DFT ``G`` is cos(2 pi k n / N) for k <= N//2 and
+# -sin(2 pi k n / N) above: for a real signal it holds the spectrum's real
+# part C[k] at k <= N//2 and its sine part S[N-k] at k > N//2, each at the
+# index whose H value it meets (H[k] = H[N-k]).  The inverse is
+# ``Gi = G^T diag(w) / N`` (w = 1 at k = 0 and k = N/2, 2 elsewhere: each
+# packed row stands for the pair k, N-k), and the hop of a field u is
+#
+#     Gi . (H o (G . u . G^T)) . Gi^T
+#
+# with H the natural-order plane, unchanged.  G is real, so the complex
+# field goes through as its real and imaginary parts: 2 real N^3 matmuls
+# per stage, half of a dense complex DFT.
+_REAL_DFT_CACHE: dict = {}
+_REAL_DFT_CACHE_MAX = 16
+
+# The hop's matmuls run at full float32 (a TPU's default is one bf16 pass,
+# a different result), like the readouts' READOUT_PRECISION.
+HOP_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def real_dft_matrices(n: int) -> tuple:
+    """``(G, Gi)``, the length-n real DFT and its inverse (float32 numpy,
+    built in float64, cached per n)."""
+    hit = lru_get(_REAL_DFT_CACHE, n)
+    if hit is not None:
+        return hit
+    k = np.arange(n)
+    # (k * j) mod n keeps the angles in [0, 2 pi): exact integer reduction
+    ang = 2.0 * math.pi * (np.outer(k, k) % n) / n
+    g = np.where(k[:, None] <= n // 2, np.cos(ang), -np.sin(ang))
+    w = np.full(n, 2.0)
+    w[0] = 1.0
+    if n % 2 == 0:
+        w[n // 2] = 1.0
+    pair = (g.astype(np.float32), (g.T * (w / n)).astype(np.float32))
+    lru_put(_REAL_DFT_CACHE, n, pair, _REAL_DFT_CACHE_MAX)
+    return pair
+
+
+def real_dft_2d(s: jax.Array, m) -> jax.Array:
+    """``m . s . m^T`` over the last two axes of a real stack ``s``."""
+    m = jnp.asarray(m)
+    t = jnp.einsum("...ij,kj->...ik", s, m, precision=HOP_PRECISION)
+    return jnp.einsum("ki,...ij->...kj", m, t, precision=HOP_PRECISION)
+
+
+def is_even_plane(h: np.ndarray) -> bool:
+    """Whether plane(s) (..., N, N) satisfy H[k] = H[(N - k) % N] exactly
+    along each of the last two axes (what the packed hop needs)."""
+    for axis in (-2, -1):
+        if not np.array_equal(h, np.roll(np.flip(h, axis), 1, axis)):
+            return False
+    return True
 
 
 def propagate(

@@ -63,6 +63,13 @@ separate unbatched stacks.  This module replaces that loop with a
     spans ``donn.compile`` and ``donn.dispatch`` (``TraceAnnotation``s,
     which do nothing while no profiler runs) mark compiles and launches.
 
+8.  **Packed real-DFT hop** — on a TPU, at the plane sizes where it
+    measured faster, the plain hop of the jnp path runs as four real
+    matmul stages on the MXU instead of XLA's FFTs (``_packed_hop``, built
+    on ``diffraction.real_dft_matrices``); it needs every transfer plane
+    even in each frequency axis, checked when the plan is built.
+    ``hop_path_stats()`` counts the hops traced on each path.
+
 The eager path remains available via ``DONNConfig(engine="eager")`` and
 must agree with the plan path to rtol <= 1e-5
 (tests/test_propagation_plan.py, tests/test_hetero.py).
@@ -155,6 +162,33 @@ def compile_stats() -> dict:
     loads = _COMPILE_STATS["cache_loads"]
     return {"compiles": _COMPILE_STATS["builds"] - loads,
             "cache_loads": loads}
+
+
+# Hops traced into programs, by path: "packed" (the real-DFT matmul hop,
+# ``diffraction.real_dft_matrices``) or "fft" (jnp.fft, the Pallas fused hop
+# included).  A scan adds its length once per trace.
+_HOP_STATS = {"packed": 0, "fft": 0}
+
+
+def hop_path_stats() -> dict:
+    """Hops traced so far, by path (``packed``, ``fft``): how often the
+    packed real-DFT hop engages.  Each trace of a program counts its hops
+    once, every layer of a scan included; running a compiled program
+    counts nothing."""
+    return dict(_HOP_STATS)
+
+
+# Plane sizes over which the packed hop was measured against XLA's FFT hop
+# on a TPU v5e, at batch 64: 1.77x faster at 200, 2.04x at 350, 2.07x at 500
+# and 2.31x at 512 (PERF.md §6, PR 14).  Outside them it is unmeasured.
+_PACKED_HOP_N = (200, 512)
+
+
+def _packed_hop_applies(n: int) -> bool:
+    """Whether an n x n hop that may take the packed path takes it: on a
+    TPU, at the plane sizes where it measured faster than the FFT."""
+    lo, hi = _PACKED_HOP_N
+    return jax.default_backend() == "tpu" and lo <= n <= hi
 
 
 def tf_cache_key(grid: df.Grid, z: float, wavelength: float, method: str,
@@ -469,6 +503,12 @@ class PropagationPlan:
             transfer_planes(grid, z, wavelength, method, band_limit, self.pad)
             for z in self.gaps
         ]
+        # the plain hop of the jnp path may run as the packed real-DFT hop
+        # (``_packed_hop``), which needs every plane even in each axis
+        self._packable = (not use_pallas and method != df.FRAUNHOFER
+                          and not self.pad
+                          and all(df.is_even_plane(p[k]) for p in planes
+                                  for k in ("hr", "hi")))
         # stacked numpy constants; uploaded lazily (imports stay device-free)
         self._np = {
             k: np.stack([p[k] for p in planes]) for k in self._plane_keys
@@ -600,15 +640,24 @@ class PropagationPlan:
         a, b = jax.jit(fold)(jnp.asarray(phis))
         return quantize_frozen_planes((a, b), plane_dtype)
 
-    def _hop(self, u: jax.Array, pair, spectral=None) -> jax.Array:
+    def _hop(self, u: jax.Array, pair, spectral=None,
+             hops: int = 1) -> jax.Array:
         """One free-space gap with a prepared TF plane pair.
 
         ``spectral`` optionally overrides the (fft2, ifft2) pair — the hook
         distributed spectral hops use: ``repro.runtime.pencil_fft.
         local_spectral_pair`` runs the pencil-decomposed local FFT *inside*
         the scan body when fields (and TF planes) are row-sharded under an
-        enclosing ``shard_map``.
+        enclosing ``shard_map``.  Without it, a plain hop of a plan whose
+        planes are even runs as ``_packed_hop`` where
+        ``_packed_hop_applies``.  ``hops`` is how many hops this trace
+        stands for in ``hop_path_stats`` (a scan body's: the scan length).
         """
+        packed = (spectral is None and self._packable
+                  and _packed_hop_applies(self.grid.n))
+        _HOP_STATS["packed" if packed else "fft"] += hops
+        if packed:
+            return self._packed_hop(u, pair)
         if spectral is not None:
             if self.method == df.FRAUNHOFER or self.pad:
                 raise NotImplementedError(
@@ -629,6 +678,22 @@ class PropagationPlan:
         with stage("ifft"):
             out = ifft2(spec)
             return df.crop_field(out, n) if self.pad else out
+
+    def _packed_hop(self, u: jax.Array, pair) -> jax.Array:
+        """The plain hop as real-DFT matmuls: ``Gi (H o (G u G^T)) Gi^T``
+        on the real and imaginary parts stacked as one real operand, with
+        the natural-order ``(hr, hi)`` pair as the packed spectrum's
+        multiplier (``diffraction.real_dft_matrices``)."""
+        g, gi = df.real_dft_matrices(self.grid.n)
+        with stage("fft"):
+            x = df.real_dft_2d(jnp.stack([u.real, u.imag]), g)
+        with stage("tf_mul"):
+            hr, hi = (p.astype(jnp.float32) for p in pair)
+            xr, xi = x[0], x[1]
+            x = jnp.stack([hr * xr - hi * xi, hr * xi + hi * xr])
+        with stage("ifft"):
+            y = df.real_dft_2d(x, gi)
+            return jax.lax.complex(y[0], y[1])
 
     # --- codesign ---
     @stage("masks")
@@ -708,68 +773,52 @@ class PropagationPlan:
         # whole-hop fusion applies whenever the body is the plain
         # fft2 -> multiply -> ifft2 -> modulate chain on local spectra
         fuse = self._fuse and spectral is None
+        hops = stop - start
+        if fuse:
+            _HOP_STATS["fft"] += hops
+
+        def layer(carry, tf, phi=None, mod=None):
+            """One modulated layer: hop, then the trainable phase ``phi``
+            or the frozen modulation pair ``mod``."""
+            if fuse:
+                return self._fused_layer(carry, tf, mod=mod, phi=phi)
+            carry = self._hop(carry, tf, spectral, hops=hops)
+            if mod is not None:
+                return self._modulate_frozen(carry, mod)
+            return self._modulate(carry, phi)
+
         if frozen is not None:
             frozen = tuple(frozen)
             xs = (a[start:stop], b[start:stop]) + tuple(
                 f[start:stop] for f in frozen
             )
 
-            def body(carry, layer):
-                a_l, b_l = layer[0], layer[1]
-                mod = dequant_frozen_layer(layer[2:])
-                if fuse:
-                    carry = self._fused_layer(carry, (a_l, b_l), mod=mod)
-                else:
-                    carry = self._modulate_frozen(
-                        self._hop(carry, (a_l, b_l), spectral), mod
-                    )
-                return carry, None
+            def body(carry, layer_xs):
+                mod = dequant_frozen_layer(layer_xs[2:])
+                return layer(carry, layer_xs[:2], mod=mod), None
+        elif mask is None:
+            xs = (a[start:stop], b[start:stop],
+                  self._codesign_stack(phis, rngs)[start:stop])
 
-            if self.remat == "layer":
-                body = jax.checkpoint(body)
-
-            def run(u0, xs_):
-                out, _ = jax.lax.scan(body, u0, xs_,
-                                      unroll=self._scan_unroll(stop - start))
-                return out
-
-            if self.remat == "segment":
-                run = jax.checkpoint(run)
-            return run(u, xs)
-        phi_eff = self._codesign_stack(phis, rngs)
-        if mask is None:
-            xs = (a[start:stop], b[start:stop], phi_eff[start:stop])
-
-            def body(carry, layer):
-                a_l, b_l, phi = layer
-                if fuse:
-                    carry = self._fused_layer(carry, (a_l, b_l), phi=phi)
-                else:
-                    carry = self._modulate(
-                        self._hop(carry, (a_l, b_l), spectral), phi
-                    )
-                return carry, None
+            def body(carry, layer_xs):
+                a_l, b_l, phi = layer_xs
+                return layer(carry, (a_l, b_l), phi=phi), None
         else:
-            xs = (a[start:stop], b[start:stop], phi_eff[start:stop],
+            xs = (a[start:stop], b[start:stop],
+                  self._codesign_stack(phis, rngs)[start:stop],
                   mask[start:stop])
 
-            def body(carry, layer):
-                a_l, b_l, phi, m = layer
-                if fuse:
-                    new = self._fused_layer(carry, (a_l, b_l), phi=phi)
-                else:
-                    new = self._modulate(
-                        self._hop(carry, (a_l, b_l), spectral), phi
-                    )
-                carry = jnp.where(m, new, carry)
-                return carry, None
+            def body(carry, layer_xs):
+                a_l, b_l, phi, m = layer_xs
+                new = layer(carry, (a_l, b_l), phi=phi)
+                return jnp.where(m, new, carry), None
 
         if self.remat == "layer":
             body = jax.checkpoint(body)
 
         def run(u0, xs_):
             out, _ = jax.lax.scan(body, u0, xs_,
-                                  unroll=self._scan_unroll(stop - start))
+                                  unroll=self._scan_unroll(hops))
             return out
 
         if self.remat == "segment":
@@ -819,13 +868,11 @@ class PropagationPlan:
         p = transfer_planes(self.grid, self.gaps[0], self.wavelength,
                             self.method, self.band_limit, self.pad)
         half = self.grid.n // 2 + 1
-        for h in (p["hr"], p["hi"]):
-            folded = np.roll(np.flip(h, (-2, -1)), (1, 1), (-2, -1))
-            if not np.allclose(h, folded, atol=1e-5):
-                raise ValueError(
-                    "transfer function is not even in frequency; the "
-                    "half-spectrum first hop does not apply"
-                )
+        if not (df.is_even_plane(p["hr"]) and df.is_even_plane(p["hi"])):
+            raise ValueError(
+                "transfer function is not even in frequency; the "
+                "half-spectrum first hop does not apply"
+            )
         pair = (jnp.asarray(p["hr"][..., :half]),
                 jnp.asarray(p["hi"][..., :half]))
         self._jax["_rhalf"] = pair
@@ -840,6 +887,7 @@ class PropagationPlan:
         ``forward(None, u, start=1, frozen=frozen)``.
         """
         hr, hi = self._rfft_half()
+        _HOP_STATS["fft"] += 1
         with stage("fft"):
             s = jnp.fft.rfft2(x)
         with stage("tf_mul"):

@@ -39,6 +39,27 @@ def test_stage_map_names_every_stage_of_the_path(use_pallas, expected):
     assert stages == expected
 
 
+def test_packed_hop_ops_map_to_fft_tf_mul_ifft(monkeypatch):
+    monkeypatch.setattr(pp, "_packed_hop_applies", lambda n: True)
+    cfg = DONNConfig(**TINY)
+    stages, _, _ = _stages_of(cfg, _images())
+    assert stages == JNP_STAGES
+    assert pp.plan_from_config(cfg, 1.0)._packable
+    dots = {}
+    smap = pp.stage_map()
+    for compiled in pp._EXEC_CACHE.values():
+        text = compiled.as_text()
+        module = text.split()[1].rstrip(",")
+        for line in text.splitlines():
+            instr = pp._HLO_INSTR.match(line)
+            if instr and instr.group(2) == "dot":
+                stage = smap.get((module, instr.group(1)))
+                dots[stage] = dots.get(stage, 0) + 1
+    clear_emulation_caches()
+    hops = cfg.depth + 1  # two matmuls per transform, two transforms per hop
+    assert dots == {"fft": 2 * hops, "ifft": 2 * hops, "readout": 1}
+
+
 def test_segmented_plan_names_its_stitch():
     cfg = DONNConfig(n=48, depth=3, distance=0.05, det_size=6, layers=(
         LayerSpec(distance=0.04, size=48),

@@ -11,12 +11,15 @@ The topology is described inside a fixture only: one process at a time may
 load the TPU library, and each pytest-xdist worker imports every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import diffraction as df
+from repro.core import propagation as pp
 from repro.kernels import ops
 from repro.kernels.complex_mul import phase_tf_apply_pallas
 from repro.kernels.intensity_readout import intensity_readout_pallas
@@ -102,3 +105,26 @@ def test_intensity_readout_compiles_for_v5e(one_chip, n):
     hlo = _compiled_text(fn, one_chip, (BATCH, hp, wp), (BATCH, hp, wp),
                          (CLASSES, hp, wp))
     assert "tpu_custom_call" in hlo
+
+
+def test_packed_hop_compiles_for_v5e_at_highest_precision(one_chip):
+    n = 500
+    plan = pp.PropagationPlan(df.Grid(n, 36e-6), [0.3], 532e-9)
+    assert plan._packable
+
+    def fn(ur, ui, hr, hi):
+        return plan._packed_hop(jax.lax.complex(ur, ui), (hr, hi))
+
+    hlo = _compiled_text(fn, one_chip, (BATCH, n, n), (BATCH, n, n),
+                         (n, n), (n, n))
+    # XLA's TPU backend writes a dot as a 1x1 convolution
+    matmuls = [line for line in hlo.splitlines()
+               if re.search(r"\b(dot|convolution)\(", line)]
+    assert len(matmuls) == 4
+    assert all("operand_precision={highest,highest}" in line
+               for line in matmuls)
+    entry = hlo[hlo.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    # the stacked real pair is never copied or transposed on its own
+    assert "transpose(" not in entry
+    assert not re.search(rf"= f32\[2,{BATCH},{n},{n}\]\S* copy\(", entry)
