@@ -56,12 +56,15 @@ separate unbatched stacks.  This module replaces that loop with a
     masks, fft, tf_mul, ifft, modulate, fused_hop, readout, stitch) runs
     under one ``jax.named_scope`` named ``donn.<stage>``, written where the
     stage's work is, so every path (train, emulate, DSE, serve; jnp and
-    Pallas) carries it.  Scopes are metadata only: ``stage_map()`` reads
-    them back from each cached executable's HLO, which is how a profiler
-    trace's device ops are joined to stages.  ``compile_stats()`` counts
-    the process's backend compiles and persistent-cache loads; the host
-    spans ``donn.compile`` and ``donn.dispatch`` (``TraceAnnotation``s,
-    which do nothing while no profiler runs) mark compiles and launches.
+    Pallas) carries it; the training steps add ``loss`` (softmax and MSE)
+    and ``optimizer`` (the parameter update).  Scopes are metadata only:
+    ``stage_map()`` reads them back from each cached executable's HLO,
+    which is how a profiler trace's device ops are joined to stages.
+    ``compile_stats()`` counts the process's backend compiles and
+    persistent-cache loads; the host spans ``donn.compile``,
+    ``donn.dispatch`` and ``donn.train_dispatch`` (``TraceAnnotation``s,
+    which do nothing while no profiler runs) mark compiles, forward
+    launches and training-chunk launches.
 
 8.  **Packed real-DFT hop** — on a TPU, at the plane sizes where it
     measured faster, the plain hop of the jnp path runs as four real
@@ -121,9 +124,10 @@ _cache_put = lru_put
 # a compiled op's op_name is the stage that op belongs to.
 STAGE_PREFIX = "donn."
 STAGES = ("encode", "masks", "fft", "tf_mul", "ifft", "modulate",
-          "fused_hop", "readout", "stitch")
+          "fused_hop", "readout", "stitch", "loss", "optimizer")
 COMPILE_SPAN = STAGE_PREFIX + "compile"
 DISPATCH_SPAN = STAGE_PREFIX + "dispatch"
+TRAIN_DISPATCH_SPAN = STAGE_PREFIX + "train_dispatch"
 
 
 def stage(name: str):

@@ -13,7 +13,10 @@ and benchmarks:
 - ``make_train_chunk``: the throughput driver — one jit runs
   ``steps_per_call`` optimizer steps as a ``lax.scan`` over a stacked
   batch chunk with (params, opt_state) *donated*, losses/metrics
-  accumulated on device, and exactly one host sync per chunk.
+  accumulated on device, and exactly one host sync per chunk.  Each launch
+  runs in a ``donn.train_dispatch`` host span and is counted by
+  ``train_stats()``; inside, the loss and the optimizer update carry the
+  ``donn.loss`` and ``donn.optimizer`` stage scopes.
 - ``train_classifier(steps_per_call=...)``: epoch loop on top, fed by the
   double-buffered device prefetcher (``repro.data.pipeline``).
 """
@@ -67,6 +70,41 @@ def iou(intensity: jax.Array, mask: jax.Array, thresh: float = 0.0):
     return jnp.mean(inter / jnp.maximum(union, 1.0))
 
 
+def make_loss_fn(model, num_classes: int, needs_rng: bool = False):
+    """(params, xb, yb, rng) -> (loss, logits): the paper's loss on the
+    model's forward, the loss itself under the ``loss`` stage scope.  Every
+    training step here differentiates this one function."""
+    from repro.core import propagation as pp
+
+    def loss_fn(params, xb, yb, rng):
+        logits = model.apply(params, xb, rng) if needs_rng else model.apply(
+            params, xb
+        )
+        with pp.stage("loss"):
+            return mse_softmax_loss(logits, yb, num_classes), logits
+
+    return loss_fn
+
+
+def _scoped_update(optimizer, grads, opt_state, params, step):
+    """``optimizer.update`` under the ``optimizer`` stage scope."""
+    from repro.core import propagation as pp
+
+    with pp.stage("optimizer"):
+        return optimizer.update(grads, opt_state, params, step)
+
+
+# Training chunks launched by ``make_train_chunk``'s functions in this process,
+# and the optimizer steps they hold, counted on the host at each launch.
+_TRAIN_STATS = {"chunks": 0, "steps": 0}
+
+
+def train_stats() -> dict:
+    """Chunks dispatched by ``make_train_chunk`` functions (``chunks``) and
+    the optimizer steps they carried (``steps``), since the process began."""
+    return dict(_TRAIN_STATS)
+
+
 @dataclasses.dataclass
 class TrainResult:
     params: Any
@@ -114,17 +152,14 @@ def make_train_step(model, optimizer, num_classes: int, needs_rng: bool = False)
     rebuild identical models stop re-tracing the same training program.
     """
 
-    def loss_fn(params, xb, yb, rng):
-        logits = model.apply(params, xb, rng) if needs_rng else model.apply(
-            params, xb
-        )
-        return mse_softmax_loss(logits, yb, num_classes), logits
+    loss_fn = make_loss_fn(model, num_classes, needs_rng)
 
     def step_impl(params, opt_state, step, xb, yb, rng):
         (loss, logits), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, xb, yb, rng
         )
-        params, opt_state = optimizer.update(grads, opt_state, params, step)
+        params, opt_state = _scoped_update(optimizer, grads, opt_state,
+                                           params, step)
         return params, opt_state, loss, accuracy(logits, yb)
 
     skey = _train_static_key("donn_train_step", model, optimizer,
@@ -172,11 +207,7 @@ def make_train_chunk(model, optimizer, num_classes: int,
     when the model/optimizer are cache-keyable.
     """
 
-    def loss_fn(params, xb, yb, rng):
-        logits = model.apply(params, xb, rng) if needs_rng else model.apply(
-            params, xb
-        )
-        return mse_softmax_loss(logits, yb, num_classes), logits
+    loss_fn = make_loss_fn(model, num_classes, needs_rng)
 
     def chunk_impl(params, opt_state, step0, xs, ys, rng):
         def body(carry, batch):
@@ -187,16 +218,16 @@ def make_train_chunk(model, optimizer, num_classes: int,
                 loss_fn, has_aux=True
             )(params, xb, yb, sub)
             if not guard:
-                params, opt_state = optimizer.update(
-                    grads, opt_state, params, step
+                params, opt_state = _scoped_update(
+                    optimizer, grads, opt_state, params, step
                 )
                 return ((params, opt_state, step + 1, rng),
                         (loss, accuracy(logits, yb)))
             ok = jnp.isfinite(loss)
             for g in jax.tree.leaves(grads):
                 ok &= jnp.all(jnp.isfinite(g))
-            new_params, new_opt = optimizer.update(grads, opt_state, params,
-                                                   step)
+            new_params, new_opt = _scoped_update(optimizer, grads,
+                                                 opt_state, params, step)
             keep = lambda new, old: jax.tree.map(
                 lambda a, b: jnp.where(ok, a, b), new, old
             )
@@ -219,19 +250,26 @@ def make_train_chunk(model, optimizer, num_classes: int,
             params_ok &= jnp.all(jnp.isfinite(p))
         return params, opt_state, rng, losses, accs, skipped, params_ok
 
+    from repro.core import propagation as pp
+
     donate_n = (0, 1) if donate else ()
     skey = _train_static_key("donn_train_chunk", model, optimizer,
                              num_classes, needs_rng, donate, guard)
-    if skey is None:
-        return jax.jit(chunk_impl, donate_argnums=donate_n)
-    from repro.core import propagation as pp
+    jitted = (jax.jit(chunk_impl, donate_argnums=donate_n)
+              if skey is None else None)
 
     def chunk_fn(params, opt_state, step0, xs, ys, rng):
-        args = (params, opt_state, jnp.asarray(step0), jnp.asarray(xs),
-                jnp.asarray(ys), rng)
-        ex = pp.cached_executable(skey, chunk_impl, *args,
-                                  donate_argnums=donate_n)
-        return ex(*args)
+        # input transfer, executable lookup (a compile on a miss) and launch
+        with jax.profiler.TraceAnnotation(pp.TRAIN_DISPATCH_SPAN):
+            _TRAIN_STATS["chunks"] += 1
+            _TRAIN_STATS["steps"] += int(np.shape(xs)[0])
+            if jitted is not None:
+                return jitted(params, opt_state, step0, xs, ys, rng)
+            args = (params, opt_state, jnp.asarray(step0), jnp.asarray(xs),
+                    jnp.asarray(ys), rng)
+            ex = pp.cached_executable(skey, chunk_impl, *args,
+                                      donate_argnums=donate_n)
+            return ex(*args)
 
     return chunk_fn
 
