@@ -147,9 +147,29 @@ def propagate_tf(u: jax.Array, h: jax.Array) -> jax.Array:
 #
 #     Gi . (H o (G . u . G^T)) . Gi^T
 #
-# with H the natural-order plane, unchanged.  G is real, so the complex
-# field goes through as its real and imaginary parts: 2 real N^3 matmuls
-# per stage, half of a dense complex DFT.
+# with H the natural-order plane.  G is real, so the complex field goes
+# through as its real and imaginary parts.
+#
+# The hop runs folded, on half of G's multiply-adds.  Pair each sample
+# with its mirror, x_j and x_{N-1-j} (j < m = ceil(N/2)): shifted by half a
+# sample, the spectrum of the even fold a_j = x_j + x_{N-1-j} is a cosine
+# transform C[k, j] = cos(pi k (2j + 1) / N) (k < m) and that of the odd
+# fold b_j = x_j - x_{N-1-j} a sine transform S[k, j] = sin(pi k (2j + 1)
+# / N) (1 <= k <= m): exp(-i pi k / N) X[k] = (C a)[k] - i (S b)[k]
+# (a DCT-II / DST-II split; Martucci 1994).  Since H is even the shift
+# cancels through the hop, and the cosine and sine coefficients at k meet
+# H[k].  In 2-D a field becomes four (m, m) folded blocks, even or odd
+# along rows and along columns (``fold_field``); the hop is four matmul
+# stages each contracting m instead of N, with each block times its
+# quadrant of H (rows and columns starting at 0 for a cosine block, 1 for
+# a sine block), and the inverse stages return the folds.  A modulation,
+# elementwise on the field, mixes the four blocks of a pixel: a Hadamard
+# transform of the blocks gives the field's mirrored quadrants, where the
+# product is elementwise, and another returns the folds
+# (``modulate_folded``).  So a plan folds once per forward and carries the
+# blocks through its scan.  The fold and unfold at the ends are matmuls
+# with 0/+-1 matrices (``fold_matrices``): a TPU runs a reversal as a pass
+# of its own, and did not compile it exactly at every size.
 _REAL_DFT_CACHE: dict = {}
 _REAL_DFT_CACHE_MAX = 16
 
@@ -182,6 +202,156 @@ def real_dft_2d(s: jax.Array, m) -> jax.Array:
     m = jnp.asarray(m)
     t = jnp.einsum("...ij,kj->...ik", s, m, precision=HOP_PRECISION)
     return jnp.einsum("ki,...ij->...kj", m, t, precision=HOP_PRECISION)
+
+
+def folded_dft_matrices(n: int) -> tuple:
+    """``(F, Fi)``, each a (2, m, m) float32 stack (m = (n + 1) // 2) of the
+    cosine and sine blocks of the half-sample folded length-n real DFT and
+    of its inverse (built in float64, cached per n).
+
+    ``F[0]`` (rows k = 0 .. m-1) takes the even fold ``x_j + x_{n-1-j}``,
+    ``F[1]`` (rows k = 1 .. m) the odd fold ``x_j - x_{n-1-j}``; for odd n
+    the middle sample pairs with itself, so ``F[0]`` halves its column and
+    ``F[1]``'s last row (k = m) is zero."""
+    key = ("folded", n)
+    hit = lru_get(_REAL_DFT_CACHE, key)
+    if hit is not None:
+        return hit
+    m = (n + 1) // 2
+    j = np.arange(m)
+    k = np.arange(m + 1)
+    # pi k (2j + 1) / n, its integer part reduced mod 2n exactly
+    ang = math.pi * (np.outer(k, 2 * j + 1) % (2 * n)) / n
+    cos, sin = np.cos(ang[:m]), np.sin(ang[1:])
+    half = np.ones(m)
+    wc = np.full(m, 2.0)  # each coefficient k stands for k and n - k ...
+    wc[0] = 1.0
+    ws = np.full(m, 2.0)
+    if n % 2:
+        half[-1] = 0.5  # the middle sample pairs with itself
+        sin[-1] = 0.0  # k = m is no coefficient
+        sin[:, -1] = 0.0  # sin(pi k): the middle of an odd fold is 0
+    else:
+        ws[-1] = 1.0  # ... but k = n/2 for itself
+    fwd = np.stack([cos * half, sin])
+    # 2 (cos^T, sin^T) diag(w) / n returns the folds, not x itself
+    inv = np.stack([cos.T * (2.0 * wc / n), sin.T * (2.0 * ws / n)])
+    pair = (fwd.astype(np.float32), inv.astype(np.float32))
+    lru_put(_REAL_DFT_CACHE, key, pair, _REAL_DFT_CACHE_MAX)
+    return pair
+
+
+# The folded hop's four blocks, by (row block, column block): 0 is the
+# cosine (even) block of an axis, 1 the sine (odd) block.
+BLOCKS = ((0, 0), (0, 1), (1, 0), (1, 1))
+
+
+def fold_matrices(n: int) -> tuple:
+    """``(P, Q)``: the even and odd half-sample folds of a length-n axis,
+    ``P`` (2, m, n) with ``(P x)_j = x_j +- x_{n-1-j}`` (for odd n the
+    middle sample pairs with itself), and their inverse ``Q`` (2, n, m),
+    ``x = Q[0] a + Q[1] b``.  Entries 0, +-1/2, +-1 and 2: exact in every
+    matmul precision pass, so at HIGHEST the folds are plain sums."""
+    m = (n + 1) // 2
+    j = np.arange(m)
+    p = np.zeros((2, m, n), np.float32)
+    q = np.zeros((2, n, m), np.float32)
+    for s, sign in enumerate((1.0, -1.0)):
+        np.add.at(p[s], (j, j), 1.0)
+        np.add.at(p[s], (j, n - 1 - j), sign)
+        q[s, j, j] = 0.5
+        t = j[: n - m]
+        q[s, n - 1 - t, t] = 0.5 * sign
+    return p, q
+
+
+def _fold_planes(v: jax.Array) -> jax.Array:
+    """Real plane(s) (..., n, n) -> their four folded blocks (4, ..., m,
+    m) in ``BLOCKS`` order, as plain matmuls with ``fold_matrices``: the
+    mirrored reads run in the matmuls, which the TPU compiles exactly at
+    every size, and not as reversals."""
+    p = fold_matrices(v.shape[-1])[0]
+    rows = [jnp.einsum("jc,...ci->...ji", p[r], v, precision=HOP_PRECISION)
+            for r in (0, 1)]
+    return jnp.stack([
+        jnp.einsum("...ji,li->...jl", rows[r], p[t], precision=HOP_PRECISION)
+        for r, t in BLOCKS])
+
+
+def fold_field(u: jax.Array) -> jax.Array:
+    """Complex field(s) (..., n, n) -> the folded hop's four real blocks in
+    ``BLOCKS`` order, each its (real, imaginary) parts: (4, 2, ..., m, m).
+    """
+    return _fold_planes(jnp.stack([u.real, u.imag]))
+
+
+def unfold_field(x: jax.Array, n: int) -> jax.Array:
+    """Inverse of ``fold_field``."""
+    q = fold_matrices(n)[1]
+    rows = [sum(jnp.einsum("...jl,cl->...jc", x[2 * r + t], q[t],
+                           precision=HOP_PRECISION) for t in (0, 1))
+            for r in (0, 1)]
+    v = sum(jnp.einsum("ij,...jc->...ic", q[r], rows[r],
+                       precision=HOP_PRECISION) for r in (0, 1))
+    return jax.lax.complex(v[0], v[1])
+
+
+def _hadamard(x: jax.Array) -> jax.Array:
+    """The 4-point Walsh-Hadamard transform of the blocks on the leading
+    axis (k = 2 r + t): block k of the result sums block k' = 2 r' + t'
+    with the sign (-1)^(r r' + t t').  It takes a field's four folds to
+    four times its mirrored quadrants (rows, and columns, of the second
+    half reversed) and back.  Two butterflies of adds, one pass over the
+    blocks; as a 4 x 4 dot the v5e laid the block axis out second-minor
+    and took six times as long."""
+    s, t = x[0] + x[1], x[0] - x[1]
+    u, v = x[2] + x[3], x[2] - x[3]
+    return jnp.stack([s + u, t + v, s - u, t - v])
+
+
+def fold_modulation(mod: jax.Array) -> jax.Array:
+    """Complex modulation plane(s) (L, ..., n, n) -> (L, 4, 2, ..., m, m):
+    for each leading L the plane's mirrored quadrants, each its (real,
+    imaginary) parts, over 4 (what ``modulate_folded`` multiplies)."""
+    v = _fold_planes(jnp.stack([mod.real, mod.imag], axis=1))
+    return jnp.moveaxis(_hadamard(v), 0, 1) / 16.0
+
+
+def modulate_folded(x: jax.Array, mod: jax.Array) -> jax.Array:
+    """The elementwise product of a field with a modulation plane, on the
+    field's folded blocks ``x`` (``fold_field``) and one layer's
+    ``fold_modulation`` stack ``mod`` (4, 2, ..., m, m).  The product is
+    elementwise on the mirrored quadrants, so the blocks go there and back
+    by ``_hadamard`` (on a TPU v5e this ran the cell faster than mixing the
+    four folds directly, which reads each of them for every block it
+    writes)."""
+    mod = mod.reshape(mod.shape[:2] + (1,) * (x.ndim - mod.ndim)
+                      + mod.shape[2:])
+    q = _hadamard(x)
+    re = mod[:, 0] * q[:, 0] - mod[:, 1] * q[:, 1]
+    im = mod[:, 0] * q[:, 1] + mod[:, 1] * q[:, 0]
+    return _hadamard(jnp.stack([re, im], axis=1))
+
+
+def folded_dft_2d(x: jax.Array, mats, inverse: bool = False) -> jax.Array:
+    """One folded transform of the four blocks ``x`` (4, 2, ..., m, m):
+    block (r, t) becomes ``mats[r] . x . mats[t]^T``, each side one dot
+    batched over the blocks (``folded_dft_matrices``' forward or inverse
+    stack).  The column side runs first forward and last ``inverse``, so
+    the dots next to a layer's modulation read and write the blocks in
+    their plain layout."""
+    mats = np.asarray(mats)
+    rows = jnp.asarray(mats[[r for r, _ in BLOCKS]])
+    cols = jnp.asarray(mats[[t for _, t in BLOCKS]])
+    sides = [
+        lambda v: jnp.einsum("k...ij,klj->k...il", v, cols,
+                             precision=HOP_PRECISION),
+        lambda v: jnp.einsum("kli,k...ij->k...lj", rows, v,
+                             precision=HOP_PRECISION),
+    ]
+    for side in sides[::-1] if inverse else sides:
+        x = side(x)
+    return x
 
 
 def is_even_plane(h: np.ndarray) -> bool:

@@ -309,14 +309,13 @@ class SegmentationDONN:
                 else None
             )
             if self.skip_from is None:
-                u = self.plan.forward(phis, u, rngs)
+                u = self.plan.forward(phis, u, rngs, final=True)
             else:
                 u = self.plan.forward(phis, u, rngs,
                                       stop=self.skip_from + 1)
                 skip_u = u
                 u = self.plan.forward(phis, u, rngs,
-                                      start=self.skip_from + 1)
-            u = self.plan.propagate_final(u)
+                                      start=self.skip_from + 1, final=True)
         if skip_u is not None:
             # beam-splitter recombination on the detector grid
             sk = self.skip_hop.propagate(skip_u)
@@ -669,14 +668,13 @@ def emulate_batch(cfgs: Sequence[DONNConfig], params, x, rng=None,
                     skip_u = u
                     u = template.forward(p, u, rngs_l,
                                          start=base.skip_from + 1, tfs=tfs,
-                                         mask=m)
-                    u = template.propagate_final(u, tfs=tfs)
+                                         mask=m, final=True)
                     u = (u + template._hop(skip_u, (sa, sb))) / jnp.sqrt(
                         2.0
                     ).astype(jnp.complex64)
                 else:
-                    u = template.forward(p, u, rngs_l, tfs=tfs, mask=m)
-                    u = template.propagate_final(u, tfs=tfs)
+                    u = template.forward(p, u, rngs_l, tfs=tfs, mask=m,
+                                         final=True)
                 inten = df.intensity(u)
                 if train and base.layer_norm:
                     mean = jnp.mean(inten, axis=(-2, -1), keepdims=True)

@@ -68,9 +68,13 @@ separate unbatched stacks.  This module replaces that loop with a
 
 8.  **Packed real-DFT hop** — on a TPU, at the plane sizes where it
     measured faster, the plain hop of the jnp path runs as four real
-    matmul stages on the MXU instead of XLA's FFTs (``_packed_hop``, built
-    on ``diffraction.real_dft_matrices``); it needs every transfer plane
-    even in each frequency axis, checked when the plan is built.
+    matmul stages on the MXU instead of XLA's FFTs (``_packed_hop``); it
+    needs every transfer plane even in each frequency axis, checked when
+    the plan is built.  From N = 385 (``_folded_hop_applies``) it runs
+    folded into even and odd halves, each stage contracting half the
+    plane (``_folded_hop``, built on ``diffraction.folded_dft_matrices``):
+    ``forward`` folds a field once and scans the folded blocks, each
+    layer's modulation mixing them (``diffraction.modulate_folded``).
     ``hop_path_stats()`` counts the hops traced on each path.
 
 The eager path remains available via ``DONNConfig(engine="eager")`` and
@@ -168,17 +172,19 @@ def compile_stats() -> dict:
             "cache_loads": loads}
 
 
-# Hops traced into programs, by path: "packed" (the real-DFT matmul hop,
-# ``diffraction.real_dft_matrices``) or "fft" (jnp.fft, the Pallas fused hop
-# included).  A scan adds its length once per trace.
-_HOP_STATS = {"packed": 0, "fft": 0}
+# Hops traced into programs, by path: "packed" (the real-DFT matmul hop)
+# or "fft" (jnp.fft, the Pallas fused hop included); "folded" counts the
+# packed hops that run folded (``diffraction.folded_dft_matrices``).  A scan
+# adds its length once per trace.
+_HOP_STATS = {"packed": 0, "fft": 0, "folded": 0}
 
 
 def hop_path_stats() -> dict:
     """Hops traced so far, by path (``packed``, ``fft``): how often the
-    packed real-DFT hop engages.  Each trace of a program counts its hops
-    once, every layer of a scan included; running a compiled program
-    counts nothing."""
+    packed real-DFT hop engages; ``folded`` counts the packed hops that
+    run folded (``_folded_hop_applies``).  Each trace of a program counts
+    its hops once, every layer of a scan included; running a compiled
+    program counts nothing."""
     return dict(_HOP_STATS)
 
 
@@ -193,6 +199,31 @@ def _packed_hop_applies(n: int) -> bool:
     TPU, at the plane sizes where it measured faster than the FFT."""
     lo, hi = _PACKED_HOP_N
     return jax.default_backend() == "tpu" and lo <= n <= hi
+
+
+# Smallest plane the packed hop runs folded: from here (N > 3 * 128) the
+# folded stages pad to half the unfolded stages' lane tiles.  Per image-hop
+# of the plan's forward at batch 64 on a TPU v5e, unfolded -> folded:
+# 27.26 -> 30.14 us at N = 300, 31.50 -> 34.67 at 350, 31.35 -> 39.70 at
+# 384, 57.39 -> 41.46 at 392, 58.64 -> 41.54 at 400, 76.93 -> 60.15 at
+# 500, 79.25 -> 60.50 at 512.  At 200 and 256 the forward gains too (6.94
+# -> 4.31, 8.89 -> 5.33), but the N = 200 training cell ran 23% slower
+# folded (PERF.md §6).
+_FOLDED_HOP_MIN_N = 385
+
+
+def _folded_hop_applies(n: int) -> bool:
+    """Whether a packed n x n hop runs folded."""
+    return n >= _FOLDED_HOP_MIN_N
+
+
+def _quadrants(h: jax.Array, m: int, ndim: int) -> jax.Array:
+    """The quadrants of a natural-order plane (..., n, n) that meet the
+    folded hop's blocks, stacked in ``diffraction.BLOCKS`` order and
+    broadcast to ``ndim`` axes (4, ..., m, m): a cosine block at
+    frequencies 0 .. m-1, a sine block at 1 .. m, per axis."""
+    q = jnp.stack([h[..., r:r + m, t:t + m] for r, t in df.BLOCKS])
+    return q.reshape((4,) + (1,) * (ndim - q.ndim) + q.shape[1:])
 
 
 def tf_cache_key(grid: df.Grid, z: float, wavelength: float, method: str,
@@ -645,7 +676,7 @@ class PropagationPlan:
         return quantize_frozen_planes((a, b), plane_dtype)
 
     def _hop(self, u: jax.Array, pair, spectral=None,
-             hops: int = 1) -> jax.Array:
+             hops: int = 1, folded: bool = False) -> jax.Array:
         """One free-space gap with a prepared TF plane pair.
 
         ``spectral`` optionally overrides the (fft2, ifft2) pair — the hook
@@ -656,10 +687,16 @@ class PropagationPlan:
         planes are even runs as ``_packed_hop`` where
         ``_packed_hop_applies``.  ``hops`` is how many hops this trace
         stands for in ``hop_path_stats`` (a scan body's: the scan length).
+        ``folded``: ``u`` is the packed hop's folded blocks
+        (``diffraction.fold_field``), and the hop returns them
+        (``_folded_hop``).
         """
-        packed = (spectral is None and self._packable
-                  and _packed_hop_applies(self.grid.n))
+        packed = self._packs(spectral)
         _HOP_STATS["packed" if packed else "fft"] += hops
+        if packed and _folded_hop_applies(self.grid.n):
+            _HOP_STATS["folded"] += hops
+        if folded:
+            return self._folded_hop(u, pair)
         if packed:
             return self._packed_hop(u, pair)
         if spectral is not None:
@@ -683,12 +720,30 @@ class PropagationPlan:
             out = ifft2(spec)
             return df.crop_field(out, n) if self.pad else out
 
+    def _packs(self, spectral=None) -> bool:
+        """Whether this plan's plain hop runs as ``_packed_hop``."""
+        return (spectral is None and self._packable
+                and _packed_hop_applies(self.grid.n))
+
+    def _folds(self, spectral=None) -> bool:
+        """Whether this plan's plain hop runs as ``_folded_hop``."""
+        return self._packs(spectral) and _folded_hop_applies(self.grid.n)
+
     def _packed_hop(self, u: jax.Array, pair) -> jax.Array:
-        """The plain hop as real-DFT matmuls: ``Gi (H o (G u G^T)) Gi^T``
-        on the real and imaginary parts stacked as one real operand, with
-        the natural-order ``(hr, hi)`` pair as the packed spectrum's
+        """The plain hop of natural-order field(s) as real-DFT matmuls:
+        ``_folded_hop`` between a fold and an unfold where
+        ``_folded_hop_applies``, else ``Gi (H o (G u G^T)) Gi^T`` on the
+        real and imaginary parts stacked as one real operand, with the
+        natural-order ``(hr, hi)`` pair as the packed spectrum's
         multiplier (``diffraction.real_dft_matrices``)."""
-        g, gi = df.real_dft_matrices(self.grid.n)
+        n = self.grid.n
+        if _folded_hop_applies(n):
+            with stage("fft"):
+                x = df.fold_field(u)
+            x = self._folded_hop(x, pair)
+            with stage("ifft"):
+                return df.unfold_field(x, n)
+        g, gi = df.real_dft_matrices(n)
         with stage("fft"):
             x = df.real_dft_2d(jnp.stack([u.real, u.imag]), g)
         with stage("tf_mul"):
@@ -698,6 +753,22 @@ class PropagationPlan:
         with stage("ifft"):
             y = df.real_dft_2d(x, gi)
             return jax.lax.complex(y[0], y[1])
+
+    def _folded_hop(self, x: jax.Array, pair) -> jax.Array:
+        """The plain hop as folded real-DFT matmuls on a field's four folded
+        blocks (``diffraction.fold_field``), in and out: each block's
+        forward stages, its quadrant of the natural-order ``(hr, hi)``
+        pair, its inverse stages (``diffraction.folded_dft_matrices``)."""
+        f, fi = df.folded_dft_matrices(self.grid.n)
+        with stage("fft"):
+            x = df.folded_dft_2d(x, f)
+        with stage("tf_mul"):
+            xr, xi = x[:, 0], x[:, 1]
+            hr, hi = (_quadrants(p.astype(jnp.float32), f.shape[-1],
+                                 xr.ndim) for p in pair)
+            x = jnp.stack([hr * xr - hi * xi, hr * xi + hi * xr], axis=1)
+        with stage("ifft"):
+            return df.folded_dft_2d(x, fi, inverse=True)
 
     # --- codesign ---
     @stage("masks")
@@ -742,7 +813,7 @@ class PropagationPlan:
     def forward(self, phis: jax.Array, u: jax.Array, rngs=None,
                 start: int = 0, stop: Optional[int] = None,
                 tfs=None, mask=None, pre=None, spectral=None,
-                frozen=None) -> jax.Array:
+                frozen=None, final: bool = False) -> jax.Array:
         """Scan layers [start, stop) over the field u.
 
         phis: full (L, ...) phase stack (codesign is applied to the whole
@@ -765,57 +836,71 @@ class PropagationPlan:
         ``frozen_modulation`` — the deployment fast path.  With it the
         scan skips phase-stack codesign (quantization, rng) entirely and
         each layer is one hop plus one fused multiply; ``phis``/``rngs``/
-        ``mask`` are ignored (pass None).
+        ``mask`` are ignored (pass None);
+        final: also run the last free-space hop (``propagate_final``), on
+        the scan's carry as it is (``stop`` must be the depth).
+
+        Where the plain hop runs folded (``_folds``) the field is folded
+        once here, the scan carries its folded blocks, each layer
+        modulating them (``diffraction.modulate_folded``), and it is
+        unfolded once at the end, after the final hop if ``final``.
 
         The plan's ``remat`` policy wraps the body (``"layer"``) or the
         whole scan (``"segment"``) in ``jax.checkpoint``.
         """
         stop = self.depth if stop is None else stop
+        if final:
+            self._check_final()
+            if stop != self.depth:
+                raise ValueError("the final hop follows the last layer")
         if pre is not None:
             u = pre(u)
         a, b = self._tf_pair() if tfs is None else tfs
         # whole-hop fusion applies whenever the body is the plain
         # fft2 -> multiply -> ifft2 -> modulate chain on local spectra
         fuse = self._fuse and spectral is None
+        fold = self._folds(spectral)
         hops = stop - start
         if fuse:
             _HOP_STATS["fft"] += hops
-
-        def layer(carry, tf, phi=None, mod=None):
-            """One modulated layer: hop, then the trainable phase ``phi``
-            or the frozen modulation pair ``mod``."""
-            if fuse:
-                return self._fused_layer(carry, tf, mod=mod, phi=phi)
-            carry = self._hop(carry, tf, spectral, hops=hops)
-            if mod is not None:
-                return self._modulate_frozen(carry, mod)
-            return self._modulate(carry, phi)
-
         if frozen is not None:
-            frozen = tuple(frozen)
-            xs = (a[start:stop], b[start:stop]) + tuple(
-                f[start:stop] for f in frozen
-            )
-
-            def body(carry, layer_xs):
-                mod = dequant_frozen_layer(layer_xs[2:])
-                return layer(carry, layer_xs[:2], mod=mod), None
-        elif mask is None:
-            xs = (a[start:stop], b[start:stop],
-                  self._codesign_stack(phis, rngs)[start:stop])
-
-            def body(carry, layer_xs):
-                a_l, b_l, phi = layer_xs
-                return layer(carry, (a_l, b_l), phi=phi), None
+            mask = None
+            mods = tuple(f[start:stop] for f in frozen)
         else:
-            xs = (a[start:stop], b[start:stop],
-                  self._codesign_stack(phis, rngs)[start:stop],
-                  mask[start:stop])
+            mods = (self._codesign_stack(phis, rngs)[start:stop],)
+        if fold:
+            with stage("masks"):
+                mods = (df.fold_modulation(
+                    jax.lax.complex(*dequant_frozen_layer(mods))
+                    if frozen is not None
+                    else self.gamma * jnp.exp(
+                        1j * mods[0].astype(jnp.complex64))),)
+            with stage("fft"):
+                u = df.fold_field(u)
+        xs = (a[start:stop], b[start:stop]) + mods
+        if mask is not None:
+            xs = xs + (mask[start:stop],)
 
-            def body(carry, layer_xs):
-                a_l, b_l, phi, m = layer_xs
-                new = layer(carry, (a_l, b_l), phi=phi)
-                return jnp.where(m, new, carry), None
+        def body(carry, layer_xs):
+            """One modulated layer: hop, then the layer's trainable phase,
+            frozen modulation pair or folded modulation."""
+            tf, mod = layer_xs[:2], layer_xs[2:2 + len(mods)]
+            if fold:
+                new = self._hop(carry, tf, hops=hops, folded=True)
+                with stage("modulate"):
+                    new = df.modulate_folded(new, mod[0])
+            elif frozen is not None:
+                mod = dequant_frozen_layer(mod)
+                new = (self._fused_layer(carry, tf, mod=mod) if fuse
+                       else self._modulate_frozen(
+                           self._hop(carry, tf, spectral, hops=hops), mod))
+            else:
+                new = (self._fused_layer(carry, tf, phi=mod[0]) if fuse
+                       else self._modulate(
+                           self._hop(carry, tf, spectral, hops=hops), mod[0]))
+            if mask is not None:
+                new = jnp.where(layer_xs[-1], new, carry)
+            return new, None
 
         if self.remat == "layer":
             body = jax.checkpoint(body)
@@ -827,18 +912,28 @@ class PropagationPlan:
 
         if self.remat == "segment":
             run = jax.checkpoint(run)
-        return run(u, xs)
+        u = run(u, xs)
+        if final:
+            u = self._hop(u, (a[self.depth], b[self.depth]), spectral,
+                          folded=fold)
+        if fold:
+            with stage("ifft"):
+                u = df.unfold_field(u, self.grid.n)
+        return u
 
     def propagate_final(self, u: jax.Array, tfs=None,
                         spectral=None) -> jax.Array:
         """The last free-space hop (layer plane -> detector, no modulation)."""
+        self._check_final()
+        a, b = self._tf_pair() if tfs is None else tfs
+        return self._hop(u, (a[self.depth], b[self.depth]), spectral)
+
+    def _check_final(self) -> None:
         if not self.final_hop:
             raise ValueError(
                 "this plan is an inner segment (final_hop=False); the next "
                 "segment owns the following hop"
             )
-        a, b = self._tf_pair() if tfs is None else tfs
-        return self._hop(u, (a[self.depth], b[self.depth]), spectral)
 
     # --- real-to-complex first hop -------------------------------------
     def rfft_first_supported(self) -> bool:
@@ -912,18 +1007,10 @@ class PropagationPlan:
         plane pair (``frozen_modulation``) — the deployment fast path; rng
         and phis are then unused.
         """
-        if frozen is not None:
-            return self.propagate_final(
-                self.forward(None, u, tfs=tfs, spectral=spectral,
-                             frozen=frozen),
-                tfs=tfs, spectral=spectral,
-            )
-        rngs = jax.random.split(rng, self.depth) if rng is not None else None
-        return self.propagate_final(
-            self.forward(phis, u, rngs, tfs=tfs, mask=mask,
-                         spectral=spectral),
-            tfs=tfs, spectral=spectral,
-        )
+        rngs = (jax.random.split(rng, self.depth)
+                if rng is not None and frozen is None else None)
+        return self.forward(phis, u, rngs, tfs=tfs, mask=mask,
+                            spectral=spectral, frozen=frozen, final=True)
 
     def apply_batch(self, phis: jax.Array, u: jax.Array, rng=None,
                     tfs=None, per_candidate_inputs: bool = False,
@@ -1072,11 +1159,13 @@ class SegmentedPlan:
     # --- forward ---
     def forward(self, phis, u: jax.Array, rngs=None, start: int = 0,
                 stop: Optional[int] = None, tfs=None,
-                frozen=None) -> jax.Array:
+                frozen=None, final: bool = False) -> jax.Array:
         """Run global layers [start, stop); ``phis`` is the per-segment
         pytree from ``stack_phases``.  The incoming field must live on the
         grid of layer ``start - 1`` (the input grid when start == 0); the
-        returned field lives on the grid of layer ``stop - 1``.
+        returned field lives on the grid of layer ``stop - 1``, or with
+        ``final`` on the detector grid, after ``propagate_final``'s hop
+        (which the last segment's forward runs) and stitch.
         ``frozen`` takes the per-segment pair tuple from this plan's
         ``frozen_modulation`` (deployment fast path; phis/rngs unused)."""
         if tfs is not None:
@@ -1085,11 +1174,14 @@ class SegmentedPlan:
                 "(batched DSE); segmented plans bake their constants"
             )
         stop = self.depth if stop is None else stop
+        if final and stop != self.depth:
+            raise ValueError("the final hop follows the last layer")
         cur_grid = (self.layer_grids[start - 1] if start > 0
                     else self.input_grid)
         for k, (lo, hi) in enumerate(self.slices):
             a, b = max(lo, start), min(hi, stop)
-            if a >= b:
+            last = final and k == len(self.slices) - 1
+            if a >= b and not last:
                 continue
             seg = self.segments[k]
             stitch = None
@@ -1103,12 +1195,14 @@ class SegmentedPlan:
                     v, s, g)
             if frozen is not None:
                 u = seg.forward(None, u, start=a - lo, stop=b - lo,
-                                pre=stitch, frozen=frozen[k])
+                                pre=stitch, frozen=frozen[k], final=last)
             else:
                 seg_rngs = rngs[lo:hi] if rngs is not None else None
                 u = seg.forward(phis[k], u, seg_rngs, start=a - lo,
-                                stop=b - lo, pre=stitch)
+                                stop=b - lo, pre=stitch, final=last)
             cur_grid = seg.grid
+        if final:
+            u = df.resample_field(u, cur_grid, self.det_grid)
         return u
 
     def propagate_final(self, u: jax.Array, tfs=None) -> jax.Array:
@@ -1121,12 +1215,10 @@ class SegmentedPlan:
 
     def apply(self, phis, u: jax.Array, rng=None, tfs=None,
               frozen=None) -> jax.Array:
-        if frozen is not None:
-            return self.propagate_final(
-                self.forward(None, u, tfs=tfs, frozen=frozen)
-            )
-        rngs = jax.random.split(rng, self.depth) if rng is not None else None
-        return self.propagate_final(self.forward(phis, u, rngs, tfs=tfs))
+        rngs = (jax.random.split(rng, self.depth)
+                if rng is not None and frozen is None else None)
+        return self.forward(phis, u, rngs, tfs=tfs, frozen=frozen,
+                            final=True)
 
 
 def device_spec_from_config(cfg) -> Optional[cd.DeviceSpec]:

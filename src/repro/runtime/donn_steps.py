@@ -349,10 +349,8 @@ def make_donn_sharded_loss(cfg: DONNConfig, mesh, rules=None):
 
         def make_seg_fn(seg, last):
             def body(phis, a, b, u):
-                u = seg.forward(phis, u, None, tfs=(a, b), spectral=spectral)
-                if last:
-                    u = seg.propagate_final(u, tfs=(a, b), spectral=spectral)
-                return u
+                return seg.forward(phis, u, None, tfs=(a, b),
+                                   spectral=spectral, final=last)
 
             return shard_map(body, mesh=mesh,
                              in_specs=(plane, plane, plane, u_spec),
@@ -413,8 +411,7 @@ def make_donn_sharded_loss(cfg: DONNConfig, mesh, rules=None):
                 u1 = plan.forward(phis, u, None, stop=skip_from + 1,
                                   tfs=(a, b), spectral=spectral)
                 u2 = plan.forward(phis, u1, None, start=skip_from + 1,
-                                  tfs=(a, b), spectral=spectral)
-                u2 = plan.propagate_final(u2, tfs=(a, b), spectral=spectral)
+                                  tfs=(a, b), spectral=spectral, final=True)
                 sk = plan._hop(u1, (sa, sb), spectral)
                 return df.intensity((u2 + sk) / sqrt2)
 
@@ -429,8 +426,8 @@ def make_donn_sharded_loss(cfg: DONNConfig, mesh, rules=None):
         else:
 
             def local_map(phis, a, b, u):
-                u = plan.forward(phis, u, None, tfs=(a, b), spectral=spectral)
-                u = plan.propagate_final(u, tfs=(a, b), spectral=spectral)
+                u = plan.forward(phis, u, None, tfs=(a, b), spectral=spectral,
+                                 final=True)
                 return df.intensity(u)
 
             sharded_map = shard_map(
@@ -476,8 +473,8 @@ def make_donn_sharded_loss(cfg: DONNConfig, mesh, rules=None):
 
     def local_logits(phis, a, b, m, u):
         """Per-shard forward core: all plane operands are local row blocks."""
-        u = plan.forward(phis, u, None, tfs=(a, b), spectral=spectral)
-        u = plan.propagate_final(u, tfs=(a, b), spectral=spectral)
+        u = plan.forward(phis, u, None, tfs=(a, b), spectral=spectral,
+                         final=True)
         return _psum_model(readout(u, m))
 
     sharded_logits = shard_map(

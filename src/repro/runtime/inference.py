@@ -141,22 +141,22 @@ class DeployedDONN:
         if self.family == "seg":
             plan = self.plan
             if self.skip_from is None:
-                u = plan.forward(None, u, start=start, frozen=frozen)
+                u = plan.forward(None, u, start=start, frozen=frozen,
+                                 final=True)
                 skip_u = None
             else:
                 u = plan.forward(None, u, start=start,
                                  stop=self.skip_from + 1, frozen=frozen)
                 skip_u = u
                 u = plan.forward(None, u, start=self.skip_from + 1,
-                                 frozen=frozen)
-            u = plan.propagate_final(u)
+                                 frozen=frozen, final=True)
             if skip_u is not None:
                 sk = self.skip_hop.propagate(skip_u)
                 sk = df.resample_field(sk, self.skip_hop.grid, self.out_grid)
                 u = (u + sk) / jnp.sqrt(2.0).astype(jnp.complex64)
             return df.intensity(u)  # eval path: no train-time layer norm
-        u = self.plan.forward(None, u, start=start, frozen=frozen)
-        u = self.plan.propagate_final(u)
+        u = self.plan.forward(None, u, start=start, frozen=frozen,
+                              final=True)
         if self.family == "multi":
             from repro.core.models import channel_readout
 
@@ -413,8 +413,7 @@ class InferenceEngine:
 
             def local_logits(u, a, b, m, fz):
                 u = plan.forward(None, u, tfs=(a, b), spectral=spectral,
-                                 frozen=fz)
-                u = plan.propagate_final(u, tfs=(a, b), spectral=spectral)
+                                 frozen=fz, final=True)
                 part = df.readout(u, m)
                 return jax.lax.psum(part, "model")
 

@@ -27,13 +27,20 @@ def _field(shape, seed=0):
             + 1j * rng.standard_normal(shape)).astype(np.complex64)
 
 
-def _on_both_paths(monkeypatch, fn):
-    """``fn()`` on the FFT path, then with the packed path forced; the
-    plan and executable caches are cleared around each."""
+# the forced packed hop, folded (``_folded_hop_applies``) and not
+FOLDS = pytest.mark.parametrize("fold", [True, False],
+                                ids=["folded", "unfolded"])
+
+
+def _on_both_paths(monkeypatch, fn, fold):
+    """``fn()`` on the FFT path, then with the packed path forced, folded
+    if ``fold``; the plan and executable caches are cleared around
+    each."""
     clear_emulation_caches()
     want = fn()
     with monkeypatch.context() as m:
         m.setattr(pp, "_packed_hop_applies", lambda n: True)
+        m.setattr(pp, "_folded_hop_applies", lambda n: fold)
         clear_emulation_caches()
         got = fn()
     clear_emulation_caches()
@@ -98,18 +105,27 @@ def _tiny_inputs(cfg, b=4):
     return phis, jnp.asarray(_field((b, cfg.n, cfg.n), seed=4))
 
 
-def test_forced_plan_forward_equals_fft_path(monkeypatch):
+@FOLDS
+def test_forced_plan_forward_equals_fft_path(monkeypatch, fold):
     cfg = DONNConfig(**TINY)
     phis, u = _tiny_inputs(cfg)
 
     def run():
+        before = pp.hop_path_stats()
         plan = pp.plan_from_config(cfg, cfg.gamma)
-        return jax.jit(plan.apply)(phis, u)
+        out = jax.jit(plan.apply)(phis, u)
+        after = pp.hop_path_stats()
+        return out, {k: after[k] - before[k] for k in after}
 
-    _close(*_on_both_paths(monkeypatch, run))
+    (got, hops), (want, _) = _on_both_paths(monkeypatch, run, fold)
+    packed = cfg.depth + 1
+    assert hops == {"packed": packed, "fft": 0,
+                    "folded": packed if fold else 0}
+    _close(got, want)
 
 
-def test_forced_frozen_apply_equals_fft_path(monkeypatch):
+@FOLDS
+def test_forced_frozen_apply_equals_fft_path(monkeypatch, fold):
     cfg = DONNConfig(**TINY)
     phis, u = _tiny_inputs(cfg)
 
@@ -118,10 +134,11 @@ def test_forced_frozen_apply_equals_fft_path(monkeypatch):
         frozen = plan.frozen_modulation(phis)
         return jax.jit(lambda v, f: plan.apply(None, v, frozen=f))(u, frozen)
 
-    _close(*_on_both_paths(monkeypatch, run))
+    _close(*_on_both_paths(monkeypatch, run, fold))
 
 
-def test_forced_apply_batch_with_traced_tfs_equals_fft_path(monkeypatch):
+@FOLDS
+def test_forced_apply_batch_with_traced_tfs_equals_fft_path(monkeypatch, fold):
     cfg = DONNConfig(**TINY)
     other = dataclasses.replace(cfg, distance=0.07)
     phis, u = _tiny_inputs(cfg)
@@ -134,15 +151,16 @@ def test_forced_apply_batch_with_traced_tfs_equals_fft_path(monkeypatch):
         fn = jax.jit(lambda p, t: plans[0].apply_batch(p, u, tfs=t))
         return fn(phis_k, tfs)
 
-    _close(*_on_both_paths(monkeypatch, run))
+    _close(*_on_both_paths(monkeypatch, run, fold))
 
 
-def test_forced_cached_apply_logits_equal_fft_path(monkeypatch):
+@FOLDS
+def test_forced_cached_apply_logits_equal_fft_path(monkeypatch, fold):
     cfg = DONNConfig(**TINY)
     params = build_model(cfg).init(jax.random.PRNGKey(0))
     x = jnp.asarray(synth_digits(4, seed=0)[0])
     _close(*_on_both_paths(monkeypatch,
-                           lambda: cached_apply(cfg)(params, x)))
+                           lambda: cached_apply(cfg)(params, x), fold))
 
 
 @pytest.mark.parametrize("extra,data", [
@@ -154,7 +172,8 @@ def test_forced_cached_apply_logits_equal_fft_path(monkeypatch):
                  LayerSpec(distance=0.05, size=48, pixel_size=54e-6))},
      synth_digits),
 ], ids=["rgb", "seg-skip", "fresnel-bf16-planes", "segmented"])
-def test_forced_families_equal_fft_path(monkeypatch, extra, data):
+@FOLDS
+def test_forced_families_equal_fft_path(monkeypatch, extra, data, fold):
     cfg = DONNConfig(**{**TINY, **extra})
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -166,7 +185,7 @@ def test_forced_families_equal_fft_path(monkeypatch, extra, data):
         out = jax.jit(lambda p, v: model.apply(p, v, **train))(params, x)
         return out, pp.hop_path_stats()["packed"] - before
 
-    (got, packed), (want, unpacked) = _on_both_paths(monkeypatch, run)
+    (got, packed), (want, unpacked) = _on_both_paths(monkeypatch, run, fold)
     assert packed > 0 and unpacked == 0
     _close(got, want)
 
@@ -205,7 +224,8 @@ def test_other_hops_are_not_packable(extra):
     assert not pp.plan_from_config(cfg, cfg.gamma)._packable
 
 
-def test_hop_path_stats_counts_each_path(monkeypatch):
+@FOLDS
+def test_hop_path_stats_counts_each_path(monkeypatch, fold):
     cfg = DONNConfig(**TINY)
     params = build_model(cfg).init(jax.random.PRNGKey(0))
     x = jnp.asarray(synth_digits(2, seed=0)[0])
@@ -217,16 +237,18 @@ def test_hop_path_stats_counts_each_path(monkeypatch):
         after = pp.hop_path_stats()
         return {k: after[k] - before[k] for k in after}
 
-    fft, packed = _on_both_paths(monkeypatch, counted)[::-1]
-    assert fft == {"packed": 0, "fft": hops}
-    assert packed == {"packed": hops, "fft": 0}
+    fft, packed = _on_both_paths(monkeypatch, counted, fold)[::-1]
+    assert fft == {"packed": 0, "fft": hops, "folded": 0}
+    assert packed == {"packed": hops, "fft": 0,
+                      "folded": hops if fold else 0}
     # a compiled program that runs again traces nothing
     with monkeypatch.context() as m:
         m.setattr(pp, "_packed_hop_applies", lambda n: True)
+        m.setattr(pp, "_folded_hop_applies", lambda n: fold)
         first = counted()
-        assert counted() == {"packed": 0, "fft": 0}
+        assert counted() == {"packed": 0, "fft": 0, "folded": 0}
     clear_emulation_caches()
-    assert first == {"packed": hops, "fft": 0}
+    assert first == packed
 
 
 def test_packed_hop_rule_is_tpu_and_measured_sizes_only(monkeypatch):
@@ -236,3 +258,124 @@ def test_packed_hop_rule_is_tpu_and_measured_sizes_only(monkeypatch):
     assert [pp._packed_hop_applies(n) for n in (64, 199, 200, 500, 512,
                                                 513)] == [
         False, False, True, True, True, False]
+
+
+def _spectrum(g, x):
+    """The complex DFT X[k], k <= n//2, of real x (..., n) from the packed
+    real DFT ``g . x``: the cosine rows hold Re X, the sine rows -Im X."""
+    n = g.shape[0]
+    y = x.astype(np.float64) @ g.T.astype(np.float64)
+    k = np.arange(n // 2 + 1)
+    sin = np.where((k > 0) & (2 * k < n), y[..., (n - k) % n], 0.0)
+    return y[..., k] - 1j * sin
+
+
+@pytest.mark.parametrize("n", [10, 63, 64, 200])
+def test_folded_constants_reproduce_real_dft_matrices(n):
+    g, gi = df.real_dft_matrices(n)
+    f, fi = df.folded_dft_matrices(n)
+    m = (n + 1) // 2
+    assert f.shape == fi.shape == (2, m, m)
+    rng = np.random.default_rng(n)
+    x = rng.standard_normal((5, n))
+    fold = df.fold_matrices(n)[0].astype(np.float64)
+    a, b = x @ fold[0].T, x @ fold[1].T
+    np.testing.assert_array_equal(a[:, 0], x[:, 0] + x[:, -1])
+    # a half-sample shift turns G's spectrum into the folded one:
+    # exp(-i pi k / n) X[k] = C[k] - i S[k]
+    rot = _spectrum(g, x) * np.exp(-1j * np.pi * np.arange(n // 2 + 1) / n)
+    c, s = a @ f[0].T.astype(np.float64), b @ f[1].T.astype(np.float64)
+    np.testing.assert_allclose(c, rot.real[..., :m], atol=1e-5 * n)
+    ks = np.arange(1, min(m, n // 2) + 1)
+    np.testing.assert_allclose(s[..., ks - 1], -rot.imag[..., ks],
+                               atol=1e-5 * n)
+    # and the inverse pair maps a spectrum back as Gi does, in folds
+    y = rng.standard_normal((5, n))
+    xi = y @ gi.T.astype(np.float64)
+    rot = _spectrum(np.linalg.inv(gi.astype(np.float64)), xi) * np.exp(
+        -1j * np.pi * np.arange(n // 2 + 1) / n)
+    c, s = rot.real[..., :m], np.zeros((5, m))
+    s[..., ks - 1] = -rot.imag[..., ks]
+    want_a, want_b = xi @ fold[0].T, xi @ fold[1].T
+    np.testing.assert_allclose(c @ fi[0].T, want_a, atol=1e-5)
+    np.testing.assert_allclose(s @ fi[1].T, want_b, atol=1e-5)
+
+
+@pytest.mark.parametrize("n", [48, 49])
+@pytest.mark.parametrize("method", [df.RS, df.FRESNEL])
+@pytest.mark.parametrize("band_limit", [True, False])
+def test_forced_folded_scan_equals_fft_path(monkeypatch, n, method,
+                                            band_limit):
+    # a modulated multi-layer plan over batch and channel axes: the scan
+    # carries the folded blocks and each layer mixes them
+    rng = np.random.default_rng(n)
+    phis = jnp.asarray(rng.uniform(0, 2 * np.pi, (3, 3, n, n)), jnp.float32)
+    u = jnp.asarray(_field((2, 3, n, n), seed=5))
+
+    def run():
+        plan = pp.PropagationPlan(df.Grid(n, 36e-6), [0.3] * 4, 532e-9,
+                                  method=method, band_limit=band_limit,
+                                  gamma=1.1)
+        assert plan._packable
+        return jax.jit(plan.apply)(phis, u)
+
+    _close(*_on_both_paths(monkeypatch, run, fold=True))
+
+
+@pytest.mark.parametrize("n", [32, 33])
+def test_forced_folded_phase_gradients_match_fft_path(monkeypatch, n):
+    rng = np.random.default_rng(n)
+    phis = jnp.asarray(rng.uniform(0, 2 * np.pi, (2, 3, n, n)), jnp.float32)
+    u = jnp.asarray(_field((2, 3, n, n), seed=6))
+
+    def run():
+        plan = pp.PropagationPlan(df.Grid(n, 36e-6), [0.05] * 3, 532e-9)
+        loss = lambda p, v: jnp.sum(jnp.abs(plan.apply(p, v)) ** 4)
+        return jax.jit(jax.grad(loss, argnums=(0, 1)))(phis, u)
+
+    got, want = _on_both_paths(monkeypatch, run, fold=True)
+    for g, w in zip(got, want):
+        _close(g, w, rtol=1e-4)
+
+
+@pytest.mark.parametrize("n", [9, 63])
+def test_quadrants_of_uneven_blocks_at_odd_n(monkeypatch, n):
+    # an even plane that is not symmetric under a transpose, so a block
+    # meeting the wrong quadrant, or a row offset taken for a column one,
+    # shows; at odd n the sine block's padded row meets H[m]
+    rng = np.random.default_rng(n)
+    half = rng.standard_normal((2, n // 2 + 1, n // 2 + 1))
+    k = np.minimum(np.arange(n), n - np.arange(n))
+    h = (half[0] + 1j * half[1])[k[:, None], k[None, :]]
+    assert df.is_even_plane(h.real) and not np.allclose(h, h.T)
+    monkeypatch.setattr(pp, "_folded_hop_applies", lambda n: True)
+    plan = pp.PropagationPlan(df.Grid(n, 36e-6), [0.3], 532e-9)
+    u = _field((2, n, n), seed=7)
+    want = np.fft.ifft2(np.fft.fft2(u.astype(np.complex128)) * h)
+    got = jax.jit(plan._packed_hop)(
+        jnp.asarray(u), (jnp.asarray(h.real, jnp.float32),
+                         jnp.asarray(h.imag, jnp.float32)))
+    err = np.abs(np.asarray(got) - want).max() / np.abs(want).max()
+    assert err < 2e-6
+
+
+@pytest.mark.parametrize("n", [10, 63, 64])
+@pytest.mark.parametrize("method", [df.RS, df.FRESNEL])
+def test_folded_hop_equals_fft_hop(monkeypatch, n, method):
+    monkeypatch.setattr(pp, "_folded_hop_applies", lambda n: True)
+    plan = pp.PropagationPlan(df.Grid(n, 36e-6), [0.3], 532e-9,
+                              method=method)
+    h = df.transfer_function(plan.grid, 0.3, 532e-9, method, True)
+    u = _field((2, 3, n, n))
+    want = np.fft.ifft2(np.fft.fft2(u.astype(np.complex128)) * h)
+    got = jax.jit(plan._packed_hop)(
+        jnp.asarray(u), (jnp.asarray(h.real), jnp.asarray(h.imag)))
+    err = np.abs(np.asarray(got) - want).max() / np.abs(want).max()
+    assert err < 2e-6
+
+
+def test_folded_rule_is_the_measured_sizes(monkeypatch):
+    monkeypatch.setattr(pp.jax, "default_backend", lambda: "tpu")
+    assert [pp._packed_hop_applies(n) and pp._folded_hop_applies(n)
+            for n in (200, 256, 350, 384, 385, 400, 500, 512)] == [
+        False, False, False, False, True, True, True, True]

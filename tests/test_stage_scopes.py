@@ -39,6 +39,33 @@ def test_stage_map_names_every_stage_of_the_path(use_pallas, expected):
     assert stages == expected
 
 
+def _instructions(module_text):
+    """(module, name, opcode, op_name or None) of every instruction."""
+    module = module_text.split()[1].rstrip(",")
+    for line in module_text.splitlines():
+        instr = pp._HLO_INSTR.match(line)
+        if instr:
+            op_name = pp._HLO_OP_NAME.search(line)
+            yield (module, instr.group(1), instr.group(2),
+                   op_name.group(1) if op_name else None)
+
+
+def _program_ops(cfg):
+    """{(stage, opcode): count} of the cached programs and the op_names of
+    their instructions that map to no stage."""
+    stages, _, _ = _stages_of(cfg, _images())
+    smap = pp.stage_map()
+    ops, unscoped = {}, set()
+    for compiled in pp._EXEC_CACHE.values():
+        for module, name, opcode, op_name in _instructions(compiled.as_text()):
+            stage = smap.get((module, name))
+            ops[stage, opcode] = ops.get((stage, opcode), 0) + 1
+            if stage is None and opcode not in pp._CONTAINER_OPS:
+                unscoped.add(op_name)
+    clear_emulation_caches()
+    return stages, ops, unscoped
+
+
 def test_packed_hop_ops_map_to_fft_tf_mul_ifft(monkeypatch):
     monkeypatch.setattr(pp, "_packed_hop_applies", lambda n: True)
     cfg = DONNConfig(**TINY)
@@ -58,6 +85,29 @@ def test_packed_hop_ops_map_to_fft_tf_mul_ifft(monkeypatch):
     clear_emulation_caches()
     hops = cfg.depth + 1  # two matmuls per transform, two transforms per hop
     assert dots == {"fft": 2 * hops, "ifft": 2 * hops, "readout": 1}
+
+
+def test_folded_hop_ops_map_to_fft_tf_mul_ifft(monkeypatch):
+    cfg = DONNConfig(**TINY)
+    _, _, fft_unscoped = _program_ops(cfg)
+    monkeypatch.setattr(pp, "_packed_hop_applies", lambda n: True)
+    monkeypatch.setattr(pp, "_folded_hop_applies", lambda n: True)
+    assert pp.plan_from_config(cfg, 1.0)._packable
+    stages, ops, unscoped = _program_ops(cfg)
+    assert stages == JNP_STAGES
+    dots = {stage: k for (stage, opcode), k in ops.items() if opcode == "dot"}
+    hops = cfg.depth + 1
+    # the folded hop: two dots per transform, each batched over the four
+    # folded blocks; the field's fold (six dots, once a forward: two for
+    # the rows, one per block for the columns) belongs to fft, its unfold
+    # to ifft, the modulation planes' fold to masks, and each layer's mix
+    # of the blocks (adds and multiplies, no dot) to modulate
+    assert dots == {"fft": 2 * hops + 6, "ifft": 2 * hops + 6, "masks": 6,
+                    "readout": 1}
+    assert ops.get(("modulate", "multiply"), 0) > 0
+    # no new op lacks a stage: what does is the scan's own slicing, as on
+    # the FFT path, or what the compiler made without a source op
+    assert unscoped <= fft_unscoped | {None}
 
 
 def test_segmented_plan_names_its_stitch():

@@ -109,6 +109,7 @@ def test_intensity_readout_compiles_for_v5e(one_chip, n):
 
 def test_packed_hop_compiles_for_v5e_at_highest_precision(one_chip):
     n = 500
+    m = (n + 1) // 2
     plan = pp.PropagationPlan(df.Grid(n, 36e-6), [0.3], 532e-9)
     assert plan._packable
 
@@ -117,14 +118,25 @@ def test_packed_hop_compiles_for_v5e_at_highest_precision(one_chip):
 
     hlo = _compiled_text(fn, one_chip, (BATCH, n, n), (BATCH, n, n),
                          (n, n), (n, n))
-    # XLA's TPU backend writes a dot as a 1x1 convolution
+    # XLA's TPU backend writes a dot as a 1x1 convolution.  Four stages,
+    # each one dot batched over the four folded blocks, write (block,
+    # real/imaginary, batch, m, m) and contract m, not n; the field's fold
+    # and unfold are six plain dots each, whose 0/+-1 matrices do the
+    # mirrored reads (a TPU runs a reversal as a pass of its own)
     matmuls = [line for line in hlo.splitlines()
                if re.search(r"\b(dot|convolution)\(", line)]
-    assert len(matmuls) == 4
+    assert len(matmuls) == 16
     assert all("operand_precision={highest,highest}" in line
                for line in matmuls)
+    stages = [line for line in matmuls
+              if re.search(rf"= f32\[4,2,{BATCH},{m},{m}\]", line)]
+    assert len(stages) == 4
+    assert not any(str(n) in line.split(", metadata")[0] for line in stages)
+    assert "reverse(" not in hlo
     entry = hlo[hlo.index("\nENTRY"):]
     entry = entry[:entry.index("\n}")]
-    # the stacked real pair is never copied or transposed on its own
+    # neither the stacked real pair nor the folded blocks are copied or
+    # transposed on their own
     assert "transpose(" not in entry
     assert not re.search(rf"= f32\[2,{BATCH},{n},{n}\]\S* copy\(", entry)
+    assert not re.search(rf"= f32\[4,2,{BATCH},{m},{m}\]\S* copy\(", entry)
