@@ -114,47 +114,12 @@ def _bench_unroll_sweep(rows: list) -> dict:
     return out
 
 
-def _bench_fused_hop(rows: list) -> dict:
-    """Fused spectral-hop (use_pallas) vs the unfused jnp scan per family.
-
-    On CPU the Pallas kernels run in interpret mode, so the wall-clock
-    ratio only becomes meaningful on TPU — the rows carry that label; the
-    cross-check that matters everywhere (fused == unfused to <=1e-5) is
-    enforced by the test suite.
-    """
-    interp = jax.default_backend() != "tpu"
-    note = ("(interpret-mode-on-CPU;wall-clock-meaningful-on-TPU-only)"
-            if interp else "")
-    out = {}
-    r = np.random.default_rng(0)
-    for label, cfg_kw, x_shape in CELLS:
-        x = jnp.asarray(r.uniform(0.0, 1.0, x_shape), jnp.float32)
-        steady = {}
-        for tag, pallas in (("jnp", False), ("fused_pallas", True)):
-            cfg = DONNConfig(**cfg_kw, use_pallas=pallas)
-            model = build_model(cfg)
-            params = model.init(jax.random.PRNGKey(0))
-            # one program per modulation path: fresh jit is the protocol
-            fn = jax.jit(lambda p, xb: model.apply(p, xb))  # lightlint: disable=LR104
-            steady[tag] = _steady(fn, params, x, iters=3 if pallas and interp
-                                  else 10)
-        sp = steady["jnp"] / steady["fused_pallas"]
-        name = f"prop_plan/{label}/fused_hop"
-        derived = f"steady_fused_vs_jnp={sp:.2f}x{note}"
-        row(name, steady["fused_pallas"], derived)
-        rows.append({"name": name, "us": steady["fused_pallas"],
-                     "derived": derived})
-        out[label] = round(sp, 3)
-    return out
-
-
 def main():
     rows: list = []
     speeds = {}
     for label, cfg_kw, x_shape in CELLS:
         speeds[label] = _bench_cell(label, cfg_kw, x_shape, rows)
     speeds["unroll_steady_vs_eager"] = _bench_unroll_sweep(rows)
-    speeds["fused_hop_steady_vs_jnp"] = _bench_fused_hop(rows)
     write_bench_json(
         "propagation_plan", rows,
         meta={"backend": jax.default_backend(), "speedups": speeds},

@@ -2,8 +2,8 @@
 
 Prints ``name,us_per_call,derived`` CSV rows.  Mapping (DESIGN.md §7):
   fig5+table3 -> bench_dse          fig7  -> bench_regularization
-  fig8        -> bench_runtime      fig9  -> bench_kernel_breakdown
-  fig10       -> bench_scaling      table4 -> bench_energy
+  fig8        -> bench_runtime      fig10 -> bench_scaling
+  table4      -> bench_energy
   table5      -> bench_rgb          fig13 -> bench_segmentation
   hetero      -> bench_hetero (segmented plans + ragged-depth DSE)
   train_throughput -> bench_train_throughput (chunked training drivers)
@@ -34,7 +34,7 @@ import traceback
 # suites whose cells gate CI: they must be fresh in the uploaded summary
 TIER1_SUITES = ("propagation_plan", "dse_batched", "hetero",
                 "train_throughput", "inference_throughput", "resilience",
-                "serving_fleet", "kernel_breakdown")
+                "serving_fleet")
 
 
 def stale_tier1(summary: dict) -> list:
@@ -86,7 +86,6 @@ def main() -> None:
         bench_energy,
         bench_hetero,
         bench_inference_throughput,
-        bench_kernel_breakdown,
         bench_propagation_plan,
         bench_regularization,
         bench_resilience,
@@ -103,7 +102,6 @@ def main() -> None:
     filters = [a for a in args if not a.startswith("-")]
     suites = [
         ("fig8_runtime", bench_runtime.main),
-        ("fig9_kernel_breakdown", bench_kernel_breakdown.main),
         ("propagation_plan", bench_propagation_plan.main),
         ("dse_batched", bench_dse_batched.main),
         ("hetero", bench_hetero.main),

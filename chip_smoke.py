@@ -8,20 +8,18 @@ arguments it runs, on the registered full-width configurations:
 
 - train   ``donn-mnist-5l`` (200^2, 5 layers, 256-level QAT) through
           ``build_model`` -> ``train_classifier(steps_per_call=...)`` at
-          batch 64 on ``synth_digits``, once on the jnp path and once with
-          the Pallas kernels;
+          batch 64 on ``synth_digits``;
 - serve   ``freeze`` -> saved artifact -> ``FleetRouter.from_artifact``
           (one supervised replica: ``InferenceEngine`` + ``warmup``)
           answering a few dozen requests, with 0 restarts, 0 retries and
           0 failed requests required;
 - emulate one forward of ``donn-xl-500`` (500^2, 30 layers) through the
-          compile-once ``cached_apply`` with the Pallas kernels.
+          compile-once ``cached_apply`` (the folded real-DFT hop).
 
 Every output is compared with the plain float32 reference on the same
 params -- the per-layer eager engine under
 ``jax.default_matmul_precision("highest")`` -- within ``TOL`` and with
-argmax parity, and every program compiled for a Pallas configuration must
-hold a Mosaic ``tpu_custom_call`` (no kernel ran interpreted).
+argmax parity.
 
 ``--four-chips`` runs only the sharded path and what it is compared
 with: ``compile_donn_train_step_sharded`` for ``donn-xl-500`` on
@@ -50,7 +48,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.core import propagation as pp  # noqa: E402
 from repro.core.cache import use_persistent_compile_cache  # noqa: E402
 from repro.core.models import (  # noqa: E402
     build_model, cached_apply, cached_model, clear_emulation_caches,
@@ -69,9 +66,9 @@ EMULATE_BATCH = 8
 EVAL_BATCH = 64  # = TRAIN_BATCH = SERVE_REQUESTS: one reference program
 # Agreement bound, max |out - ref| / max |ref| over a batch of outputs.
 # Both sides are float32 on the same chip with the same FFTs; they differ
-# only in op order (fused scan vs per-layer loop, Pallas vs XLA cos/sin,
-# the pencil all-to-all vs a local FFT), whose f32 rounding through up to
-# 31 hops of 500^2 FFTs stays near 1e-5.  A bf16 pass anywhere in the
+# only in op order (scan vs per-layer loop, real-DFT matmuls vs FFTs, the
+# pencil all-to-all vs a local FFT), whose f32 rounding through up to 31
+# hops of 500^2 planes stays near 1e-5.  A bf16 pass anywhere in the
 # path rounds values by up to 2^-9 (~2e-3): the detector readout at
 # default precision moved the 200^2 loss by 9e-4 on a v5e, which is why
 # the readouts run at "highest" (repro.core.diffraction.READOUT_PRECISION).
@@ -90,7 +87,7 @@ _REFERENCES: dict = {}
 
 def reference_apply(cfg, params, x):
     """Plain float32 reference: the eager engine, matmuls at "highest"."""
-    ref_cfg = dataclasses.replace(cfg, engine="eager", use_pallas=False)
+    ref_cfg = dataclasses.replace(cfg, engine="eager")
     if ref_cfg not in _REFERENCES:  # one jitted reference per architecture
         _REFERENCES[ref_cfg] = jax.jit(build_model(ref_cfg).apply)
     with jax.default_matmul_precision("highest"):
@@ -111,16 +108,6 @@ def compare(name: str, out, ref) -> bool:
     return ok
 
 
-def require_mosaic(tag: str) -> bool:
-    """Every executable compiled since the last cache clear holds a kernel."""
-    texts = [c.as_text() for c in pp._EXEC_CACHE.values()]
-    n_kernel = sum("tpu_custom_call" in t for t in texts)
-    ok = bool(texts) and n_kernel == len(texts)
-    log(f"{tag}: {n_kernel}/{len(texts)} compiled programs hold a Mosaic "
-        f"tpu_custom_call -> {'ok' if ok else 'FAIL'}")
-    return ok
-
-
 # ---------------------------------------------------------------- phases
 def train_phase(cfg, batch: int, steps_per_call: int, chunks: int):
     """Train from seeded random phases.
@@ -136,7 +123,7 @@ def train_phase(cfg, batch: int, steps_per_call: int, chunks: int):
                                                           seed=1),
                            steps=steps_per_call * chunks, lr=0.1,
                            steps_per_call=steps_per_call)
-    log(f"train {cfg.name} use_pallas={cfg.use_pallas}: "
+    log(f"train {cfg.name}: "
         f"{len(res.losses)} steps in {time.perf_counter() - t0:.1f}s "
         f"(compile included), loss first={res.losses[0]:.6f} "
         f"last={res.losses[-1]:.6f}")
@@ -152,14 +139,13 @@ def check_train(cfg, res, ref_loss, params_trained, x_eval) -> bool:
     finite = bool(np.all(np.isfinite(losses)))
     loss_err = abs(float(losses[0]) - ref_loss) / abs(ref_loss)
     ok = finite and loss_err <= TOL
-    log(f"compare {cfg.name} use_pallas={cfg.use_pallas} first loss: "
+    log(f"compare {cfg.name} first loss: "
         f"{float(losses[0]):.6f} vs reference {ref_loss:.6f} "
         f"rel_err={loss_err:.3e} (tol {TOL:g}) all_finite={finite} "
         f"-> {'ok' if ok else 'FAIL'}")
     out = cached_apply(cfg)(params_trained, x_eval)
     ref = reference_apply(cfg, params_trained, x_eval)
-    return compare(f"{cfg.name} use_pallas={cfg.use_pallas} trained "
-                   f"logits", out, ref) and ok
+    return compare(f"{cfg.name} trained logits", out, ref) and ok
 
 
 def serve_phase(model, params, requests):
@@ -206,8 +192,8 @@ def emulate_phase(cfg, batch: int):
     x, _ = synth_digits(batch, seed=SEED + 1)
     t0 = time.perf_counter()
     out = np.asarray(cached_apply(cfg)(params, x))
-    log(f"emulate {cfg.name} (n={cfg.n}, depth={cfg.depth}, "
-        f"use_pallas={cfg.use_pallas}): forward of batch {batch} -> "
+    log(f"emulate {cfg.name} (n={cfg.n}, depth={cfg.depth}): "
+        f"forward of batch {batch} -> "
         f"{out.shape} in {time.perf_counter() - t0:.1f}s (compile included)")
     return params, x, out
 
@@ -293,31 +279,24 @@ def sharded_serve_phase(cfg, data: int, model_devices: int,
 
 # ------------------------------------------------------------------ main
 def single_chip() -> bool:
-    ok = True
-    base = get_config("donn-mnist-5l")
+    cfg = get_config("donn-mnist-5l")
     x_eval, _ = synth_digits(EVAL_BATCH, seed=SEED + 3)
-    for use_pallas in (False, True):  # the Pallas-trained model is served
-        cfg = dataclasses.replace(base, use_pallas=use_pallas)
-        clear_emulation_caches()
-        model, res, ref_loss = train_phase(cfg, TRAIN_BATCH, STEPS_PER_CALL,
-                                           TRAIN_CHUNKS)
-        params = res.params
-        ok &= check_train(cfg, res, ref_loss, params, x_eval)
-        if use_pallas:
-            ok &= require_mosaic(f"train {cfg.name}")
+    clear_emulation_caches()
+    model, res, ref_loss = train_phase(cfg, TRAIN_BATCH, STEPS_PER_CALL,
+                                       TRAIN_CHUNKS)
+    params = res.params
+    ok = check_train(cfg, res, ref_loss, params, x_eval)
 
     clear_emulation_caches()
     requests, _ = synth_digits(SERVE_REQUESTS, seed=SEED + 4)
     outs, serve_ok = serve_phase(model, params, list(requests))
     ok &= serve_ok
-    ok &= require_mosaic(f"serve {cfg.name}")
     ok &= compare(f"serve {cfg.name} fleet outputs", outs,
                   reference_apply(cfg, params, requests))
 
-    xl = dataclasses.replace(get_config("donn-xl-500"), use_pallas=True)
+    xl = get_config("donn-xl-500")
     clear_emulation_caches()
     params, x, out = emulate_phase(xl, EMULATE_BATCH)
-    ok &= require_mosaic(f"emulate {xl.name}")
     ok &= compare(f"emulate {xl.name} logits", out,
                   reference_apply(xl, params, x))
     return ok

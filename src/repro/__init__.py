@@ -2,7 +2,7 @@
 
 Subpackages:
 - core:    the paper's contribution (optical physics kernels, DSL, DSE, codesign)
-- kernels: Pallas TPU kernels for the paper's hot spots (ComplexMM, readout)
+- kernels: Pallas TPU kernels of the LM stack (RoPE, selective scan)
 - models:  assigned LM-family architectures (dense/MoE/VLM/audio/SSM/hybrid)
 - runtime: distributed runtime (sharding rules, train/serve steps)
 - optim:   optimizers, schedules, gradient compression

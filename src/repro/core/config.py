@@ -109,7 +109,9 @@ class DONNConfig:
     # --- heterogeneous per-layer architecture ---
     layers: Optional[Sequence[LayerSpec]] = None  # per-layer overrides
     # --- runtime ---
-    use_pallas: bool = False  # Pallas kernels for modulation/readout
+    # retired: must be False (the benchmark's configuration files still
+    # name the field); the hop is jnp, packed real-DFT matmuls on a TPU
+    use_pallas: bool = False
     engine: str = "scan"  # "scan" (fused PropagationPlan) | "eager" (per-layer loop)
     input_size: int = 28  # native input image side (embedded/upsampled to n)
     # scan-engine steady-state tuning: unroll factor for the layer scan
@@ -131,6 +133,13 @@ class DONNConfig:
     remat: str = "none"
 
     def __post_init__(self):
+        if self.use_pallas:
+            raise ValueError(
+                "use_pallas=True: the Pallas kernels were removed; the "
+                "propagation hop runs as jnp, on a TPU as the packed or "
+                "folded real-DFT matmul hop (PropagationPlan._packed_hop), "
+                "which replaced them"
+            )
         if self.engine not in ("scan", "eager"):
             raise ValueError(
                 f"engine must be 'scan' or 'eager', got {self.engine!r}"

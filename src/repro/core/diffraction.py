@@ -548,6 +548,6 @@ def readout(u: jax.Array, masks, channel_axis: bool = False) -> jax.Array:
 # per-class region masks).  At default precision a TPU runs that f32
 # contraction as a single bf16 pass, which on a v5e moved the 200^2
 # classifier's logits by 2e-4 and its loss by 9e-4 (relative) against the
-# float32 reference, while the Pallas readout, which sums in f32, agreed to
-# 2e-7.  The contraction is a few MFLOP beside the FFTs, so it stays f32.
+# float32 reference, while a readout summing in f32 agreed to 2e-7.  The
+# contraction is a few MFLOP beside the FFTs, so it stays f32.
 READOUT_PRECISION = jax.lax.Precision.HIGHEST

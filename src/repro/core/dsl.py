@@ -35,6 +35,7 @@ Mixed-precision, mixed-size stack (SLM front end, printed-mask back end):
 """
 from __future__ import annotations
 
+import dataclasses
 from types import SimpleNamespace
 from typing import Optional, Sequence
 
@@ -110,7 +111,7 @@ def _sequential(layer_specs: Sequence[dict], detector_spec: dict,
 
 def _sequential_config(layer_specs: Sequence[dict], detector_spec: dict,
                        laser: Optional[Laser] = None, name: str = "donn-dsl",
-                       gamma: Optional[float] = None, use_pallas: bool = False,
+                       gamma: Optional[float] = None,
                        segmentation: bool = False,
                        skip_from: Optional[int] = None,
                        channels: int = 1, input_size: int = 28,
@@ -157,7 +158,6 @@ def _sequential_config(layer_specs: Sequence[dict], detector_spec: dict,
         segmentation=segmentation,
         skip_from=skip_from,
         layer_norm=segmentation if layer_norm is None else layer_norm,
-        use_pallas=use_pallas,
         input_size=input_size,
         engine=engine,
         scan_unroll=scan_unroll,
@@ -202,7 +202,7 @@ def _sequential_config(layer_specs: Sequence[dict], detector_spec: dict,
 
 
 _SEQUENTIAL_OPTS = (
-    "name", "gamma", "use_pallas", "segmentation", "skip_from", "channels",
+    "name", "gamma", "segmentation", "skip_from", "channels",
     "input_size", "engine", "scan_unroll", "tf_dtype", "remat",
     "layer_norm", "n", "pixel_size",
 )
@@ -219,7 +219,12 @@ def spec_to_config(spec: dict) -> DONNConfig:
     ]
     det = _detector(**{k: v for k, v in spec["detector"].items() if k != "kind"})
     opts = {k: spec[k] for k in _SEQUENTIAL_OPTS if k in spec}
-    return _sequential_config(layer_specs, det, laser=src, **opts)
+    cfg = _sequential_config(layer_specs, det, laser=src, **opts)
+    if "use_pallas" in spec:
+        # specs written before the Pallas path was retired name it;
+        # ``DONNConfig`` refuses a true value
+        cfg = dataclasses.replace(cfg, use_pallas=spec["use_pallas"])
+    return cfg
 
 
 def from_spec(spec: dict):
@@ -276,7 +281,6 @@ def to_spec(cfg: DONNConfig, laser_: Optional[Laser] = None) -> dict:
             distance=cfg.gap_distances()[-1],
         ),
         "gamma": cfg.gamma,
-        "use_pallas": cfg.use_pallas,
         "segmentation": cfg.segmentation,
         "skip_from": cfg.skip_from,
         "channels": cfg.channels,

@@ -39,7 +39,6 @@ class DiffractiveLayer:
         device: Optional[cd.DeviceSpec] = None,
         codesign_mode: str = "none",
         gamma: float = 1.0,
-        use_pallas: bool = False,
     ):
         self.grid = grid
         self.z = z
@@ -49,7 +48,6 @@ class DiffractiveLayer:
         self.device = device
         self.codesign_mode = codesign_mode
         self.gamma = gamma
-        self.use_pallas = use_pallas
         if method == df.FRAUNHOFER:
             self.h = None  # handled by df.fraunhofer at call time
         else:
@@ -85,11 +83,6 @@ class DiffractiveLayer:
         self, phi: jax.Array, u: jax.Array, rng: Optional[jax.Array] = None
     ) -> jax.Array:
         phi_eff = cd.apply_codesign(phi, self.device, self.codesign_mode, rng)
-        if self.use_pallas:
-            from repro.kernels import ops as kops
-
-            ur, ui = kops.phase_apply(u.real, u.imag, phi_eff, self.gamma)
-            return jax.lax.complex(ur, ui)
         mod = self.gamma * jnp.exp(1j * phi_eff.astype(jnp.complex64))
         return u * mod
 
@@ -149,13 +142,11 @@ class Detector:
         layout: str = "grid",
         x_loc=None,
         y_loc=None,
-        use_pallas: bool = False,
     ):
         n = grid.n
         self.grid = grid
         self.num_classes = num_classes
         self.det_size = det_size
-        self.use_pallas = use_pallas
         if x_loc is not None and y_loc is not None:
             coords = list(zip(list(y_loc), list(x_loc)))
         else:
@@ -168,13 +159,6 @@ class Detector:
 
     def __call__(self, u: jax.Array) -> jax.Array:
         """Field (..., n, n) -> per-class intensities (..., C)."""
-        if self.use_pallas:
-            from repro.core.propagation import stage
-            from repro.kernels import ops as kops
-
-            with stage("readout"):
-                return kops.intensity_readout(u.real, u.imag,
-                                              jnp.asarray(self.masks))
         return df.readout(u, jnp.asarray(self.masks))
 
     def intensity_image(self, u: jax.Array) -> jax.Array:

@@ -27,25 +27,18 @@ from repro.core.propagation import plan_from_config
 from repro.nn import ParamSpec, init_params
 
 
-def channel_readout(u: jax.Array, masks, use_pallas: bool) -> jax.Array:
+def channel_readout(u: jax.Array, masks) -> jax.Array:
     """Multi-channel detector accumulation, shared by every path.
 
     (..., C, n, n) per-channel output fields -> (..., num_classes): the
-    incoherent channel sum pooled over the per-class detector regions,
-    through the fused Pallas kernel under ``use_pallas`` or a single jnp
-    contraction otherwise.  One definition serves training
+    incoherent channel sum pooled over the per-class detector regions, as
+    one jnp contraction.  One definition serves training
     (``MultiChannelDONN.apply``, both engines), batched DSE emulation
     (``emulate_batch``) and the deployment engine
-    (``repro.runtime.inference``), so the fallback contraction and kernel
-    routing cannot drift between them.
+    (``repro.runtime.inference``), so the contraction cannot drift between
+    them.
     """
-    masks = jnp.asarray(masks)
-    if use_pallas:
-        from repro.kernels import ops as kops
-
-        with pp.stage("readout"):
-            return kops.channel_intensity_readout(u.real, u.imag, masks)
-    return df.readout(u, masks, channel_axis=True)
+    return df.readout(u, jnp.asarray(masks), channel_axis=True)
 
 
 def _build_layers(cfg: DONNConfig, gamma: float):
@@ -70,7 +63,6 @@ def _build_layers(cfg: DONNConfig, gamma: float):
                                            s.response_gamma),
                 codesign_mode=s.codesign,
                 gamma=gamma,
-                use_pallas=cfg.use_pallas,
             )
         )
     # final free-space hop: last layer -> detector plane (no modulation)
@@ -82,7 +74,6 @@ def _build_layers(cfg: DONNConfig, gamma: float):
         band_limit=cfg.band_limit,
         pad=cfg.pad,
         gamma=1.0,
-        use_pallas=cfg.use_pallas,
     )
     return layers, final
 
@@ -105,7 +96,6 @@ class DONN:
             cfg.num_classes,
             cfg.det_size,
             cfg.detector_layout,
-            use_pallas=cfg.use_pallas,
         )
         self.source = self.laser.field(self.in_grid)  # (n, n) complex64 const
 
@@ -215,7 +205,7 @@ class MultiChannelDONN:
             u = jax.vmap(one_channel, in_axes=(0, -3), out_axes=-3)(
                 params["phase"], x
             )  # (..., C, n, n) per-channel output fields
-            return channel_readout(u, cm.detector.masks, self.cfg.use_pallas)
+            return channel_readout(u, cm.detector.masks)
         # batched plan path: all channels propagate as one (..., C, N, N)
         # tensor through shared kernels (the TFs are channel-independent;
         # the (L, C, N, N) phase stack rides the scan — per segment for
@@ -224,7 +214,7 @@ class MultiChannelDONN:
             params["phase"][f"layer_{i}"] for i in range(len(cm.layers))
         )
         u = cm.plan.apply(phis, cm.encode(x), rng)
-        return channel_readout(u, cm.detector.masks, self.cfg.use_pallas)
+        return channel_readout(u, cm.detector.masks)
 
 
 class SegmentationDONN:
@@ -508,13 +498,12 @@ def _batched_inputs(cfgs, base, gamma: float, template, has_skip: bool):
     if hit is not None:
         return hit
     plans = [pp.plan_from_config(c, gamma) for c in cfgs]
-    k0, k1 = template._plane_keys
     L = template.depth
     tf_a = jnp.asarray(
-        np.stack([_pad_planes(p._np[k0], p.depth, L) for p in plans])
+        np.stack([_pad_planes(p._np["hr"], p.depth, L) for p in plans])
     )
     tf_b = jnp.asarray(
-        np.stack([_pad_planes(p._np[k1], p.depth, L) for p in plans])
+        np.stack([_pad_planes(p._np["hi"], p.depth, L) for p in plans])
     )
     if base.tf_dtype != "float32":
         tf_a = tf_a.astype(base.tf_dtype)
@@ -536,8 +525,8 @@ def _batched_inputs(cfgs, base, gamma: float, template, has_skip: bool):
                 pad=template.pad,
             )
         sk = [_skip_planes(c) for c in cfgs]
-        skip_pair = (jnp.asarray(np.stack([p[k0] for p in sk])),
-                     jnp.asarray(np.stack([p[k1] for p in sk])))
+        skip_pair = (jnp.asarray(np.stack([p["hr"] for p in sk])),
+                     jnp.asarray(np.stack([p["hi"] for p in sk])))
     entry = (tf_a, tf_b, sources, skip_pair)
     lru_put(_BATCH_INPUT_CACHE, key, entry, _BATCH_INPUT_CACHE_MAX)
     return entry
@@ -683,7 +672,7 @@ def emulate_batch(cfgs: Sequence[DONNConfig], params, x, rng=None,
                 return inten
             u = template.apply(p, u, r, tfs=tfs, mask=m)
             if family == "multi":
-                return channel_readout(u, det.masks, base.use_pallas)
+                return channel_readout(u, det.masks)
             return det(u)
 
         per_cand = {k: v for k, v in inp.items() if k != "x"}

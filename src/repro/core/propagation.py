@@ -9,9 +9,7 @@ separate unbatched stacks.  This module replaces that loop with a
 1.  **TF cache** — transfer functions are precomputed once per geometry and
     cached process-wide (LRU), keyed by ``(grid, z, wavelength, method,
     band_limit, pad)``.  They are stored as split real/imag float32 planes
-    (the Pallas kernels are struct-of-arrays) together with the derived
-    polar form ``(arg H, |H|)`` consumed by the fused kernel; band-limit
-    masks and evanescent decay fold into ``|H|``.
+    ``(hr, hi)``; band-limit masks and evanescent decay fold into them.
 2.  **Stacked scan** — all layer TFs and phase maps stack into ``(L, N,
     N)`` tensors and the forward becomes a single ``jax.lax.scan`` whose
     body is traced once: FFT2 -> spectral multiply -> iFFT2 -> phase
@@ -19,14 +17,7 @@ separate unbatched stacks.  This module replaces that loop with a
     (``DONNConfig.scan_unroll``; default from ``default_scan_unroll``) that
     claws back XLA:CPU's while-loop overhead in steady state, and TF planes
     may be stored bf16 with f32 accumulation (``DONNConfig.tf_dtype``).
-3.  **Fused elementwise kernel** — with ``use_pallas`` both elementwise
-    sites in the scan body (the spectral TF multiply and the trainable
-    phase modulation) route through one Pallas kernel,
-    ``repro.kernels.ops.phase_tf_apply``, which performs the cos/sin phase
-    rotation and the amplitude-weighted complex multiply in a single VMEM
-    pass (the TF multiply *is* a phase modulation by ``arg H`` scaled by
-    ``|H|``).
-4.  **Batched channels and candidates** — multi-channel inputs keep their
+3.  **Batched channels and candidates** — multi-channel inputs keep their
     channel axis and propagate as one ``(..., C, N, N)`` tensor through
     shared kernels with ``(L, C, N, N)`` phase stacks
     (``repro.core.models.MultiChannelDONN``).  The same machinery batches
@@ -36,7 +27,7 @@ separate unbatched stacks.  This module replaces that loop with a
     supplied transfer planes (``tfs=...``) so per-candidate *geometries*
     ride the same executable as traced inputs instead of baked constants
     (``repro.core.models.emulate_batch``, the DSE verification path).
-5.  **Plan and executable caches** — ``plan_from_config`` memoizes
+4.  **Plan and executable caches** — ``plan_from_config`` memoizes
     ``PropagationPlan`` instances per geometry tuple and
     ``cached_executable`` memoizes AOT-compiled programs keyed by
     ``(statics, input shapes/dtypes)``; ``plan_cache_stats()`` /
@@ -44,7 +35,7 @@ separate unbatched stacks.  This module replaces that loop with a
     (DSE verification sweeps, sensitivity analysis, codesign loops) stops
     paying trace+compile per candidate.
 
-6.  **Segmented plans for heterogeneous stacks** — configs with per-layer
+5.  **Segmented plans for heterogeneous stacks** — configs with per-layer
     ``LayerSpec`` overrides (mixed plane sizes, pixel sizes, approximation
     methods, codesign devices) compile to a ``SegmentedPlan``: maximal
     runs of fusable layers each become one scan segment, stitched by
@@ -52,12 +43,12 @@ separate unbatched stacks.  This module replaces that loop with a
     keep the single-segment ``PropagationPlan`` (identical HLO and cache
     keys as before).
 
-7.  **Stage scopes** — every stage of the forward (``STAGES``: encode,
-    masks, fft, tf_mul, ifft, modulate, fused_hop, readout, stitch) runs
-    under one ``jax.named_scope`` named ``donn.<stage>``, written where the
-    stage's work is, so every path (train, emulate, DSE, serve; jnp and
-    Pallas) carries it; the training steps add ``loss`` (softmax and MSE)
-    and ``optimizer`` (the parameter update).  Scopes are metadata only:
+6.  **Stage scopes** — every stage of the forward (``STAGES``: encode,
+    masks, fft, tf_mul, ifft, modulate, readout, stitch) runs under one
+    ``jax.named_scope`` named ``donn.<stage>``, written where the stage's
+    work is, so every path (train, emulate, DSE, serve) carries it; the
+    training steps add ``loss`` (softmax and MSE) and ``optimizer`` (the
+    parameter update).  Scopes are metadata only:
     ``stage_map()`` reads them back from each cached executable's HLO,
     which is how a profiler trace's device ops are joined to stages.
     ``compile_stats()`` counts the process's backend compiles and
@@ -66,13 +57,13 @@ separate unbatched stacks.  This module replaces that loop with a
     which do nothing while no profiler runs) mark compiles, forward
     launches and training-chunk launches.
 
-8.  **Packed real-DFT hop** — on a TPU, at the plane sizes where it
-    measured faster, the plain hop of the jnp path runs as four real
-    matmul stages on the MXU instead of XLA's FFTs (``_packed_hop``); it
-    needs every transfer plane even in each frequency axis, checked when
-    the plan is built.  From N = 385 (``_folded_hop_applies``) it runs
-    folded into even and odd halves, each stage contracting half the
-    plane (``_folded_hop``, built on ``diffraction.folded_dft_matrices``):
+7.  **Packed real-DFT hop** — on a TPU, at the plane sizes where it
+    measured faster, the plain hop runs as four real matmul stages on the
+    MXU instead of XLA's FFTs (``_packed_hop``); it needs every transfer
+    plane even in each frequency axis, checked when the plan is built.
+    From N = 385 (``_folded_hop_applies``) it runs folded into even and
+    odd halves, each stage contracting half the plane (``_folded_hop``,
+    built on ``diffraction.folded_dft_matrices``):
     ``forward`` folds a field once and scans the folded blocks, each
     layer's modulation mixing them (``diffraction.modulate_folded``).
     ``hop_path_stats()`` counts the hops traced on each path.
@@ -128,7 +119,7 @@ _cache_put = lru_put
 # a compiled op's op_name is the stage that op belongs to.
 STAGE_PREFIX = "donn."
 STAGES = ("encode", "masks", "fft", "tf_mul", "ifft", "modulate",
-          "fused_hop", "readout", "stitch", "loss", "optimizer")
+          "readout", "stitch", "loss", "optimizer")
 COMPILE_SPAN = STAGE_PREFIX + "compile"
 DISPATCH_SPAN = STAGE_PREFIX + "dispatch"
 TRAIN_DISPATCH_SPAN = STAGE_PREFIX + "train_dispatch"
@@ -173,9 +164,9 @@ def compile_stats() -> dict:
 
 
 # Hops traced into programs, by path: "packed" (the real-DFT matmul hop)
-# or "fft" (jnp.fft, the Pallas fused hop included); "folded" counts the
-# packed hops that run folded (``diffraction.folded_dft_matrices``).  A scan
-# adds its length once per trace.
+# or "fft" (jnp.fft); "folded" counts the packed hops that run folded
+# (``diffraction.folded_dft_matrices``).  A scan adds its length once per
+# trace.
 _HOP_STATS = {"packed": 0, "fft": 0, "folded": 0}
 
 
@@ -268,10 +259,9 @@ def transfer_planes(grid: df.Grid, z: float, wavelength: float,
                     pad: bool = False) -> dict:
     """Cached split-plane transfer function for one propagation gap.
 
-    Returns {"hr", "hi", "theta", "amp"} float32 numpy arrays on the
-    (possibly padded) grid; for ``method="fraunhofer"`` the planes describe
-    the far-field quadratic output factor instead (its amplitude carries
-    the 1/(lambda z) scaling, so the polar form covers it too).
+    Returns {"hr", "hi"} float32 numpy arrays on the (possibly padded)
+    grid; for ``method="fraunhofer"`` the planes describe the far-field
+    quadratic output factor instead (it carries the 1/(lambda z) scaling).
     """
     key = tf_cache_key(grid, z, wavelength, method, band_limit, pad)
     hit = _cache_get(_TF_CACHE, key, _TF_STATS)
@@ -285,8 +275,6 @@ def transfer_planes(grid: df.Grid, z: float, wavelength: float,
     entry = {
         "hr": np.ascontiguousarray(h.real.astype(np.float32)),
         "hi": np.ascontiguousarray(h.imag.astype(np.float32)),
-        "theta": np.angle(h).astype(np.float32),
-        "amp": np.abs(h).astype(np.float32),
     }
     _cache_put(_TF_CACHE, key, entry, _TF_CACHE_MAX)
     return entry
@@ -488,7 +476,6 @@ class PropagationPlan:
         gamma: float = 1.0,
         device: Optional[cd.DeviceSpec] = None,
         codesign_mode: str = "none",
-        use_pallas: bool = False,
         unroll: Optional[int] = None,
         tf_dtype: str = "float32",
         final_hop: bool = True,
@@ -521,33 +508,20 @@ class PropagationPlan:
         self.gamma = float(gamma)
         self.device = device
         self.codesign_mode = codesign_mode
-        self.use_pallas = use_pallas
         self.unroll = unroll
         self.tf_dtype = tf_dtype
         self.remat = remat
-        # split-plane pair consumed by the scan body: polar for the fused
-        # Pallas kernel, cartesian for the jnp path
-        self._plane_keys = ("theta", "amp") if use_pallas else ("hr", "hi")
-        # whole-hop fusion (kernels.ops.fused_spectral_hop): TF multiply +
-        # modulation as one VMEM pass per FFT side.  Needs the polar plane
-        # convention and the plain fft2/ifft2 hop structure — fraunhofer
-        # (single shifted FFT) and padded hops keep the two-site path.
-        self._fuse = bool(use_pallas) and method != df.FRAUNHOFER \
-            and not self.pad
         planes = [
             transfer_planes(grid, z, wavelength, method, band_limit, self.pad)
             for z in self.gaps
         ]
-        # the plain hop of the jnp path may run as the packed real-DFT hop
-        # (``_packed_hop``), which needs every plane even in each axis
-        self._packable = (not use_pallas and method != df.FRAUNHOFER
-                          and not self.pad
+        # the plain hop may run as the packed real-DFT hop (``_packed_hop``),
+        # which needs every plane even in each axis
+        self._packable = (method != df.FRAUNHOFER and not self.pad
                           and all(df.is_even_plane(p[k]) for p in planes
                                   for k in ("hr", "hi")))
         # stacked numpy constants; uploaded lazily (imports stay device-free)
-        self._np = {
-            k: np.stack([p[k] for p in planes]) for k in self._plane_keys
-        }
+        self._np = {k: np.stack([p[k] for p in planes]) for k in ("hr", "hi")}
         self._jax: dict = {}
 
     # --- constants ---
@@ -567,8 +541,7 @@ class PropagationPlan:
 
     def _tf_pair(self) -> tuple:
         """Full (depth+1, N, N) split-plane stacks (baked constants)."""
-        return (self._const(self._plane_keys[0]),
-                self._const(self._plane_keys[1]))
+        return self._const("hr"), self._const("hi")
 
     # --- elementwise sites ---
     @stage("tf_mul")
@@ -577,50 +550,12 @@ class PropagationPlan:
         a, b = pair
         a = a.astype(jnp.float32)
         b = b.astype(jnp.float32)
-        if not self.use_pallas:
-            return s * jax.lax.complex(a, b)  # (hr, hi)
-        from repro.kernels import ops as kops
-
-        tr, ti = kops.phase_tf_apply(s.real, s.imag, a, b)  # (theta, amp)
-        return jax.lax.complex(tr, ti)
+        return s * jax.lax.complex(a, b)  # (hr, hi)
 
     @stage("modulate")
     def _modulate(self, u: jax.Array, phi: jax.Array) -> jax.Array:
         """gamma * u * exp(j phi); phi (N, N) or per-channel (C, N, N)."""
-        if not self.use_pallas:
-            return u * (self.gamma * jnp.exp(1j * phi.astype(jnp.complex64)))
-        from repro.kernels import ops as kops
-
-        amp = jnp.full(phi.shape, self.gamma, phi.dtype)
-        ur, ui = kops.phase_tf_apply(u.real, u.imag, phi, amp)
-        return jax.lax.complex(ur, ui)
-
-    @stage("fused_hop")
-    def _fused_layer(self, u: jax.Array, tf_pair, mod=None,
-                     phi=None) -> jax.Array:
-        """One whole modulated layer as the fused spectral-hop kernel.
-
-        ``M . ifft2(Hc . fft2(u))`` with both elementwise sites (TF
-        multiply, modulation) fused into one VMEM pass per FFT side
-        (``kernels.ops.fused_spectral_hop``).  ``tf_pair`` is the polar
-        ``(arg H, |H|)`` pair (possibly bf16 storage — upcast here, f32
-        accumulation); the modulation is either a trainable phase ``phi``
-        (amp = gamma, the custom VJP carries d phi) or a frozen polar
-        ``mod`` pair from ``frozen_modulation``.  TF planes are static
-        geometry: their cotangents are zero, exactly like the ``amp``
-        argument of ``phase_tf_apply``.
-        """
-        from repro.kernels import ops as kops
-
-        th_h, amp_h = (p.astype(jnp.float32) for p in tf_pair)
-        if phi is not None:
-            th_m = phi
-            amp_m = jnp.full(phi.shape, self.gamma, jnp.float32)
-        else:
-            th_m, amp_m = mod
-        ur, ui = kops.fused_spectral_hop(u.real, u.imag, th_h, amp_h,
-                                         th_m, amp_m)
-        return jax.lax.complex(ur, ui)
+        return u * (self.gamma * jnp.exp(1j * phi.astype(jnp.complex64)))
 
     @stage("modulate")
     def _modulate_frozen(self, u: jax.Array, pair) -> jax.Array:
@@ -628,18 +563,12 @@ class PropagationPlan:
 
         The deployment fast path: codesign response and ``gamma * exp(j
         theta)`` were folded once at freeze time (``frozen_modulation``),
-        so per-request work is a single fused multiply — the polar pair
-        feeds the fused Pallas kernel directly, the cartesian pair a bare
-        complex multiply.  Numerics are bit-identical to ``_modulate`` on
-        the codesign-resolved phase (same kernels, same operand values).
+        so per-request work is a single complex multiply.  Numerics are
+        bit-identical to ``_modulate`` on the codesign-resolved phase (same
+        operand values).
         """
         a, b = pair
-        if not self.use_pallas:
-            return u * jax.lax.complex(a, b)  # (mr, mi) = gamma * exp(j phi)
-        from repro.kernels import ops as kops
-
-        ur, ui = kops.phase_tf_apply(u.real, u.imag, a, b)  # (theta, amp)
-        return jax.lax.complex(ur, ui)
+        return u * jax.lax.complex(a, b)  # (mr, mi) = gamma * exp(j phi)
 
     def frozen_modulation(self, phis: jax.Array,
                           plane_dtype: str = "float32") -> tuple:
@@ -649,9 +578,7 @@ class PropagationPlan:
         response is resolved rng-free (``codesign.deployed_phase`` — the
         statically-known state the fabricated hardware holds) and the
         modulation ``gamma * exp(j phi_eff)`` is precomputed into a split
-        plane pair in the plan's kernel convention: polar ``(theta, amp)``
-        consumed directly by the fused Pallas kernels under ``use_pallas``,
-        cartesian ``(mr, mi)`` for the jnp path.  Feed the result to
+        plane pair ``(mr, mi)``.  Feed the result to
         ``forward``/``apply`` via ``frozen=`` — the per-request hot path
         then skips phase-stack construction, quantization and codesign rng
         entirely (bit-identical to the training-path forward at eval,
@@ -667,8 +594,6 @@ class PropagationPlan:
         @stage("masks")
         def fold(p):
             eff = self._codesign_stack(p, None)
-            if self.use_pallas:
-                return eff, jnp.full(eff.shape, self.gamma, eff.dtype)
             m = self.gamma * jnp.exp(1j * eff.astype(jnp.complex64))
             return m.real, m.imag
 
@@ -835,7 +760,7 @@ class PropagationPlan:
         frozen: optional precomputed (L, ...) modulation plane pair from
         ``frozen_modulation`` — the deployment fast path.  With it the
         scan skips phase-stack codesign (quantization, rng) entirely and
-        each layer is one hop plus one fused multiply; ``phis``/``rngs``/
+        each layer is one hop plus one complex multiply; ``phis``/``rngs``/
         ``mask`` are ignored (pass None);
         final: also run the last free-space hop (``propagate_final``), on
         the scan's carry as it is (``stop`` must be the depth).
@@ -856,13 +781,8 @@ class PropagationPlan:
         if pre is not None:
             u = pre(u)
         a, b = self._tf_pair() if tfs is None else tfs
-        # whole-hop fusion applies whenever the body is the plain
-        # fft2 -> multiply -> ifft2 -> modulate chain on local spectra
-        fuse = self._fuse and spectral is None
         fold = self._folds(spectral)
         hops = stop - start
-        if fuse:
-            _HOP_STATS["fft"] += hops
         if frozen is not None:
             mask = None
             mods = tuple(f[start:stop] for f in frozen)
@@ -890,14 +810,12 @@ class PropagationPlan:
                 with stage("modulate"):
                     new = df.modulate_folded(new, mod[0])
             elif frozen is not None:
-                mod = dequant_frozen_layer(mod)
-                new = (self._fused_layer(carry, tf, mod=mod) if fuse
-                       else self._modulate_frozen(
-                           self._hop(carry, tf, spectral, hops=hops), mod))
+                new = self._modulate_frozen(
+                    self._hop(carry, tf, spectral, hops=hops),
+                    dequant_frozen_layer(mod))
             else:
-                new = (self._fused_layer(carry, tf, phi=mod[0]) if fuse
-                       else self._modulate(
-                           self._hop(carry, tf, spectral, hops=hops), mod[0]))
+                new = self._modulate(
+                    self._hop(carry, tf, spectral, hops=hops), mod[0])
             if mask is not None:
                 new = jnp.where(layer_xs[-1], new, carry)
             return new, None
@@ -1117,7 +1035,6 @@ class SegmentedPlan:
                 device=cd.device_for_layer(s0.codesign, s0.device_levels,
                                            s0.response_gamma),
                 codesign_mode=s0.codesign,
-                use_pallas=cfg.use_pallas,
                 unroll=cfg.scan_unroll,
                 tf_dtype=cfg.tf_dtype,
                 final_hop=last,
@@ -1245,13 +1162,12 @@ def plan_cache_key(cfg, gamma: float) -> tuple:
         return ("seg", per_layer, cfg.n, float(cfg.pixel_size),
                 float(cfg.distance), float(cfg.wavelength),
                 bool(cfg.band_limit), bool(cfg.pad), float(gamma),
-                bool(cfg.use_pallas), cfg.scan_unroll, cfg.tf_dtype,
-                cfg.remat)
+                cfg.scan_unroll, cfg.tf_dtype, cfg.remat)
     dev = device_spec_from_config(cfg)
     return (cfg.n, float(cfg.pixel_size), cfg.gap_distances(),
             float(cfg.wavelength), cfg.approximation, bool(cfg.band_limit),
             bool(cfg.pad), float(gamma), dev, cfg.codesign,
-            bool(cfg.use_pallas), cfg.scan_unroll, cfg.tf_dtype, cfg.remat)
+            cfg.scan_unroll, cfg.tf_dtype, cfg.remat)
 
 
 def plan_from_config(cfg, gamma: float):
@@ -1287,7 +1203,6 @@ def plan_from_config(cfg, gamma: float):
             gamma=gamma,
             device=dev,
             codesign_mode=cfg.codesign,
-            use_pallas=cfg.use_pallas,
             unroll=cfg.scan_unroll,
             tf_dtype=cfg.tf_dtype,
             remat=cfg.remat,

@@ -1,55 +1,7 @@
-"""Pure-jnp oracles for every Pallas kernel in this package."""
+"""Pure-jnp oracle for the RoPE Pallas kernel."""
 from __future__ import annotations
 
 import jax.numpy as jnp
-
-
-def complex_mul_ref(ar, ai, br, bi):
-    """(a_r + j a_i) * (b_r + j b_i), split-plane complex multiply.
-
-    b broadcasts against a (e.g. a: (B, H, W), b: (H, W)).
-    """
-    return ar * br - ai * bi, ar * bi + ai * br
-
-
-def phase_apply_ref(ur, ui, phi, gamma=1.0):
-    """gamma * u * exp(j phi): the paper's phase-modulation hot spot (Eq. 9)."""
-    c = jnp.cos(phi) * gamma
-    s = jnp.sin(phi) * gamma
-    return ur * c - ui * s, ur * s + ui * c
-
-
-def phase_tf_apply_ref(xr, xi, theta, amp):
-    """x * amp * exp(j theta): fused phase rotation + amplitude multiply.
-
-    theta/amp broadcast against x (e.g. x: (B, H, W), theta/amp: (H, W)).
-    Covers both propagation-engine call sites: trainable phase planes
-    (theta=phi, amp=gamma) and spectral transfer functions (theta=arg H,
-    amp=|H|, which absorbs band-limit masks and evanescent decay).
-    """
-    c = jnp.cos(theta) * amp
-    s = jnp.sin(theta) * amp
-    return xr * c - xi * s, xr * s + xi * c
-
-
-def fused_spectral_hop_ref(x, theta_h, amp_h, theta_m, amp_m):
-    """One propagation hop + modulation: M . ifft2(Hc . fft2(x)).
-
-    x: complex (..., H, W); planes broadcast against x.  Hc = amp_h *
-    exp(j theta_h) is the (band-limited) spectral transfer function, M =
-    amp_m * exp(j theta_m) the modulation plane (gamma/codesign folded
-    into amp_m).  This is the unfused four-op hop the Pallas kernel
-    (`ops.fused_spectral_hop`) collapses to two FFTs + two fused passes.
-    """
-    hc = amp_h * jnp.exp(1j * theta_h.astype(jnp.complex64))
-    m = amp_m * jnp.exp(1j * theta_m.astype(jnp.complex64))
-    return m * jnp.fft.ifft2(hc * jnp.fft.fft2(x))
-
-
-def intensity_readout_ref(ur, ui, masks):
-    """|u|^2 pooled per detector region: (B,H,W)x(C,H,W) -> (B,C)."""
-    inten = ur * ur + ui * ui
-    return jnp.einsum("bhw,chw->bc", inten, masks)
 
 
 def rope_ref(x, cos, sin):

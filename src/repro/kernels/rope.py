@@ -1,8 +1,7 @@
 """Pallas kernel: rotary position embedding (rotate-half convention).
 
-RoPE is the same computation as the paper's phase-modulation hot spot —
-an elementwise complex rotation (DESIGN.md §3) — so it shares this kernel
-family.  x is viewed as (x1 + j x2) pairs and rotated by exp(j theta_s,d):
+RoPE is an elementwise complex rotation: x is viewed as (x1 + j x2) pairs
+and rotated by exp(j theta_s,d):
 
     out1 = x1 cos - x2 sin,  out2 = x2 cos + x1 sin
 

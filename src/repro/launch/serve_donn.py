@@ -54,7 +54,6 @@ def build_cfg(args) -> DONNConfig:
         name=f"serve-{args.family}", n=args.n, depth=args.depth,
         distance=args.distance, det_size=args.det_size,
         codesign=args.codesign, response_gamma=args.response_gamma,
-        use_pallas=args.use_pallas,
     )
     if args.family == "rgb":
         kw["channels"] = 3
@@ -75,7 +74,6 @@ def main(argv=None):
     ap.add_argument("--codesign", default="qat")
     ap.add_argument("--response-gamma", type=float, default=1.2,
                     help="nonlinear device response (1.0 = ideal)")
-    ap.add_argument("--use-pallas", action="store_true")
     ap.add_argument("--train-steps", type=int, default=0,
                     help="quick-train on synth digits before freezing")
     ap.add_argument("--requests", type=int, default=256)

@@ -107,30 +107,20 @@ def rope_angles(cfg: LMConfig, positions: jax.Array):
     return jnp.cos(ang), jnp.sin(ang)
 
 
-def apply_rotary(x, cos, sin, cfg: LMConfig, use_pallas: bool = False):
+def apply_rotary(x, cos, sin, cfg: LMConfig):
     """x: (B, S, H, Dh); cos/sin: (B?, S, rot//2). Rotate-half convention.
 
     Partial rotary (glm4): only the first ``rot`` features rotate.
     """
     rot = 2 * cos.shape[-1]
     xr, xp = x[..., :rot], x[..., rot:]
-    if use_pallas:
-        from repro.kernels import ops as kops
-
-        b, s, h, d = xr.shape
-        # kernel expects (..., S, D): fold heads into batch
-        xk = jnp.swapaxes(xr, 1, 2).reshape(b * h, s, d)
-        ck = cos if cos.ndim == 2 else cos[0]
-        out = kops.apply_rope(xk, ck.astype(x.dtype), (sin if sin.ndim == 2 else sin[0]).astype(x.dtype))
-        xr = jnp.swapaxes(out.reshape(b, h, s, d), 1, 2)
-    else:
-        half = rot // 2
-        x1, x2 = xr[..., :half], xr[..., half:]
-        c = cos[..., None, :].astype(x.dtype)  # (B?, S, 1, half)
-        s = sin[..., None, :].astype(x.dtype)
-        if c.ndim == 3:  # (S, 1, half) -> broadcast over batch
-            c, s = c[None], s[None]
-        xr = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+    half = rot // 2
+    x1, x2 = xr[..., :half], xr[..., half:]
+    c = cos[..., None, :].astype(x.dtype)  # (B?, S, 1, half)
+    s = sin[..., None, :].astype(x.dtype)
+    if c.ndim == 3:  # (S, 1, half) -> broadcast over batch
+        c, s = c[None], s[None]
+    xr = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
     if xp.shape[-1] == 0:
         return xr
     return jnp.concatenate([xr, xp], axis=-1)

@@ -241,10 +241,6 @@ def _check_sharded_support(cfg: DONNConfig) -> None:
             "stochastic codesign draws per-element noise: row shards "
             "would sample different streams than the single-device step"
         )
-    if cfg.use_pallas:
-        raise NotImplementedError(
-            "the fused Pallas kernels operate on full planes"
-        )
     if cfg.tf_dtype != "float32":
         raise NotImplementedError(
             "spatial sharding reads the plan's f32 TF planes; the bf16 "
@@ -255,8 +251,7 @@ def _check_sharded_support(cfg: DONNConfig) -> None:
 
 def _plan_tf_stacks(plan):
     """The plan's baked split TF planes as traced shard_map operands."""
-    key_a, key_b = plan._plane_keys
-    return jnp.asarray(plan._np[key_a]), jnp.asarray(plan._np[key_b])
+    return jnp.asarray(plan._np["hr"]), jnp.asarray(plan._np["hi"])
 
 
 def make_donn_sharded_loss(cfg: DONNConfig, mesh, rules=None):

@@ -13,10 +13,9 @@ folds it out of the hot path entirely:
 1.  **Frozen artifact** — ``freeze(model, params)`` resolves the codesign
     device response once (``codesign.deployed_phase``) and precomputes the
     ``gamma * exp(j theta)`` modulation planes per layer
-    (``PropagationPlan.frozen_modulation``), in the kernel's native
-    convention (polar for the fused ``phase_tf_apply`` Pallas kernel,
-    cartesian split planes for the jnp path).  Per-request work shrinks to
-    the FFT hops plus one fused multiply per layer, via the
+    (``PropagationPlan.frozen_modulation``), as cartesian split planes.
+    Per-request work shrinks to the hops plus one complex multiply per
+    layer, via the
     ``forward(frozen=...)`` fast path — bit-identical to the training-path
     forward at eval (tests/test_inference.py).
 2.  **Bucketed AOT executables** — one compiled program per batch bucket,
@@ -160,8 +159,7 @@ class DeployedDONN:
         if self.family == "multi":
             from repro.core.models import channel_readout
 
-            return channel_readout(u, self.detector.masks,
-                                   self.cfg.use_pallas)
+            return channel_readout(u, self.detector.masks)
         return self.detector(u)
 
     def static_key(self) -> tuple:
@@ -315,10 +313,6 @@ class InferenceEngine:
                     "rfft_first's half-spectrum entry hop is not row-"
                     "shardable; freeze with rfft_first=False to serve "
                     "model-parallel"
-                )
-            if cfg.use_pallas:
-                raise NotImplementedError(
-                    "the fused Pallas kernels operate on full planes"
                 )
             if cfg.pad or any(l.approximation == "fraunhofer"
                               for l in cfg.resolved_layers()):

@@ -212,29 +212,21 @@ def poison_batches(it: Iterator, poison_steps: Iterable[int],
 # --------------------------------------------------------------------------
 # Physics faults: frozen modulation-plane non-idealities
 # --------------------------------------------------------------------------
-def _perturb_pair(pair, rng, use_pallas: bool, phase_sigma: float,
-                  dead_frac: float, shift_px: int):
+def _perturb_pair(pair, rng, phase_sigma: float, dead_frac: float,
+                  shift_px: int):
     a, b = (np.asarray(p) for p in pair)
     if phase_sigma or dead_frac:
-        # recover (phase, amplitude): the pallas convention stores them
-        # directly; the jnp convention stores cartesian gamma*exp(j theta)
-        if use_pallas:
-            theta, amp = a.astype(np.float64), b.astype(np.float64)
-        else:
-            theta = np.arctan2(b.astype(np.float64), a.astype(np.float64))
-            amp = np.hypot(a, b).astype(np.float64)
+        # recover (phase, amplitude) from the cartesian gamma*exp(j theta)
+        theta = np.arctan2(b.astype(np.float64), a.astype(np.float64))
+        amp = np.hypot(a, b).astype(np.float64)
         if phase_sigma:
             theta = theta + rng.normal(0.0, phase_sigma, theta.shape)
         if dead_frac:
             # dead SLM pixels: stuck at phase 0, amplitude response intact
             theta = np.where(rng.random(theta.shape) < dead_frac, 0.0, theta)
-        if use_pallas:
-            a, b = theta, amp
-        else:
-            a, b = amp * np.cos(theta), amp * np.sin(theta)
+        a, b = amp * np.cos(theta), amp * np.sin(theta)
     if shift_px:
-        # lateral misalignment: roll both planes along the last axis —
-        # identical in either split convention
+        # lateral misalignment: roll both planes along the last axis
         a = np.roll(a, shift_px, axis=-1)
         b = np.roll(b, shift_px, axis=-1)
     return (np.asarray(a, np.float32), np.asarray(b, np.float32))
@@ -261,13 +253,11 @@ def perturb_frozen(deployed, *, phase_sigma: float = 0.0,
     from repro.runtime.inference import DeployedDONN
 
     rng = np.random.default_rng(seed)
-    use_pallas = bool(deployed.cfg.use_pallas)
 
     def one(pair):
         if not (phase_sigma or dead_frac or shift_px):
             return pair
-        a, b = _perturb_pair(pair, rng, use_pallas, phase_sigma,
-                             dead_frac, shift_px)
+        a, b = _perturb_pair(pair, rng, phase_sigma, dead_frac, shift_px)
         return (jnp.asarray(a), jnp.asarray(b))
 
     if deployed.heterogeneous:

@@ -7,7 +7,11 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.core import DONNConfig, build_model
 from repro.core import diffraction as df
+from repro.core import propagation as pp
+from repro.core.layers import DiffractiveLayer
+from repro.core.models import channel_readout
 
 WL = 532e-9
 PX = 36e-6
@@ -190,3 +194,205 @@ class TestFresnelShiftPrefold:
         h = df.transfer_function(g, 0.02, WL, df.FRESNEL, band_limit=False,
                                  pad=True)
         np.testing.assert_array_equal(np.fft.ifftshift(hc), h)
+
+
+# --------------------------------------------------------------------------
+# Elementwise stages of the forward against numpy oracles: the detector
+# readout, the phase modulation (plan and eager layer), and the modulation
+# of a field's folded blocks (the folded hop's ``modulate`` stage).
+# --------------------------------------------------------------------------
+SHAPES = [(1, 8, 128), (2, 64, 128), (3, 200, 200), (1, 37, 111), (2, 17, 513)]
+
+
+def _planes(shape, seed):
+    r = np.random.default_rng(seed)
+    return r.normal(size=shape), r.normal(size=shape)
+
+
+def _cfield(shape, seed):
+    ur, ui = _planes(shape, seed)
+    return ur + 1j * ui
+
+
+def _assert_close(got, want, rtol):
+    got, want = np.asarray(got), np.asarray(want)
+    np.testing.assert_allclose(got, want, rtol=rtol,
+                               atol=rtol * np.abs(want).max())
+
+
+def _readout_ref(u, masks):
+    """|u|^2 pooled per detector region, float64: (B, H, W) x (C, H, W)."""
+    return np.einsum("bhw,chw->bc", np.abs(u) ** 2, masks)
+
+
+class TestReadout:
+    @pytest.mark.parametrize("shape", SHAPES)
+    @pytest.mark.parametrize("classes", [3, 10])
+    def test_matches_oracle(self, shape, classes):
+        B, H, W = shape
+        u = _cfield(shape, 1)
+        masks = (np.random.default_rng(3).random((classes, H, W)) < 0.1
+                 ).astype(np.float32)
+        got = df.readout(jnp.asarray(u, jnp.complex64), jnp.asarray(masks))
+        assert got.shape == (B, classes)
+        _assert_close(got, _readout_ref(u, masks), 1e-5)
+
+    def test_partition_sums_to_total(self):
+        """Masks that partition the plane => per-class sums add to total."""
+        u = jnp.asarray(_cfield((2, 32, 128), 4), jnp.complex64)
+        labels = np.random.default_rng(6).integers(0, 4, (32, 128))
+        masks = jnp.asarray(np.stack([labels == c for c in range(4)]),
+                            jnp.float32)
+        out = df.readout(u, masks)
+        np.testing.assert_allclose(jnp.sum(out, -1),
+                                   jnp.sum(df.intensity(u), axis=(1, 2)),
+                                   rtol=1e-5)
+
+    def test_gradients(self):
+        ur, ui = (jnp.asarray(p, jnp.float32) for p in _planes((2, 16, 128), 7))
+        masks = jnp.asarray(
+            np.random.default_rng(8).random((3, 16, 128)) < 0.5, jnp.float32)
+        g = jax.grad(lambda a, b: jnp.sum(
+            df.readout(jax.lax.complex(a, b), masks)), argnums=(0, 1))(ur, ui)
+        # d/du sum_c <masks_c, |u|^2> = 2 u sum_c masks_c
+        w = jnp.sum(masks, axis=0)
+        np.testing.assert_allclose(g[0], 2 * ur * w, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(g[1], 2 * ui * w, rtol=1e-5, atol=1e-5)
+
+
+class TestChannelReadout:
+    """The multi-channel detector accumulation (``models.channel_readout``):
+    per-channel intensities summed incoherently over the shared detector."""
+
+    def test_matches_oracle(self):
+        r = np.random.default_rng(0)
+        u = _cfield((2, 3, 40, 40), 1)
+        masks = (r.random((5, 40, 40)) > 0.7).astype(np.float32)
+        got = channel_readout(jnp.asarray(u, jnp.complex64), masks)
+        want = np.einsum("bdhw,chw->bc", np.abs(u) ** 2, masks)
+        _assert_close(got, want, 1e-5)
+
+    def test_single_sample(self):
+        u = _cfield((3, 16, 16), 2)
+        masks = np.ones((2, 16, 16), np.float32)
+        got = channel_readout(jnp.asarray(u, jnp.complex64), masks)
+        _assert_close(got, np.full((2,), np.sum(np.abs(u) ** 2)), 1e-5)
+
+    def test_gradients_flow_through_channel_sum(self):
+        ur, ui = (jnp.asarray(p, jnp.float32) for p in _planes((1, 2, 16, 16),
+                                                               3))
+        masks = jnp.ones((1, 16, 16), jnp.float32)
+        da, db = jax.grad(lambda a, b: jnp.sum(channel_readout(
+            jax.lax.complex(a, b), masks)), argnums=(0, 1))(ur, ui)
+        np.testing.assert_allclose(da, 2 * ur, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(db, 2 * ui, rtol=1e-5, atol=1e-5)
+
+    def test_eager_multichannel_model_reads_out_its_fields(self):
+        """The eager RGB model's logits are the oracle readout of its
+        per-channel output fields."""
+        cfg = DONNConfig(name="mc-eager", n=24, depth=2, distance=0.05,
+                         det_size=4, channels=3, engine="eager")
+        m = build_model(cfg)
+        params = m.init(jax.random.PRNGKey(0))
+        x = np.random.default_rng(3).random((2, 3, 24, 24), np.float32)
+        cm = m.channel_model
+        fields = jax.vmap(
+            lambda ph, xc: cm.fields({"phase": ph}, xc, None)[-1],
+            in_axes=(0, -3), out_axes=-3)(params["phase"], jnp.asarray(x))
+        want = np.einsum("bdhw,chw->bc", np.abs(np.asarray(fields)) ** 2,
+                         cm.detector.masks)
+        _assert_close(m.apply(params, x), want, 1e-5)
+
+
+def _modulation_sites(gamma):
+    """The plan's and the eager layer's phase modulation, (phi, u) -> u'."""
+    plan = pp.PropagationPlan(df.Grid(8, PX), [0.05], WL, gamma=gamma)
+    layer = DiffractiveLayer(df.Grid(8, PX), 0.05, WL, gamma=gamma)
+    return {"plan": lambda phi, u: plan._modulate(u, phi),
+            "eager": lambda phi, u: layer.modulate(phi, u)}
+
+
+class TestModulation:
+    @pytest.mark.parametrize("shape", SHAPES)
+    def test_matches_oracle(self, shape):
+        B, H, W = shape
+        u = _cfield(shape, 1)
+        phi = np.random.default_rng(3).uniform(0, 6.28, (H, W))
+        want = 1.3 * u * np.exp(1j * phi)
+        for name, fn in _modulation_sites(1.3).items():
+            got = fn(jnp.asarray(phi, jnp.float32),
+                     jnp.asarray(u, jnp.complex64))
+            _assert_close(got, want, 1e-5)
+
+    def test_unitary_when_gamma_one(self):
+        u = jnp.asarray(_cfield((2, 32, 128), 4), jnp.complex64)
+        phi = jnp.asarray(_planes((32, 128), 6)[0], jnp.float32)
+        for name, fn in _modulation_sites(1.0).items():
+            np.testing.assert_allclose(df.intensity(fn(phi, u)),
+                                       df.intensity(u), rtol=1e-5, atol=1e-5)
+
+    def test_gradients_match_reference(self):
+        ur, ui = (jnp.asarray(p, jnp.float32) for p in _planes((2, 24, 96), 7))
+        phi = jnp.asarray(_planes((24, 96), 9)[0], jnp.float32)
+
+        def split_ref(p):
+            # gamma * u * exp(j phi) on split planes
+            c, s = 1.1 * jnp.cos(p), 1.1 * jnp.sin(p)
+            return ur * c - ui * s, ur * s + ui * c
+
+        def loss(a, b):
+            return jnp.sum(jnp.sin(a) + b * b)
+
+        want = jax.grad(lambda p: loss(*split_ref(p)))(phi)
+        for name, fn in _modulation_sites(1.1).items():
+            got = jax.grad(lambda p: loss(
+                *(lambda v: (v.real, v.imag))(fn(p, jax.lax.complex(ur, ui)))
+            ))(phi)
+            np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+# the folded blocks need square planes: SHAPES' batch and row counts, an
+# odd N among them
+FOLD_SHAPES = [(b, h, h) for b, h, _ in SHAPES]
+
+
+def _folded_product(u, mod):
+    """unfold(modulate_folded(fold(u), fold_modulation(mod)))."""
+    n = u.shape[-1]
+    x = df.modulate_folded(df.fold_field(u), df.fold_modulation(mod[None])[0])
+    return df.unfold_field(x, n)
+
+
+class TestFoldedModulation:
+    """``diffraction.modulate_folded`` (the folded hop's ``modulate``
+    stage) against unfold -> multiply -> fold."""
+
+    @pytest.mark.parametrize("shape", FOLD_SHAPES)
+    @pytest.mark.parametrize("storage", ["float32", "bfloat16"])
+    def test_matches_oracle(self, shape, storage):
+        B, n, _ = shape
+        u = _cfield(shape, 1)
+        phi = np.random.default_rng(3).uniform(0, 6.28, (n, n))
+        # a frozen modulation plane pair in its storage dtype, read in f32
+        mr, mi = (jnp.asarray(p, jnp.float32).astype(storage).astype(
+            jnp.float32) for p in (1.2 * np.cos(phi), 1.2 * np.sin(phi)))
+        got = _folded_product(jnp.asarray(u, jnp.complex64),
+                              jax.lax.complex(mr, mi))
+        want = u * (np.asarray(mr, np.float64) + 1j * np.asarray(mi))
+        _assert_close(got, want, 1e-5)
+
+    def test_2d_input(self):
+        u = _cfield((16, 16), 5)
+        m = _cfield((16, 16), 6)
+        got = _folded_product(jnp.asarray(u, jnp.complex64),
+                              jnp.asarray(m, jnp.complex64))
+        assert got.shape == (16, 16)
+        _assert_close(got, u * m, 1e-5)
+
+    def test_unit_modulus_keeps_energy(self):
+        """A phase-only plane moves no energy between pixels."""
+        u = jnp.asarray(_cfield((2, 33, 33), 7), jnp.complex64)
+        phi = np.random.default_rng(8).uniform(0, 6.28, (33, 33))
+        got = _folded_product(u, jnp.asarray(np.exp(1j * phi), jnp.complex64))
+        np.testing.assert_allclose(df.intensity(got), df.intensity(u),
+                                   rtol=1e-5, atol=1e-5)

@@ -17,6 +17,7 @@ from repro.core import (
     plan_cache_stats,
 )
 from repro.core import models as mmod
+from repro.core import propagation as pp
 from repro.data import synth_digits, synth_rgb_scenes, synth_seg
 
 BASE = dict(n=48, depth=3, det_size=6)
@@ -97,14 +98,19 @@ class TestEmulateBatch:
         for i, want in enumerate(seq):
             np.testing.assert_allclose(bat[i], want, rtol=1e-5, atol=1e-4)
 
-    def test_pallas_matches_sequential(self):
-        cfgs = _cls_cfgs(use_pallas=True)
+    def test_folded_matches_sequential(self, monkeypatch):
+        # per-candidate TF planes as traced inputs through the folded hop
+        monkeypatch.setattr(pp, "_packed_hop_applies", lambda n: True)
+        monkeypatch.setattr(pp, "_folded_hop_applies", lambda n: True)
+        mmod.clear_emulation_caches()
+        cfgs = _cls_cfgs()
         params = build_model(cfgs[0]).init(jax.random.PRNGKey(0))
         x = _digits(seed=3)
         seq = [build_model(c).apply(params, x) for c in cfgs]
         bat = emulate_batch(cfgs, params, x)
+        mmod.clear_emulation_caches()
         for i, want in enumerate(seq):
-            np.testing.assert_allclose(bat[i], want, rtol=2e-4, atol=2e-4)
+            np.testing.assert_allclose(bat[i], want, rtol=1e-5, atol=1e-5)
 
     def test_statics_mismatch_raises(self):
         cfgs = _cls_cfgs()

@@ -1,12 +1,8 @@
-"""PR-8 perf surfaces: fused spectral hop, quantized frozen planes,
-rfft first hop, artifact format 2.
+"""Serving surfaces: quantized frozen planes, the rfft first hop, artifact
+format 2, and the retired ``use_pallas`` flag.
 
 Four invariants:
 
-- **fused hop == jnp reference** at rtol <= 1e-5, values *and* gradients,
-  at the kernel level and through every plan path that fuses
-  (``use_pallas`` x {trainable, frozen, masked} x {cls, rgb, seg},
-  heterogeneous segments, rng codesign);
 - **quantized frozen planes** (``freeze(plane_dtype=...)``): the f32 path
   stays bit-identical to the default, bf16 stays within the documented
   5e-2 output tolerance, int8 is finite and close, and every dtype
@@ -17,10 +13,14 @@ Four invariants:
   eagerly, and the engine output is bit-identical to the deployed
   forward;
 - **artifact format 2**: format-1 artifacts still load, unknown formats
-  are rejected with a clear error before any deserialization.
+  are rejected with a clear error before any deserialization;
+- **``use_pallas``**: a config, DSL spec or artifact that asks for the
+  removed Pallas path is refused with the path that replaced it, and
+  specs or artifacts that name it false still load.
 """
 import dataclasses
 import json
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -28,21 +28,15 @@ import numpy as np
 import pytest
 
 from repro.core import DONNConfig, build_model
-from repro.core import propagation as pp
+from repro.core import dsl
 from repro.core.config import LayerSpec
-from repro.kernels import ops
+from repro.models.config import get_config
 from repro.runtime.inference import InferenceEngine, freeze
 from repro.runtime.resilience import (
-    ARTIFACT_FILE, load_deployed, save_deployed,
+    ARTIFACT_FILE, load_deployed, save_deployed, validate_artifact,
 )
 
 TINY = dict(name="fq", n=32, depth=3, distance=0.05, det_size=6)
-
-
-def _rand(shape, seed=0):
-    return jnp.asarray(
-        np.random.default_rng(seed).normal(size=shape), jnp.float32
-    )
 
 
 def _model(seed=0, **kw):
@@ -53,152 +47,6 @@ def _model(seed=0, **kw):
 
 def _digits(b, shape=(28, 28), seed=0):
     return np.random.default_rng(seed).random((b,) + shape, np.float32)
-
-
-# --------------------------------------------------------------------------
-class TestFusedHopKernel:
-    """fused_spectral_hop vs the unfused jnp reference."""
-
-    def _planes(self, pshape, seed=0):
-        r = np.random.default_rng(seed)
-        th_h = jnp.asarray(r.uniform(0, 2 * np.pi, pshape), jnp.float32)
-        amp_h = jnp.asarray(r.uniform(0.2, 1.0, pshape), jnp.float32)
-        th_m = jnp.asarray(r.uniform(0, 2 * np.pi, pshape), jnp.float32)
-        amp_m = jnp.asarray(r.uniform(0.2, 1.0, pshape), jnp.float32)
-        return th_h, amp_h, th_m, amp_m
-
-    @pytest.mark.parametrize("shape", [(2, 32, 32), (1, 24, 40), (3, 17, 33)])
-    def test_matches_ref(self, shape):
-        planes = self._planes(shape[-2:])
-        xr, xi = _rand(shape, 1), _rand(shape, 2)
-        gr, gi = ops.fused_spectral_hop(xr, xi, *planes)
-        want = ops.fused_spectral_hop_ref(
-            jax.lax.complex(xr, xi), *planes
-        )
-        np.testing.assert_allclose(gr, want.real, rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(gi, want.imag, rtol=1e-5, atol=1e-5)
-
-    def test_2d_input(self):
-        planes = self._planes((16, 16))
-        xr, xi = _rand((16, 16), 3), _rand((16, 16), 4)
-        gr, gi = ops.fused_spectral_hop(xr, xi, *planes)
-        assert gr.shape == (16, 16)
-        want = ops.fused_spectral_hop_ref(jax.lax.complex(xr, xi), *planes)
-        np.testing.assert_allclose(gr, want.real, rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(gi, want.imag, rtol=1e-5, atol=1e-5)
-
-    def test_plane_stack_broadcast(self):
-        """(H,W) TF planes + (C,H,W) modulation planes, x (B,C,H,W)."""
-        th_h, amp_h, _, _ = self._planes((16, 16), seed=5)
-        _, _, th_m, amp_m = self._planes((3, 16, 16), seed=6)
-        xr, xi = _rand((2, 3, 16, 16), 7), _rand((2, 3, 16, 16), 8)
-        gr, gi = ops.fused_spectral_hop(xr, xi, th_h, amp_h, th_m, amp_m)
-        want = ops.fused_spectral_hop_ref(
-            jax.lax.complex(xr, xi), th_h, amp_h, th_m, amp_m
-        )
-        np.testing.assert_allclose(gr, want.real, rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(gi, want.imag, rtol=1e-5, atol=1e-5)
-
-    def test_gradients_match_ref(self):
-        planes = self._planes((16, 16), seed=9)
-        xr, xi = _rand((2, 16, 16), 10), _rand((2, 16, 16), 11)
-
-        def loss(xr, xi, th_m):
-            gr, gi = ops.fused_spectral_hop(
-                xr, xi, planes[0], planes[1], th_m, planes[3]
-            )
-            return jnp.sum(gr**2 + 0.5 * gi**2)
-
-        def loss_ref(xr, xi, th_m):
-            w = ops.fused_spectral_hop_ref(
-                jax.lax.complex(xr, xi), planes[0], planes[1], th_m,
-                planes[3],
-            )
-            return jnp.sum(w.real**2 + 0.5 * w.imag**2)
-
-        got = jax.grad(loss, argnums=(0, 1, 2))(xr, xi, planes[2])
-        want = jax.grad(loss_ref, argnums=(0, 1, 2))(xr, xi, planes[2])
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
-
-
-# --------------------------------------------------------------------------
-class TestFusedPlanAgreement:
-    """use_pallas (fused hop) vs the jnp scan, through build_model."""
-
-    CASES = [
-        ("classify", dict(), (28, 28)),
-        ("rgb", dict(channels=3, num_classes=6), (3, 28, 28)),
-        ("segmentation", dict(segmentation=True, skip_from=0,
-                              layer_norm=True), (28, 28)),
-        ("qat", dict(codesign="qat", device_levels=64), (28, 28)),
-    ]
-
-    @pytest.mark.parametrize("label,extra,x_shape",
-                             CASES, ids=[c[0] for c in CASES])
-    def test_forward_agreement(self, label, extra, x_shape):
-        m_jnp, p = _model(name=f"fp-{label}", **extra)
-        m_fused, _ = _model(name=f"fp-{label}", use_pallas=True, **extra)
-        x = jnp.asarray(_digits(3, x_shape))
-        np.testing.assert_allclose(
-            m_fused.apply(p, x), m_jnp.apply(p, x), rtol=1e-5, atol=1e-5
-        )
-
-    def test_gradients_agreement(self):
-        m_jnp, p = _model(name="fp-grad")
-        m_fused, _ = _model(name="fp-grad", use_pallas=True)
-        x = jnp.asarray(_digits(3))
-        g1 = jax.grad(lambda p: jnp.sum(m_fused.apply(p, x) ** 2))(p)
-        g2 = jax.grad(lambda p: jnp.sum(m_jnp.apply(p, x) ** 2))(p)
-        for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
-            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
-
-    def test_rng_codesign_agreement(self):
-        """Stochastic codesign: same rng chain on both paths."""
-        extra = dict(codesign="gumbel", device_levels=16)
-        m_jnp, p = _model(name="fp-rng", **extra)
-        m_fused, _ = _model(name="fp-rng", use_pallas=True, **extra)
-        x = jnp.asarray(_digits(3))
-        rng = jax.random.PRNGKey(7)
-        np.testing.assert_allclose(
-            m_fused.apply(p, x, rng), m_jnp.apply(p, x, rng),
-            rtol=1e-5, atol=1e-5,
-        )
-
-    def test_hetero_segments_agreement(self):
-        layers = (LayerSpec(0.05, size=40), LayerSpec(0.05, size=40),
-                  LayerSpec(0.05, codesign="qat", device_levels=4))
-        m_jnp, p = _model(name="fp-het", layers=layers)
-        m_fused, _ = _model(name="fp-het", use_pallas=True, layers=layers)
-        x = jnp.asarray(_digits(2))
-        np.testing.assert_allclose(
-            m_fused.apply(p, x), m_jnp.apply(p, x), rtol=1e-5, atol=1e-5
-        )
-
-    def test_frozen_fused_agreement(self):
-        """The frozen serving scan also fuses under use_pallas."""
-        m_jnp, p = _model(name="fp-frozen", codesign="qat")
-        m_fused, _ = _model(name="fp-frozen", use_pallas=True,
-                            codesign="qat")
-        x = _digits(2)
-        a = freeze(m_fused, p)
-        b = freeze(m_jnp, p)
-        np.testing.assert_allclose(
-            np.asarray(a.forward(jnp.asarray(x))),
-            np.asarray(b.forward(jnp.asarray(x))),
-            rtol=1e-5, atol=1e-5,
-        )
-
-    def test_fraunhofer_and_padded_plans_do_not_fuse(self):
-        """Fusion is gated off where the hop is not fft->tf->ifft."""
-        plan_fr = pp.plan_from_config(
-            DONNConfig(**{**TINY, "approximation": "fraunhofer",
-                          "band_limit": False, "distance": 2.5,
-                          "use_pallas": True}), 1.0)
-        plan_pad = pp.plan_from_config(
-            DONNConfig(**{**TINY, "pad": True, "use_pallas": True}), 1.0)
-        assert not plan_fr._fuse
-        assert not plan_pad._fuse
 
 
 # --------------------------------------------------------------------------
@@ -350,3 +198,69 @@ class TestArtifactFormat:
         np.testing.assert_array_equal(
             np.asarray(dep2.forward(jnp.asarray(x))), ref
         )
+
+
+# --------------------------------------------------------------------------
+def _pallas_spec(value):
+    spec = dsl.to_spec(DONNConfig(**TINY))
+    assert "use_pallas" not in spec
+    return dict(spec, use_pallas=value)
+
+
+def _pallas_artifact(tmp_path, value):
+    model, params = _model(name="up-art")
+    save_deployed(freeze(model, params), tmp_path)
+    meta_path = tmp_path / ARTIFACT_FILE
+    meta = json.loads(meta_path.read_text())
+    meta["spec"]["use_pallas"] = value
+    meta_path.write_text(json.dumps(meta))
+    return tmp_path
+
+
+def _benchmark_fields():
+    """The fields of a benchmark configuration file, which name the flag."""
+    path = (pathlib.Path(__file__).resolve().parents[1] / "perfbench"
+            / "configs" / "donn-mnist-5l.json")
+    return json.loads(path.read_text())["fields"]
+
+
+class TestUsePallasRejected:
+    """``DONNConfig.use_pallas`` names the removed Pallas path: True is
+    refused wherever it enters, naming the hop that replaced it."""
+
+    @pytest.mark.parametrize("make", [
+        lambda tmp: DONNConfig(use_pallas=True),
+        lambda tmp: DONNConfig(**TINY, use_pallas=1),
+        lambda tmp: dataclasses.replace(get_config("donn-mnist-5l"),
+                                        use_pallas=True),
+        lambda tmp: dsl.spec_to_config(_pallas_spec(True)),
+        lambda tmp: dsl.from_spec(_pallas_spec(True)),
+        lambda tmp: load_deployed(_pallas_artifact(tmp, True)),
+        lambda tmp: validate_artifact(_pallas_artifact(tmp, True)),
+        lambda tmp: DONNConfig(**dict(_benchmark_fields(), use_pallas=True)),
+    ], ids=["config", "truthy", "registry-replace", "spec-to-config",
+            "from-spec", "load-artifact", "validate-artifact",
+            "benchmark-fields"])
+    def test_true_is_refused_naming_the_replacement(self, make, tmp_path):
+        with pytest.raises(ValueError, match="packed or folded real-DFT"):
+            make(tmp_path)
+
+    def test_specs_and_artifacts_naming_it_false_still_load(self, tmp_path):
+        model, params = _model(name="up-art")
+        x = _digits(2)
+        ref = np.asarray(freeze(model, params).forward(jnp.asarray(x)))
+        assert dsl.spec_to_config(_pallas_spec(False)) == \
+            dsl.spec_to_config(dsl.to_spec(DONNConfig(**TINY)))
+        dep = load_deployed(_pallas_artifact(tmp_path, False))
+        np.testing.assert_array_equal(
+            np.asarray(dep.forward(jnp.asarray(x))), ref)
+        assert DONNConfig(**_benchmark_fields()) == get_config(
+            "donn-mnist-5l")
+
+    def test_serving_cli_has_no_pallas_flag(self, monkeypatch):
+        from repro.launch import serve_donn
+
+        monkeypatch.setattr(serve_donn, "use_persistent_compile_cache",
+                            lambda: None)
+        with pytest.raises(SystemExit):
+            serve_donn.main(["--use-pallas", "--requests", "0"])

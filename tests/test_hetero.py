@@ -534,7 +534,7 @@ _FIELD_ALTERNATES = {
     "skip_from": 1,
     "layer_norm": True,
     "layers": (LayerSpec(distance=0.05, size=32),) * 3,
-    "use_pallas": True,
+    "use_pallas": None,  # retired: True is refused, so it keys nothing
     "engine": "eager",
     "input_size": 14,
     "scan_unroll": 2,
@@ -547,8 +547,7 @@ _FIELD_ALTERNATES = {
 _PLAN_FIELDS = (
     "n", "pixel_size", "wavelength", "distance", "distances", "depth",
     "approximation", "band_limit", "pad", "codesign", "device_levels",
-    "response_gamma", "layers", "use_pallas", "scan_unroll", "tf_dtype",
-    "remat",
+    "response_gamma", "layers", "scan_unroll", "tf_dtype", "remat",
 )
 
 

@@ -61,15 +61,6 @@ class TestFrozenBitIdentity:
         eng = InferenceEngine(dep, buckets=(4,))
         np.testing.assert_array_equal(eng.infer(x), ref)
 
-    def test_classify_pallas(self):
-        model, params = _model(name="fz-pl", depth=2, codesign="qat",
-                               use_pallas=True)
-        x = _digits(2)
-        ref = np.asarray(jax.jit(lambda p, xx: model.apply(p, xx))(params, x))
-        dep = freeze(model, params)
-        eng = InferenceEngine(dep, buckets=(2,))
-        np.testing.assert_array_equal(eng.infer(x), ref)
-
     def test_multi_channel(self):
         model, params = _model(name="fz-rgb", channels=3, det_size=4)
         x = _digits(3, shape=(3, 28, 28))

@@ -1,190 +1,14 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps, grads, properties."""
-import jax
+"""The LM stack's RoPE Pallas kernel against its pure-jnp oracle."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops
-
-SHAPES = [(1, 8, 128), (2, 64, 128), (3, 200, 200), (1, 37, 111), (2, 17, 513)]
-DTYPES = [jnp.float32, jnp.bfloat16]
 
 
 def _rand(shape, dtype, seed=0):
     r = np.random.default_rng(seed)
     return jnp.asarray(r.normal(size=shape), dtype)
-
-
-def _tol(dtype):
-    return dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 else dict(
-        rtol=1e-5, atol=1e-5
-    )
-
-
-class TestComplexMul:
-    @pytest.mark.parametrize("shape", SHAPES)
-    @pytest.mark.parametrize("dtype", DTYPES)
-    def test_matches_oracle(self, shape, dtype):
-        B, H, W = shape
-        ar, ai = _rand(shape, dtype, 1), _rand(shape, dtype, 2)
-        br, bi = _rand((H, W), dtype, 3), _rand((H, W), dtype, 4)
-        got = ops.complex_mul(ar, ai, br, bi)
-        want = ops.complex_mul_ref(ar, ai, br, bi)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(
-                np.asarray(g, np.float32), np.asarray(w, np.float32),
-                **_tol(dtype),
-            )
-
-    def test_2d_input(self):
-        ar, ai = _rand((16, 128), jnp.float32, 5), _rand((16, 128), jnp.float32, 6)
-        got = ops.complex_mul(ar, ai, ar, ai)
-        want = ops.complex_mul_ref(ar, ai, ar, ai)
-        np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-5)
-
-    @settings(max_examples=8, deadline=None)
-    @given(seed=st.integers(0, 100))
-    def test_conjugate_product_is_magnitude(self, seed):
-        """a * conj(a) = |a|^2 (pure real)."""
-        ar, ai = _rand((1, 16, 128), jnp.float32, seed), _rand(
-            (1, 16, 128), jnp.float32, seed + 1
-        )
-        re, im = ops.complex_mul(ar, ai, ar[0], -ai[0])
-        np.testing.assert_allclose(re, ar * ar + ai * ai, rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(im, np.zeros_like(im), atol=1e-5)
-
-
-class TestPhaseApply:
-    @pytest.mark.parametrize("shape", SHAPES)
-    def test_matches_oracle(self, shape):
-        B, H, W = shape
-        ur, ui = _rand(shape, jnp.float32, 1), _rand(shape, jnp.float32, 2)
-        phi = jnp.asarray(
-            np.random.default_rng(3).uniform(0, 6.28, (H, W)), jnp.float32
-        )
-        got = ops.phase_apply(ur, ui, phi, 1.3)
-        want = ops.phase_apply_ref(ur, ui, phi, 1.3)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
-
-    def test_unitary_when_gamma_one(self):
-        ur, ui = _rand((2, 32, 128), jnp.float32, 4), _rand(
-            (2, 32, 128), jnp.float32, 5
-        )
-        phi = _rand((32, 128), jnp.float32, 6)
-        our, oui = ops.phase_apply(ur, ui, phi, 1.0)
-        np.testing.assert_allclose(
-            our**2 + oui**2, ur**2 + ui**2, rtol=1e-4, atol=1e-4
-        )
-
-    def test_gradients_match_reference(self):
-        ur, ui = _rand((2, 24, 96), jnp.float32, 7), _rand(
-            (2, 24, 96), jnp.float32, 8
-        )
-        phi = _rand((24, 96), jnp.float32, 9)
-
-        def f(fn, p):
-            a, b = fn(ur, ui, p, 1.1)
-            return jnp.sum(jnp.sin(a) + b * b)
-
-        g1 = jax.grad(lambda p: f(ops.phase_apply, p))(phi)
-        g2 = jax.grad(lambda p: f(ops.phase_apply_ref, p))(phi)
-        np.testing.assert_allclose(g1, g2, rtol=2e-4, atol=2e-4)
-
-
-class TestIntensityReadout:
-    @pytest.mark.parametrize("shape", SHAPES)
-    @pytest.mark.parametrize("classes", [3, 10])
-    def test_matches_oracle(self, shape, classes):
-        B, H, W = shape
-        ur, ui = _rand(shape, jnp.float32, 1), _rand(shape, jnp.float32, 2)
-        masks = jnp.asarray(
-            (np.random.default_rng(3).random((classes, H, W)) < 0.1),
-            jnp.float32,
-        )
-        got = ops.intensity_readout(ur, ui, masks)
-        want = ops.intensity_readout_ref(ur, ui, masks)
-        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
-
-    def test_partition_sums_to_total(self):
-        """Masks that partition the plane => per-class sums add to total."""
-        B, H, W = 2, 32, 128
-        ur, ui = _rand((B, H, W), jnp.float32, 4), _rand((B, H, W), jnp.float32, 5)
-        labels = np.random.default_rng(6).integers(0, 4, (H, W))
-        masks = jnp.asarray(
-            np.stack([(labels == c) for c in range(4)]), jnp.float32
-        )
-        out = ops.intensity_readout(ur, ui, masks)
-        total = jnp.sum(ur**2 + ui**2, axis=(1, 2))
-        np.testing.assert_allclose(jnp.sum(out, -1), total, rtol=1e-4)
-
-    def test_gradients(self):
-        ur, ui = _rand((2, 16, 128), jnp.float32, 7), _rand(
-            (2, 16, 128), jnp.float32, 8
-        )
-        masks = jnp.ones((2, 16, 128), jnp.float32)
-        g1 = jax.grad(
-            lambda u: jnp.sum(ops.intensity_readout(u, ui, masks))
-        )(ur)
-        g2 = jax.grad(
-            lambda u: jnp.sum(ops.intensity_readout_ref(u, ui, masks))
-        )(ur)
-        np.testing.assert_allclose(g1, g2, rtol=1e-4, atol=1e-4)
-
-
-class TestChannelIntensityReadout:
-    """The fused multi-channel detector accumulation (ISSUE-5 audit)."""
-
-    def test_matches_einsum_fallback(self):
-        r = np.random.default_rng(0)
-        ur = jnp.asarray(r.normal(size=(2, 3, 40, 40)), jnp.float32)
-        ui = jnp.asarray(r.normal(size=(2, 3, 40, 40)), jnp.float32)
-        masks = jnp.asarray(
-            (r.random((5, 40, 40)) > 0.7).astype(np.float32)
-        )
-        got = ops.channel_intensity_readout(ur, ui, masks)
-        inten = ur**2 + ui**2
-        want = jnp.einsum("bdhw,chw->bc", inten, masks)
-        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
-
-    def test_single_sample(self):
-        r = np.random.default_rng(1)
-        ur = jnp.asarray(r.normal(size=(3, 16, 16)), jnp.float32)
-        ui = jnp.asarray(r.normal(size=(3, 16, 16)), jnp.float32)
-        masks = jnp.ones((2, 16, 16), jnp.float32)
-        got = ops.channel_intensity_readout(ur, ui, masks)
-        want = jnp.sum(ur**2 + ui**2)
-        np.testing.assert_allclose(got, jnp.full((2,), want), rtol=1e-4)
-
-    def test_gradients_flow_through_channel_sum(self):
-        r = np.random.default_rng(2)
-        ur = jnp.asarray(r.normal(size=(1, 2, 16, 16)), jnp.float32)
-        ui = jnp.asarray(r.normal(size=(1, 2, 16, 16)), jnp.float32)
-        masks = jnp.ones((1, 16, 16), jnp.float32)
-
-        def f(a, b):
-            return jnp.sum(ops.channel_intensity_readout(a, b, masks))
-
-        da, db = jax.grad(f, argnums=(0, 1))(ur, ui)
-        np.testing.assert_allclose(da, 2 * ur, rtol=1e-4, atol=1e-5)
-        np.testing.assert_allclose(db, 2 * ui, rtol=1e-4, atol=1e-5)
-
-    def test_eager_multichannel_model_routes_through_kernel(self):
-        """Eager RGB path: pallas readout agrees with the jnp einsum."""
-        from repro.core import DONNConfig, build_model
-
-        x = np.random.default_rng(3).random((2, 3, 24, 24), np.float32)
-        outs = {}
-        for up in (False, True):
-            cfg = DONNConfig(name=f"mc-eager-{up}", n=24, depth=2,
-                             distance=0.05, det_size=4, channels=3,
-                             engine="eager", use_pallas=up)
-            m = build_model(cfg)
-            params = m.init(jax.random.PRNGKey(0))
-            outs[up] = np.asarray(m.apply(params, x))
-        np.testing.assert_allclose(outs[True], outs[False], rtol=1e-5,
-                                   atol=1e-5)
 
 
 class TestRope:

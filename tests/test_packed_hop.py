@@ -124,17 +124,37 @@ def test_forced_plan_forward_equals_fft_path(monkeypatch, fold):
     _close(got, want)
 
 
+@pytest.mark.parametrize("plane_dtype", pp.PLANE_DTYPES)
 @FOLDS
-def test_forced_frozen_apply_equals_fft_path(monkeypatch, fold):
+def test_forced_frozen_apply_equals_fft_path(monkeypatch, fold, plane_dtype):
     cfg = DONNConfig(**TINY)
     phis, u = _tiny_inputs(cfg)
 
     def run():
         plan = pp.plan_from_config(cfg, cfg.gamma)
-        frozen = plan.frozen_modulation(phis)
+        frozen = plan.frozen_modulation(phis, plane_dtype)
         return jax.jit(lambda v, f: plan.apply(None, v, frozen=f))(u, frozen)
 
     _close(*_on_both_paths(monkeypatch, run, fold))
+
+
+@FOLDS
+def test_forced_masked_ragged_apply_batch_equals_fft_path(monkeypatch, fold):
+    # depth-padded candidates: the second is a depth-2 stack whose middle
+    # layer is masked to an identity hop
+    cfg = DONNConfig(**TINY)
+    phis, u = _tiny_inputs(cfg)
+    phis_k = jnp.stack([phis, phis[::-1]])
+    mask = jnp.asarray([[True, True, True], [True, False, True]])
+
+    def run():
+        plan = pp.plan_from_config(cfg, cfg.gamma)
+        return jax.jit(lambda p, m: plan.apply_batch(p, u, mask=m))(phis_k,
+                                                                    mask)
+
+    got, want = _on_both_paths(monkeypatch, run, fold)
+    _close(got, want)
+    assert not np.allclose(np.asarray(got[0]), np.asarray(got[1]))
 
 
 @FOLDS
@@ -161,6 +181,50 @@ def test_forced_cached_apply_logits_equal_fft_path(monkeypatch, fold):
     x = jnp.asarray(synth_digits(4, seed=0)[0])
     _close(*_on_both_paths(monkeypatch,
                            lambda: cached_apply(cfg)(params, x), fold))
+
+
+@FOLDS
+def test_forced_rng_codesign_equals_fft_path(monkeypatch, fold):
+    # stochastic codesign: each layer draws from its own key of the split
+    cfg = DONNConfig(**{**TINY, "codesign": "gumbel", "device_levels": 16})
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    x = jnp.asarray(synth_digits(4, seed=0)[0])
+    rng = jax.random.PRNGKey(7)
+    _close(*_on_both_paths(monkeypatch,
+                           lambda: cached_apply(cfg)(params, x, rng), fold))
+
+
+@pytest.mark.parametrize("remat", ["none", "layer"])
+@FOLDS
+def test_forced_qat_phase_gradients_equal_fft_path(monkeypatch, fold, remat):
+    cfg = DONNConfig(**{**TINY, "remat": remat})
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(1))
+    x = jnp.asarray(synth_digits(4, seed=1)[0])
+
+    def run():
+        loss = lambda p: jnp.sum(model.apply(p, x) ** 2)
+        return jax.jit(jax.grad(loss))(params)
+
+    got, want = _on_both_paths(monkeypatch, run, fold)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        _close(g, w, rtol=1e-4)
+
+
+@FOLDS
+def test_forced_multichannel_frozen_serving_equals_fft_path(monkeypatch,
+                                                           fold):
+    from repro.runtime.inference import InferenceEngine, freeze
+
+    cfg = DONNConfig(**{**TINY, "channels": 3, "num_classes": 6})
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    x = np.asarray(synth_rgb_scenes(2, seed=0)[0])
+
+    def run():
+        return InferenceEngine(freeze(model, params), buckets=(2,)).infer(x)
+
+    _close(*_on_both_paths(monkeypatch, run, fold))
 
 
 @pytest.mark.parametrize("extra,data", [
@@ -216,9 +280,8 @@ def test_uneven_plane_keeps_the_fft_hop(monkeypatch):
 
 @pytest.mark.parametrize("extra", [
     {"pad": True},
-    {"use_pallas": True},
     {"approximation": "fraunhofer", "band_limit": False, "distance": 2.5},
-], ids=["padded", "pallas", "fraunhofer"])
+], ids=["padded", "fraunhofer"])
 def test_other_hops_are_not_packable(extra):
     cfg = DONNConfig(**{**TINY, **extra})
     assert not pp.plan_from_config(cfg, cfg.gamma)._packable

@@ -1,4 +1,4 @@
-"""Propagation-plan engine: scan path vs eager loop, TF cache, fused kernel."""
+"""Propagation-plan engine: scan path vs eager loop, TF cache, TF multiply."""
 import dataclasses
 
 import jax
@@ -9,8 +9,8 @@ import pytest
 from repro.core import DONNConfig, build_model
 from repro.core import diffraction as df
 from repro.core import propagation as pp
+from repro.core.models import clear_emulation_caches
 from repro.data import synth_digits, synth_rgb_scenes, synth_seg
-from repro.kernels import ops
 
 TINY = dict(name="t", n=64, depth=3, distance=0.05, det_size=8)
 
@@ -20,6 +20,16 @@ def _pair(cfg_kw):
     return build_model(cfg), build_model(
         dataclasses.replace(cfg, engine="eager")
     )
+
+
+def _force_folded(monkeypatch):
+    """The scan's hops as the packed hop, folded (a TPU rule on a CPU)."""
+    monkeypatch.setattr(pp, "_packed_hop_applies", lambda n: True)
+    monkeypatch.setattr(pp, "_folded_hop_applies", lambda n: True)
+    clear_emulation_caches()
+
+
+FOLDED = {}  # the default config, its scan forced to the folded hop
 
 
 class TestScanMatchesEager:
@@ -34,14 +44,16 @@ class TestScanMatchesEager:
             # puts every hop at F <= 1 where the single-FFT pattern holds
             {"approximation": "fraunhofer", "band_limit": False,
              "distance": 2.5},
-            {"use_pallas": True},
+            FOLDED,
             {"codesign": "qat", "device_levels": 64},
             {"distances": (0.04, 0.05, 0.06, 0.08)},
         ],
-        ids=["rs", "fresnel", "padded", "fraunhofer", "pallas", "qat",
+        ids=["rs", "fresnel", "padded", "fraunhofer", "folded", "qat",
              "heterogeneous"],
     )
-    def test_classify_forward(self, extra):
+    def test_classify_forward(self, monkeypatch, extra):
+        if extra is FOLDED:
+            _force_folded(monkeypatch)
         m_scan, m_eager = _pair({**TINY, **extra})
         p = m_scan.init(jax.random.PRNGKey(0))
         xs, _ = synth_digits(4, seed=0)
@@ -245,9 +257,7 @@ class TestTFCache:
         planes = pp.transfer_planes(g, 0.05, 532e-9, df.RS, True)
         np.testing.assert_array_equal(planes["hr"], h.real)
         np.testing.assert_array_equal(planes["hi"], h.imag)
-        np.testing.assert_allclose(
-            planes["amp"] * np.exp(1j * planes["theta"]), h, atol=1e-6
-        )
+        assert set(planes) == {"hr", "hi"}
 
     def test_lru_refresh_on_hit(self, monkeypatch):
         """A hit must refresh recency: alternating sweeps keep hot entries."""
@@ -338,85 +348,92 @@ class TestMultiChannelBatched:
         for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
             np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
 
-    def test_batched_pallas_readout(self):
-        cfg_kw = {**TINY, "channels": 3, "num_classes": 6, "use_pallas": True}
-        m_scan, m_eager = _pair(cfg_kw)
+    def test_batched_folded_forward(self, monkeypatch):
+        _force_folded(monkeypatch)
+        m_scan, m_eager = _pair({**TINY, "channels": 3, "num_classes": 6})
         p = m_scan.init(jax.random.PRNGKey(0))
         xs, _ = synth_rgb_scenes(4, seed=2)
         x = jnp.asarray(xs)
         np.testing.assert_allclose(
-            m_scan.apply(p, x), m_eager.apply(p, x), rtol=2e-4, atol=2e-4
+            m_scan.apply(p, x), m_eager.apply(p, x), rtol=1e-5, atol=1e-5
         )
 
 
-class TestPhaseTFApplyKernel:
+class TestSpectralMul:
+    """The plan's TF multiply (the ``tf_mul`` stage) against the polar
+    oracle ``x * amp * exp(j theta)``, on cartesian ``(hr, hi)`` planes."""
+
     def _rand(self, shape, seed):
-        r = np.random.default_rng(seed)
-        return jnp.asarray(r.normal(size=shape), jnp.float32)
+        return np.random.default_rng(seed).normal(size=shape)
+
+    def _case(self, xshape, pshape, seed):
+        x = self._rand(xshape, seed) + 1j * self._rand(xshape, seed + 1)
+        th = self._rand(pshape, seed + 2)
+        am = np.abs(self._rand(pshape, seed + 3))
+        return x, th, am, x * am * np.exp(1j * th)
+
+    def _plan(self):
+        return pp.PropagationPlan(df.Grid(8, 36e-6), [0.05], 532e-9)
+
+    def _mul(self, x, th, am):
+        pair = tuple(jnp.asarray(am * f(th), jnp.float32)
+                     for f in (np.cos, np.sin))
+        return self._plan()._spectral_mul(jnp.asarray(x, jnp.complex64), pair)
+
+    def _close(self, got, want):
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                                   atol=1e-5 * np.abs(want).max())
 
     @pytest.mark.parametrize("shape", [(1, 8, 128), (3, 37, 111), (2, 64, 64)])
     def test_forward_matches_ref(self, shape):
-        B, H, W = shape
-        xr, xi = self._rand(shape, 1), self._rand(shape, 2)
-        th, am = self._rand((H, W), 3), jnp.abs(self._rand((H, W), 4))
-        got = ops.phase_tf_apply(xr, xi, th, am)
-        want = ops.phase_tf_apply_ref(xr, xi, th, am)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+        x, th, am, want = self._case(shape, shape[-2:], 1)
+        self._close(self._mul(x, th, am), want)
 
     def test_per_plane_forward(self):
-        P, B, H, W = 3, 4, 16, 64
-        xr, xi = self._rand((B, P, H, W), 5), self._rand((B, P, H, W), 6)
-        th = self._rand((P, H, W), 7)
-        am = jnp.abs(self._rand((P, H, W), 8))
-        got = ops.phase_tf_apply(xr, xi, th, am)
-        want = ops.phase_tf_apply_ref(xr, xi, th, am)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+        x, th, am, want = self._case((4, 3, 16, 64), (3, 16, 64), 5)
+        self._close(self._mul(x, th, am), want)
 
     def test_multi_axis_plane_broadcast(self):
-        """(K, C, H, W) plane stacks flatten to one plane-major axis."""
-        K, C, B, H, W = 2, 3, 4, 16, 64
-        xr = self._rand((B, K, C, H, W), 20)
-        xi = self._rand((B, K, C, H, W), 21)
-        th = self._rand((K, C, H, W), 22)
-        am = jnp.abs(self._rand((K, C, H, W), 23))
-        got = ops.phase_tf_apply(xr, xi, th, am)
-        want = ops.phase_tf_apply_ref(xr, xi, th, am)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
-        # leading batch axis absent: (K, C, H, W) fields squeeze through too
-        got2 = ops.phase_tf_apply(xr[0], xi[0], th, am)
-        for g, w in zip(got2, (want[0][0], want[1][0])):
-            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+        """(K, C, H, W) plane stacks against (B, K, C, H, W) fields."""
+        x, th, am, want = self._case((4, 2, 3, 16, 64), (2, 3, 16, 64), 20)
+        self._close(self._mul(x, th, am), want)
+        # leading batch axis absent: (K, C, H, W) fields take them too
+        self._close(self._mul(x[0], th, am), want[0])
 
     def test_mismatched_plane_axes_raise(self):
-        xr = self._rand((4, 2, 16, 64), 24)
-        th = self._rand((3, 16, 64), 25)
-        with pytest.raises(ValueError, match="plane axes"):
-            ops.phase_tf_apply(xr, xr, th, jnp.abs(th))
+        x = self._rand((4, 2, 16, 64), 24) + 0j
+        th, am = self._rand((3, 16, 64), 25), np.ones((3, 16, 64))
+        with pytest.raises((TypeError, ValueError), match="broadcast"):
+            self._mul(x, th, am)
 
     def test_gradients_match_ref(self):
         B, H, W = 2, 33, 65
-        xr, xi = self._rand((B, H, W), 9), self._rand((B, H, W), 10)
-        th, am = self._rand((H, W), 11), jnp.abs(self._rand((H, W), 12))
+        x, th, am, _ = self._case((B, H, W), (H, W), 9)
+        args = tuple(jnp.asarray(p, jnp.float32)
+                     for p in (x.real, x.imag, am * np.cos(th),
+                               am * np.sin(th)))
+        plan = self._plan()
 
-        def loss(fn, xr, xi, th):
-            a, b = fn(xr, xi, th, am)
-            return jnp.sum(a**2 + 2.0 * b)
+        def loss(a, b):
+            return jnp.sum(a ** 2 + 2.0 * b)
 
-        g1 = jax.grad(lambda *a: loss(ops.phase_tf_apply, *a),
-                      argnums=(0, 1, 2))(xr, xi, th)
-        g2 = jax.grad(lambda *a: loss(ops.phase_tf_apply_ref, *a),
-                      argnums=(0, 1, 2))(xr, xi, th)
+        def via_plan(xr, xi, hr, hi):
+            out = plan._spectral_mul(jax.lax.complex(xr, xi), (hr, hi))
+            return loss(out.real, out.imag)
+
+        def split_ref(xr, xi, hr, hi):
+            return loss(xr * hr - xi * hi, xr * hi + xi * hr)
+
+        g1 = jax.grad(via_plan, argnums=(0, 1, 2, 3))(*args)
+        g2 = jax.grad(split_ref, argnums=(0, 1, 2, 3))(*args)
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
-    def test_unit_amp_matches_phase_apply(self):
-        B, H, W = 2, 16, 128
-        xr, xi = self._rand((B, H, W), 13), self._rand((B, H, W), 14)
-        th = self._rand((H, W), 15)
-        got = ops.phase_tf_apply(xr, xi, th, jnp.ones((H, W), jnp.float32))
-        want = ops.phase_apply(xr, xi, th, 1.0)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+    def test_unit_amp_matches_modulation(self):
+        """A unit-modulus TF multiply is a phase modulation at gamma 1."""
+        x, th, _, _ = self._case((2, 16, 128), (16, 128), 13)
+        got = self._mul(x, th, np.ones_like(th))
+        mod = self._plan()._modulate(jnp.asarray(x, jnp.complex64),
+                                     jnp.asarray(th, jnp.float32))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(mod),
+                                   rtol=1e-5, atol=1e-5)

@@ -61,7 +61,6 @@ def _model(seed=0, **kw):
 class TestArtifactRoundTrip:
     @pytest.mark.parametrize("kw", [
         dict(name="ar-qat", codesign="qat"),
-        dict(name="ar-pl", depth=2, codesign="qat", use_pallas=True),
     ])
     def test_save_load_bit_identical(self, tmp_path, kw):
         model, params = _model(**kw)
@@ -356,18 +355,6 @@ class TestPerturbFrozen:
         np.testing.assert_array_equal(
             InferenceEngine(dep, buckets=(2,)).infer(x), ref
         )
-
-    def test_pallas_polar_convention(self):
-        """Phase noise on the polar (pallas) planes leaves amplitudes
-        untouched — only the theta plane moves."""
-        model, params = _model(name="pf-pl", depth=2, codesign="qat",
-                               use_pallas=True)
-        dep = freeze(model, params)
-        pert = perturb_frozen(dep, phase_sigma=0.4, seed=5)
-        np.testing.assert_array_equal(np.asarray(pert.frozen[1]),
-                                      np.asarray(dep.frozen[1]))
-        assert not np.array_equal(np.asarray(pert.frozen[0]),
-                                  np.asarray(dep.frozen[0]))
 
     def test_jnp_cartesian_preserves_amplitude(self):
         """In the cartesian convention phase noise must move both split

@@ -13,11 +13,17 @@ from repro.core.models import cached_apply, clear_emulation_caches
 
 TINY = dict(n=64, depth=3, distance=0.05, det_size=8, codesign="qat",
             device_levels=256)
-# every stage a path runs, by path
+# every stage the forward runs, on the FFT and on the folded hop alike
 JNP_STAGES = {"encode", "masks", "fft", "tf_mul", "ifft", "modulate",
               "readout"}
-PALLAS_STAGES = {"encode", "masks", "fft", "tf_mul", "ifft", "fused_hop",
-                 "readout"}
+# the FFT hop, and the packed hop forced to run folded
+HOPS = pytest.mark.parametrize("hop", ["fft", "folded"])
+
+
+def _force(monkeypatch, hop):
+    if hop == "folded":
+        monkeypatch.setattr(pp, "_packed_hop_applies", lambda n: True)
+        monkeypatch.setattr(pp, "_folded_hop_applies", lambda n: True)
 
 
 def _images(b=4, seed=0):
@@ -31,12 +37,13 @@ def _stages_of(cfg, x):
     return set(pp.stage_map().values()), out, params
 
 
-@pytest.mark.parametrize("use_pallas,expected", [(False, JNP_STAGES),
-                                                 (True, PALLAS_STAGES)])
-def test_stage_map_names_every_stage_of_the_path(use_pallas, expected):
-    cfg = DONNConfig(**TINY, use_pallas=use_pallas)
+@HOPS
+def test_stage_map_names_every_stage_of_the_path(monkeypatch, hop):
+    _force(monkeypatch, hop)
+    cfg = DONNConfig(**TINY)
     stages, _, _ = _stages_of(cfg, _images())
-    assert stages == expected
+    clear_emulation_caches()
+    assert stages == JNP_STAGES
 
 
 def _instructions(module_text):
@@ -120,11 +127,13 @@ def test_segmented_plan_names_its_stitch():
     assert {"stitch", "fft", "ifft", "modulate", "readout"} <= stages
 
 
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_scoped_outputs_agree_with_eager(use_pallas):
-    cfg = DONNConfig(**TINY, use_pallas=use_pallas)
+@HOPS
+def test_scoped_outputs_agree_with_eager(monkeypatch, hop):
+    _force(monkeypatch, hop)
+    cfg = DONNConfig(**TINY)
     x = _images()
     _, got, params = _stages_of(cfg, x)
+    clear_emulation_caches()
     want = build_model(dataclasses.replace(cfg, engine="eager")).apply(
         params, x)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)

@@ -1,11 +1,11 @@
-"""v5e compile rehearsals for the main-path Pallas kernels.
+"""v5e compile rehearsals for the main path's hop, modulation and readout.
 
-Interpret mode runs the kernels on CPU but never hands them to Mosaic, so
-block-shape alignment, VMEM limits and lowering gaps only show when the
-kernels compile for a TPU.  The installed TPU compiler compiles for a
-described (not attached) v5e, so these tests compile each kernel at the
-padded widths of the registered configurations (n = 200, 350, 500) with a
-batch > 1 and check that a Mosaic custom call is in the program.
+A CPU run never hands these programs to the TPU compiler, so precision,
+layout and lowering choices only show when they compile for a TPU.  The
+installed TPU compiler compiles for a described (not attached) v5e, so
+these tests compile each stage at the widths of the registered
+configurations (n = 200, 350, 500) with a batch > 1 and read the
+compiled program.
 
 The topology is described inside a fixture only: one process at a time may
 load the TPU library, and each pytest-xdist worker imports every test file.
@@ -20,10 +20,6 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import diffraction as df
 from repro.core import propagation as pp
-from repro.kernels import ops
-from repro.kernels.complex_mul import phase_tf_apply_pallas
-from repro.kernels.intensity_readout import intensity_readout_pallas
-from repro.kernels.spectral_hop import conj_phase_scale_pallas
 
 WIDTHS = (200, 350, 500)
 BATCH = 4
@@ -62,53 +58,14 @@ def _compiled_text(fn, one_chip, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _padded(n, **block_max):
-    bh, bw = ops._pick_blocks(n, n, **block_max)
-    return bh, bw, ops._ceil_to(n, bh), ops._ceil_to(n, bw)
+def _matmuls(hlo):
+    """XLA's TPU backend writes a dot as a 1x1 convolution."""
+    return [line for line in hlo.splitlines()
+            if re.search(r"\b(dot|convolution)\(", line)]
 
 
 @pytest.mark.parametrize("n", WIDTHS)
-def test_phase_tf_apply_compiles_for_v5e(one_chip, n):
-    bh, bw, hp, wp = _padded(n)
-
-    def fn(xr, xi, th, amp):
-        return phase_tf_apply_pallas(xr, xi, th, amp, nb=BATCH, bh=bh, bw=bw,
-                                     interpret=False)
-
-    hlo = _compiled_text(fn, one_chip, (BATCH, hp, wp), (BATCH, hp, wp),
-                         (1, hp, wp), (1, hp, wp))
-    assert "tpu_custom_call" in hlo
-
-
-@pytest.mark.parametrize("n", WIDTHS)
-def test_conj_phase_scale_compiles_for_v5e(one_chip, n):
-    bh, bw, hp, wp = _padded(n)
-
-    def fn(xr, xi, th, amp):
-        return conj_phase_scale_pallas(xr, xi, th, amp, sign=-1.0,
-                                       scale=1.0 / (n * n), nb=BATCH, bh=bh,
-                                       bw=bw, interpret=False)
-
-    hlo = _compiled_text(fn, one_chip, (BATCH, hp, wp), (BATCH, hp, wp),
-                         (1, hp, wp), (1, hp, wp))
-    assert "tpu_custom_call" in hlo
-
-
-@pytest.mark.parametrize("n", WIDTHS)
-def test_intensity_readout_compiles_for_v5e(one_chip, n):
-    bh, bw, hp, wp = _padded(n, max_h=32, max_w=256)
-
-    def fn(ur, ui, masks):
-        return intensity_readout_pallas(ur, ui, masks, bh=bh, bw=bw,
-                                        interpret=False)
-
-    hlo = _compiled_text(fn, one_chip, (BATCH, hp, wp), (BATCH, hp, wp),
-                         (CLASSES, hp, wp))
-    assert "tpu_custom_call" in hlo
-
-
-def test_packed_hop_compiles_for_v5e_at_highest_precision(one_chip):
-    n = 500
+def test_packed_hop_compiles_for_v5e_at_highest_precision(one_chip, n):
     m = (n + 1) // 2
     plan = pp.PropagationPlan(df.Grid(n, 36e-6), [0.3], 532e-9)
     assert plan._packable
@@ -118,21 +75,26 @@ def test_packed_hop_compiles_for_v5e_at_highest_precision(one_chip):
 
     hlo = _compiled_text(fn, one_chip, (BATCH, n, n), (BATCH, n, n),
                          (n, n), (n, n))
-    # XLA's TPU backend writes a dot as a 1x1 convolution.  Four stages,
-    # each one dot batched over the four folded blocks, write (block,
-    # real/imaginary, batch, m, m) and contract m, not n; the field's fold
-    # and unfold are six plain dots each, whose 0/+-1 matrices do the
-    # mirrored reads (a TPU runs a reversal as a pass of its own)
-    matmuls = [line for line in hlo.splitlines()
-               if re.search(r"\b(dot|convolution)\(", line)]
-    assert len(matmuls) == 16
+    matmuls = _matmuls(hlo)
     assert all("operand_precision={highest,highest}" in line
                for line in matmuls)
+    assert "reverse(" not in hlo
+    if not pp._folded_hop_applies(n):
+        # two real stages a transform on the stacked (real, imaginary)
+        # pair, each contracting n
+        assert len(matmuls) == 4
+        assert all(re.search(rf"= f32\[2,{BATCH},{n},{n}\]", line)
+                   for line in matmuls)
+        return
+    # Four stages, each one dot batched over the four folded blocks, write
+    # (block, real/imaginary, batch, m, m) and contract m, not n; the
+    # field's fold and unfold are six plain dots each, whose 0/+-1 matrices
+    # do the mirrored reads (a TPU runs a reversal as a pass of its own)
+    assert len(matmuls) == 16
     stages = [line for line in matmuls
               if re.search(rf"= f32\[4,2,{BATCH},{m},{m}\]", line)]
     assert len(stages) == 4
     assert not any(str(n) in line.split(", metadata")[0] for line in stages)
-    assert "reverse(" not in hlo
     entry = hlo[hlo.index("\nENTRY"):]
     entry = entry[:entry.index("\n}")]
     # neither the stacked real pair nor the folded blocks are copied or
@@ -140,3 +102,54 @@ def test_packed_hop_compiles_for_v5e_at_highest_precision(one_chip):
     assert "transpose(" not in entry
     assert not re.search(rf"= f32\[2,{BATCH},{n},{n}\]\S* copy\(", entry)
     assert not re.search(rf"= f32\[4,2,{BATCH},{m},{m}\]\S* copy\(", entry)
+
+
+@pytest.mark.parametrize("n", WIDTHS)
+def test_folded_modulation_compiles_for_v5e_without_a_matmul(one_chip, n):
+    # the mix of the four folded blocks: butterflies of adds; as a 4 x 4
+    # dot the v5e laid the block axis out second-minor and ran it six
+    # times as long (diffraction._hadamard)
+    m = (n + 1) // 2
+    hlo = _compiled_text(df.modulate_folded, one_chip, (4, 2, BATCH, m, m),
+                         (4, 2, m, m))
+    assert not _matmuls(hlo)
+    assert "reverse(" not in hlo
+
+
+@pytest.mark.parametrize("n", WIDTHS)
+def test_readout_compiles_for_v5e_at_highest_precision(one_chip, n):
+    def fn(ur, ui, masks):
+        return df.readout(jax.lax.complex(ur, ui), masks)
+
+    hlo = _compiled_text(fn, one_chip, (BATCH, n, n), (BATCH, n, n),
+                         (CLASSES, n, n))
+    matmuls = _matmuls(hlo)
+    assert len(matmuls) == 1
+    assert "operand_precision={highest,highest}" in matmuls[0]
+
+
+def test_phase_gradient_compiles_for_v5e_on_the_packed_hop(one_chip,
+                                                           monkeypatch):
+    # the training cell's plane size: the forward and the adjoint of the
+    # packed hop, every matmul at HIGHEST on the stacked (real, imaginary)
+    # pair (XLA's TPU FFT lowers to convolutions of another shape)
+    n, depth = 200, 2
+    monkeypatch.setattr(pp, "_packed_hop_applies", lambda n: True)
+    plan = pp.PropagationPlan(df.Grid(n, 36e-6), [0.3] * (depth + 1),
+                              532e-9, gamma=1.12)
+    assert not pp._folded_hop_applies(n)
+
+    def loss(phis, ur, ui):
+        return jnp.sum(df.intensity(plan.apply(phis,
+                                               jax.lax.complex(ur, ui))))
+
+    hlo = _compiled_text(jax.grad(loss), one_chip, (depth, n, n),
+                         (BATCH, n, n), (BATCH, n, n))
+    matmuls = _matmuls(hlo)
+    # four stages a hop: depth + 1 forward hops, and the adjoints of all
+    # but the first (the phases' gradient needs no hop before the first
+    # mask)
+    assert len(matmuls) == 4 * (depth + 1) + 4 * depth
+    assert all("operand_precision={highest,highest}" in line
+               and re.search(rf"= f32\[2,{BATCH},{n},{n}\]", line)
+               for line in matmuls)

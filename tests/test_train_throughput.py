@@ -271,7 +271,6 @@ class TestSpatialGates:
         dict(pad=True),
         dict(approximation="fraunhofer"),
         dict(codesign="gumbel"),
-        dict(use_pallas=True),
         dict(tf_dtype="bfloat16"),
     ])
     def test_unsupported_config_raises(self, kw):

@@ -20,7 +20,7 @@ REPO = pathlib.Path(__file__).resolve().parents[1]
 TINY = dict(n=64, depth=3, distance=0.05, det_size=8, codesign="qat",
             device_levels=256)
 FORWARD_STAGES = ("encode", "masks", "fft", "tf_mul", "ifft", "modulate",
-                  "fused_hop", "readout", "stitch")
+                  "readout", "stitch")
 
 
 def _chunk_inputs(cfg, steps=3, b=4):

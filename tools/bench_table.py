@@ -87,9 +87,6 @@ HEADLINES = {
     "serving_fleet": (
         "9", _serving_fleet_headline,
         "continuous-batching fleet: Poisson latency, failover, warm swap"),
-    "kernel_breakdown": (
-        "8", lambda m: _fmt_map(_pick(m), "x"),
-        "per-operator batched-jit vs per-sample numpy (Fig. 9)"),
 }
 
 

@@ -136,7 +136,7 @@ reproduced [34,67]-style baseline under identical data):
 |---|---|---|
 | Fig 7 (gamma reg.) | +31% acc at depth 1; deep DONNs match regardless of depth | +41 pts at depth 1 (0.37->0.78); +62/+59 pts at depths 3/5 (both ~0.99 with gamma); confirms both claims |
 | Fig 8 (runtime) | up to 6.4x CPU vs LightPipes | 4.9-8.1x vs the reproduced per-sample eager baseline across sizes 64-256 and depths 1-5 (jit+batch+cached TF) — same magnitude class |
-| Fig 9 (breakdown) | FFT2 11x / iFFT2 10x / MM 4x | FFT2 5.0x / iFFT2 4.9x / ComplexMM 52x (batched c64+jit vs per-sample c128; the Pallas ComplexMM row is interpret-mode on CPU — TPU-only wall-clock) |
+| Fig 9 (breakdown) | FFT2 11x / iFFT2 10x / MM 4x | FFT2 5.0x / iFFT2 4.9x / ComplexMM 52x (batched c64+jit vs per-sample c128) |
 | Fig 10 (scaling) | runtime ~linear in depth | linear fit R^2 = 0.9996 over depths 5-30 |
 | Fig 5 (DSE) | ~60x fewer emulations | 12.5x on the reduced 5x5 grid (25 -> 2 verifications), best point recovered within 0.05 acc |
 | Table 3 | unit size most sensitive | largest acc drop under +-10% perturbation is unit_size (`table3/*`) |

@@ -722,16 +722,14 @@ class Bf16Accumulation(Rule):
 class ComplexPromotionInHotPath(Rule):
     """LR107: ``a + 1j*b`` pair assembly inside compiled/scanned code.
 
-    The propagation engine carries fields as split real/imag planes so
-    the elementwise sites stay fused (``phase_tf_apply``,
-    ``fused_spectral_hop``).  Re-assembling a complex array from the
-    split pair inside a scan body or jitted function (``a + 1j*b`` /
-    ``a - 1j*b``) materializes an interleaved complex temporary between
-    kernels — exactly the promotion the fused spectral-hop kernel exists
-    to avoid — and silently widens every downstream op to complex
-    arithmetic.  Use ``jax.lax.complex(a, b)`` at the single FFT
-    boundary that genuinely needs a complex operand, or keep the pair
-    split through the fused kernels.
+    The propagation engine keeps transfer and modulation planes as split
+    real/imag pairs, and the packed hop runs its matmul stages on a
+    field's split pair.  Re-assembling a complex array from a split pair
+    inside a scan body or jitted function (``a + 1j*b`` / ``a - 1j*b``)
+    materializes an interleaved complex temporary and silently widens
+    every downstream op to complex arithmetic.  Use
+    ``jax.lax.complex(a, b)`` at the single boundary that genuinely needs
+    a complex operand, or keep the pair split.
 
     Hot regions are discovered exactly like LR103: scan bodies,
     jit/pjit'd and remat'd functions, ``cached_executable`` targets, and
