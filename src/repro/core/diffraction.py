@@ -301,9 +301,9 @@ def _hadamard(x: jax.Array) -> jax.Array:
     axis (k = 2 r + t): block k of the result sums block k' = 2 r' + t'
     with the sign (-1)^(r r' + t t').  It takes a field's four folds to
     four times its mirrored quadrants (rows, and columns, of the second
-    half reversed) and back.  Two butterflies of adds, one pass over the
-    blocks; as a 4 x 4 dot the v5e laid the block axis out second-minor
-    and took six times as long."""
+    half reversed) and back.  Two butterflies of adds; as a 4 x 4 dot the
+    v5e laid the block axis out second-minor and took six times as
+    long."""
     s, t = x[0] + x[1], x[0] - x[1]
     u, v = x[2] + x[3], x[2] - x[3]
     return jnp.stack([s + u, t + v, s - u, t - v])
@@ -324,7 +324,10 @@ def modulate_folded(x: jax.Array, mod: jax.Array) -> jax.Array:
     elementwise on the mirrored quadrants, so the blocks go there and back
     by ``_hadamard`` (on a TPU v5e this ran the cell faster than mixing the
     four folds directly, which reads each of them for every block it
-    writes)."""
+    writes).  The TPU compiler runs this as five loop passes over the
+    blocks; on a TPU the plan runs it as one Pallas pass
+    (``repro.kernels.fold_mix``), and this is that kernel's reference and
+    fallback."""
     mod = mod.reshape(mod.shape[:2] + (1,) * (x.ndim - mod.ndim)
                       + mod.shape[2:])
     q = _hadamard(x)

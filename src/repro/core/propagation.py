@@ -65,7 +65,8 @@ separate unbatched stacks.  This module replaces that loop with a
     odd halves, each stage contracting half the plane (``_folded_hop``,
     built on ``diffraction.folded_dft_matrices``):
     ``forward`` folds a field once and scans the folded blocks, each
-    layer's modulation mixing them (``diffraction.modulate_folded``).
+    layer's modulation mixing them (``diffraction.modulate_folded``; on a
+    TPU one Pallas pass, ``repro.kernels.fold_mix``).
     ``hop_path_stats()`` counts the hops traced on each path.
 
 The eager path remains available via ``DONNConfig(engine="eager")`` and
@@ -85,6 +86,7 @@ from repro.core import codesign as cd
 from repro.core import diffraction as df
 from repro.core import physics
 from repro.core.cache import lru_get, lru_put
+from repro.runtime import sharding as shd
 
 # --------------------------------------------------------------------------
 # Process-wide caches (TF planes, plans, executables)
@@ -165,17 +167,20 @@ def compile_stats() -> dict:
 
 # Hops traced into programs, by path: "packed" (the real-DFT matmul hop)
 # or "fft" (jnp.fft); "folded" counts the packed hops that run folded
-# (``diffraction.folded_dft_matrices``).  A scan adds its length once per
-# trace.
-_HOP_STATS = {"packed": 0, "fft": 0, "folded": 0}
+# (``diffraction.folded_dft_matrices``), "mix_kernel" the modulated folded
+# layers whose mix runs as the Pallas kernel (``repro.kernels.fold_mix``).
+# A scan adds its length once per trace.
+_HOP_STATS = {"packed": 0, "fft": 0, "folded": 0, "mix_kernel": 0}
 
 
 def hop_path_stats() -> dict:
     """Hops traced so far, by path (``packed``, ``fft``): how often the
     packed real-DFT hop engages; ``folded`` counts the packed hops that
-    run folded (``_folded_hop_applies``).  Each trace of a program counts
-    its hops once, every layer of a scan included; running a compiled
-    program counts nothing."""
+    run folded (``_folded_hop_applies``), ``mix_kernel`` the modulated
+    folded layers whose mix traced the one-pass kernel
+    (``_mix_kernel_applies``).  Each trace of a program counts its hops
+    once, every layer of a scan included; running a compiled program
+    counts nothing."""
     return dict(_HOP_STATS)
 
 
@@ -206,6 +211,39 @@ _FOLDED_HOP_MIN_N = 385
 def _folded_hop_applies(n: int) -> bool:
     """Whether a packed n x n hop runs folded."""
     return n >= _FOLDED_HOP_MIN_N
+
+
+def _mix_kernel_takes(x: jax.Array, mod: jax.Array) -> bool:
+    """Whether the one-pass mix kernel (``repro.kernels.fold_mix``) takes
+    these operands: float32 blocks of the plain (4, 2, B, m, m) field and
+    one (4, 2, m, m) modulation, in a program the compiler does not
+    partition over several devices (``sharding.partitioned_by_compiler``;
+    inside a ``shard_map`` each device runs the kernel on its shard).  Any
+    other rank, such as a multi-channel (..., C, m, m) field, keeps the
+    jnp mix."""
+    return (x.ndim == 5 and x.shape[:2] == (4, 2)
+            and mod.shape == x.shape[:2] + x.shape[-2:]
+            and x.dtype == mod.dtype == jnp.float32
+            and not shd.partitioned_by_compiler())
+
+
+def _mix_kernel_applies(x: jax.Array, mod: jax.Array) -> bool:
+    """Whether a folded layer's mix (``diffraction.modulate_folded``) runs
+    as the one-pass kernel: on a TPU, where the kernel takes the
+    operands."""
+    return jax.default_backend() == "tpu" and _mix_kernel_takes(x, mod)
+
+
+def _modulate_folded(x: jax.Array, mod: jax.Array, layers: int) -> jax.Array:
+    """One folded layer's mix, as the kernel where ``_mix_kernel_applies``
+    (Pallas is imported here, on first use), else as jnp.  ``layers`` is
+    how many layers this trace stands for in ``hop_path_stats``."""
+    if not _mix_kernel_applies(x, mod):
+        return df.modulate_folded(x, mod)
+    from repro.kernels import fold_mix
+
+    _HOP_STATS["mix_kernel"] += layers
+    return fold_mix.modulate_folded(x, mod)
 
 
 def _quadrants(h: jax.Array, m: int, ndim: int) -> jax.Array:
@@ -808,7 +846,7 @@ class PropagationPlan:
             if fold:
                 new = self._hop(carry, tf, hops=hops, folded=True)
                 with stage("modulate"):
-                    new = df.modulate_folded(new, mod[0])
+                    new = _modulate_folded(new, mod[0], hops)
             elif frozen is not None:
                 new = self._modulate_frozen(
                     self._hop(carry, tf, spectral, hops=hops),

@@ -355,6 +355,19 @@ def activation_sharding(mesh: Mesh, rules=None):
         _ACTIVE.reset(token)
 
 
+def partitioned_by_compiler() -> bool:
+    """Whether the program being traced is one the compiler partitions over
+    several devices: a mesh of more than one device is active
+    (``activation_sharding``) and the trace is not inside a ``shard_map``
+    over all of its axes.  A Pallas kernel cannot be partitioned so."""
+    ctx = _ACTIVE.get()
+    if ctx is None or ctx[0].size == 1:
+        return False
+    am = jax.sharding.get_abstract_mesh()
+    return not (am.axis_names and all(t == jax.sharding.AxisType.Manual
+                                      for t in am.axis_types))
+
+
 def constrain(x, logical_axes: Sequence[Optional[str]],
               require: Optional[str] = None):
     """Apply a logical-axis sharding constraint if it resolves.

@@ -27,20 +27,29 @@ def _field(shape, seed=0):
             + 1j * rng.standard_normal(shape)).astype(np.complex64)
 
 
-# the forced packed hop, folded (``_folded_hop_applies``) and not
-FOLDS = pytest.mark.parametrize("fold", [True, False],
-                                ids=["folded", "unfolded"])
+# the forced packed hop, folded (``_folded_hop_applies``) and not, and
+# folded with each layer's mix as the one-pass kernel where it takes the
+# operands (``_mix_kernel_applies``; interpreted on a CPU)
+FOLDS = pytest.mark.parametrize("fold,kernel", [(True, False), (False, False),
+                                                (True, True)],
+                                ids=["folded", "unfolded", "folded-kernel"])
 
 
-def _on_both_paths(monkeypatch, fn, fold):
+def _force(m, fold, kernel=False):
+    m.setattr(pp, "_packed_hop_applies", lambda n: True)
+    m.setattr(pp, "_folded_hop_applies", lambda n: fold)
+    if kernel:
+        m.setattr(pp, "_mix_kernel_applies", pp._mix_kernel_takes)
+
+
+def _on_both_paths(monkeypatch, fn, fold, kernel=False):
     """``fn()`` on the FFT path, then with the packed path forced, folded
-    if ``fold``; the plan and executable caches are cleared around
-    each."""
+    if ``fold``, the mix kernel forced if ``kernel``; the plan and
+    executable caches are cleared around each."""
     clear_emulation_caches()
     want = fn()
     with monkeypatch.context() as m:
-        m.setattr(pp, "_packed_hop_applies", lambda n: True)
-        m.setattr(pp, "_folded_hop_applies", lambda n: fold)
+        _force(m, fold, kernel)
         clear_emulation_caches()
         got = fn()
     clear_emulation_caches()
@@ -106,7 +115,7 @@ def _tiny_inputs(cfg, b=4):
 
 
 @FOLDS
-def test_forced_plan_forward_equals_fft_path(monkeypatch, fold):
+def test_forced_plan_forward_equals_fft_path(monkeypatch, fold, kernel):
     cfg = DONNConfig(**TINY)
     phis, u = _tiny_inputs(cfg)
 
@@ -117,16 +126,18 @@ def test_forced_plan_forward_equals_fft_path(monkeypatch, fold):
         after = pp.hop_path_stats()
         return out, {k: after[k] - before[k] for k in after}
 
-    (got, hops), (want, _) = _on_both_paths(monkeypatch, run, fold)
+    (got, hops), (want, _) = _on_both_paths(monkeypatch, run, fold, kernel)
     packed = cfg.depth + 1
     assert hops == {"packed": packed, "fft": 0,
-                    "folded": packed if fold else 0}
+                    "folded": packed if fold else 0,
+                    "mix_kernel": cfg.depth if kernel else 0}
     _close(got, want)
 
 
 @pytest.mark.parametrize("plane_dtype", pp.PLANE_DTYPES)
 @FOLDS
-def test_forced_frozen_apply_equals_fft_path(monkeypatch, fold, plane_dtype):
+def test_forced_frozen_apply_equals_fft_path(monkeypatch, fold, kernel,
+                                            plane_dtype):
     cfg = DONNConfig(**TINY)
     phis, u = _tiny_inputs(cfg)
 
@@ -135,11 +146,12 @@ def test_forced_frozen_apply_equals_fft_path(monkeypatch, fold, plane_dtype):
         frozen = plan.frozen_modulation(phis, plane_dtype)
         return jax.jit(lambda v, f: plan.apply(None, v, frozen=f))(u, frozen)
 
-    _close(*_on_both_paths(monkeypatch, run, fold))
+    _close(*_on_both_paths(monkeypatch, run, fold, kernel))
 
 
 @FOLDS
-def test_forced_masked_ragged_apply_batch_equals_fft_path(monkeypatch, fold):
+def test_forced_masked_ragged_apply_batch_equals_fft_path(monkeypatch, fold,
+                                                         kernel):
     # depth-padded candidates: the second is a depth-2 stack whose middle
     # layer is masked to an identity hop
     cfg = DONNConfig(**TINY)
@@ -152,13 +164,14 @@ def test_forced_masked_ragged_apply_batch_equals_fft_path(monkeypatch, fold):
         return jax.jit(lambda p, m: plan.apply_batch(p, u, mask=m))(phis_k,
                                                                     mask)
 
-    got, want = _on_both_paths(monkeypatch, run, fold)
+    got, want = _on_both_paths(monkeypatch, run, fold, kernel)
     _close(got, want)
     assert not np.allclose(np.asarray(got[0]), np.asarray(got[1]))
 
 
 @FOLDS
-def test_forced_apply_batch_with_traced_tfs_equals_fft_path(monkeypatch, fold):
+def test_forced_apply_batch_with_traced_tfs_equals_fft_path(monkeypatch, fold,
+                                                           kernel):
     cfg = DONNConfig(**TINY)
     other = dataclasses.replace(cfg, distance=0.07)
     phis, u = _tiny_inputs(cfg)
@@ -171,32 +184,35 @@ def test_forced_apply_batch_with_traced_tfs_equals_fft_path(monkeypatch, fold):
         fn = jax.jit(lambda p, t: plans[0].apply_batch(p, u, tfs=t))
         return fn(phis_k, tfs)
 
-    _close(*_on_both_paths(monkeypatch, run, fold))
+    _close(*_on_both_paths(monkeypatch, run, fold, kernel))
 
 
 @FOLDS
-def test_forced_cached_apply_logits_equal_fft_path(monkeypatch, fold):
+def test_forced_cached_apply_logits_equal_fft_path(monkeypatch, fold, kernel):
     cfg = DONNConfig(**TINY)
     params = build_model(cfg).init(jax.random.PRNGKey(0))
     x = jnp.asarray(synth_digits(4, seed=0)[0])
     _close(*_on_both_paths(monkeypatch,
-                           lambda: cached_apply(cfg)(params, x), fold))
+                           lambda: cached_apply(cfg)(params, x), fold,
+                           kernel))
 
 
 @FOLDS
-def test_forced_rng_codesign_equals_fft_path(monkeypatch, fold):
+def test_forced_rng_codesign_equals_fft_path(monkeypatch, fold, kernel):
     # stochastic codesign: each layer draws from its own key of the split
     cfg = DONNConfig(**{**TINY, "codesign": "gumbel", "device_levels": 16})
     params = build_model(cfg).init(jax.random.PRNGKey(0))
     x = jnp.asarray(synth_digits(4, seed=0)[0])
     rng = jax.random.PRNGKey(7)
     _close(*_on_both_paths(monkeypatch,
-                           lambda: cached_apply(cfg)(params, x, rng), fold))
+                           lambda: cached_apply(cfg)(params, x, rng), fold,
+                           kernel))
 
 
 @pytest.mark.parametrize("remat", ["none", "layer"])
 @FOLDS
-def test_forced_qat_phase_gradients_equal_fft_path(monkeypatch, fold, remat):
+def test_forced_qat_phase_gradients_equal_fft_path(monkeypatch, fold, kernel,
+                                                    remat):
     cfg = DONNConfig(**{**TINY, "remat": remat})
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(1))
@@ -206,14 +222,14 @@ def test_forced_qat_phase_gradients_equal_fft_path(monkeypatch, fold, remat):
         loss = lambda p: jnp.sum(model.apply(p, x) ** 2)
         return jax.jit(jax.grad(loss))(params)
 
-    got, want = _on_both_paths(monkeypatch, run, fold)
+    got, want = _on_both_paths(monkeypatch, run, fold, kernel)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         _close(g, w, rtol=1e-4)
 
 
 @FOLDS
 def test_forced_multichannel_frozen_serving_equals_fft_path(monkeypatch,
-                                                           fold):
+                                                           fold, kernel):
     from repro.runtime.inference import InferenceEngine, freeze
 
     cfg = DONNConfig(**{**TINY, "channels": 3, "num_classes": 6})
@@ -224,7 +240,7 @@ def test_forced_multichannel_frozen_serving_equals_fft_path(monkeypatch,
     def run():
         return InferenceEngine(freeze(model, params), buckets=(2,)).infer(x)
 
-    _close(*_on_both_paths(monkeypatch, run, fold))
+    _close(*_on_both_paths(monkeypatch, run, fold, kernel))
 
 
 @pytest.mark.parametrize("extra,data", [
@@ -237,7 +253,8 @@ def test_forced_multichannel_frozen_serving_equals_fft_path(monkeypatch,
      synth_digits),
 ], ids=["rgb", "seg-skip", "fresnel-bf16-planes", "segmented"])
 @FOLDS
-def test_forced_families_equal_fft_path(monkeypatch, extra, data, fold):
+def test_forced_families_equal_fft_path(monkeypatch, extra, data, fold,
+                                       kernel):
     cfg = DONNConfig(**{**TINY, **extra})
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -245,12 +262,18 @@ def test_forced_families_equal_fft_path(monkeypatch, extra, data, fold):
     train = {"train": True} if cfg.segmentation else {}
 
     def run():
-        before = pp.hop_path_stats()["packed"]
+        before = pp.hop_path_stats()
         out = jax.jit(lambda p, v: model.apply(p, v, **train))(params, x)
-        return out, pp.hop_path_stats()["packed"] - before
+        after = pp.hop_path_stats()
+        return out, {k: after[k] - before[k] for k in after}
 
-    (got, packed), (want, unpacked) = _on_both_paths(monkeypatch, run, fold)
-    assert packed > 0 and unpacked == 0
+    (got, hops), (want, fft_hops) = _on_both_paths(monkeypatch, run, fold,
+                                                   kernel)
+    assert hops["packed"] > 0 and fft_hops["packed"] == 0
+    # a multi-channel (..., C, m, m) field keeps the jnp mix
+    kernel_layers = kernel and not cfg.channels > 1
+    assert (hops["mix_kernel"] > 0) == kernel_layers
+    assert fft_hops["mix_kernel"] == 0
     _close(got, want)
 
 
@@ -288,7 +311,7 @@ def test_other_hops_are_not_packable(extra):
 
 
 @FOLDS
-def test_hop_path_stats_counts_each_path(monkeypatch, fold):
+def test_hop_path_stats_counts_each_path(monkeypatch, fold, kernel):
     cfg = DONNConfig(**TINY)
     params = build_model(cfg).init(jax.random.PRNGKey(0))
     x = jnp.asarray(synth_digits(2, seed=0)[0])
@@ -300,16 +323,18 @@ def test_hop_path_stats_counts_each_path(monkeypatch, fold):
         after = pp.hop_path_stats()
         return {k: after[k] - before[k] for k in after}
 
-    fft, packed = _on_both_paths(monkeypatch, counted, fold)[::-1]
-    assert fft == {"packed": 0, "fft": hops, "folded": 0}
+    fft, packed = _on_both_paths(monkeypatch, counted, fold, kernel)[::-1]
+    assert fft == {"packed": 0, "fft": hops, "folded": 0, "mix_kernel": 0}
+    # the kernel mixes each modulated layer, not the final hop
     assert packed == {"packed": hops, "fft": 0,
-                      "folded": hops if fold else 0}
+                      "folded": hops if fold else 0,
+                      "mix_kernel": cfg.depth if kernel else 0}
     # a compiled program that runs again traces nothing
     with monkeypatch.context() as m:
-        m.setattr(pp, "_packed_hop_applies", lambda n: True)
-        m.setattr(pp, "_folded_hop_applies", lambda n: fold)
+        _force(m, fold, kernel)
         first = counted()
-        assert counted() == {"packed": 0, "fft": 0, "folded": 0}
+        assert counted() == {"packed": 0, "fft": 0, "folded": 0,
+                             "mix_kernel": 0}
     clear_emulation_caches()
     assert first == packed
 

@@ -16,14 +16,17 @@ TINY = dict(n=64, depth=3, distance=0.05, det_size=8, codesign="qat",
 # every stage the forward runs, on the FFT and on the folded hop alike
 JNP_STAGES = {"encode", "masks", "fft", "tf_mul", "ifft", "modulate",
               "readout"}
-# the FFT hop, and the packed hop forced to run folded
-HOPS = pytest.mark.parametrize("hop", ["fft", "folded"])
+# the FFT hop, the packed hop forced to run folded, and folded with each
+# layer's mix as the one-pass kernel (interpreted on a CPU)
+HOPS = pytest.mark.parametrize("hop", ["fft", "folded", "folded-kernel"])
 
 
 def _force(monkeypatch, hop):
-    if hop == "folded":
+    if hop.startswith("folded"):
         monkeypatch.setattr(pp, "_packed_hop_applies", lambda n: True)
         monkeypatch.setattr(pp, "_folded_hop_applies", lambda n: True)
+    if hop == "folded-kernel":
+        monkeypatch.setattr(pp, "_mix_kernel_applies", pp._mix_kernel_takes)
 
 
 def _images(b=4, seed=0):
@@ -115,6 +118,25 @@ def test_folded_hop_ops_map_to_fft_tf_mul_ifft(monkeypatch):
     # no new op lacks a stage: what does is the scan's own slicing, as on
     # the FFT path, or what the compiler made without a source op
     assert unscoped <= fft_unscoped | {None}
+
+
+def test_mix_kernel_ops_map_to_modulate(monkeypatch):
+    _force(monkeypatch, "folded-kernel")
+    cfg = DONNConfig(**TINY)
+    before = pp.hop_path_stats()["mix_kernel"]
+    stages, _, _ = _stages_of(cfg, _images())
+    assert pp.hop_path_stats()["mix_kernel"] - before == cfg.depth
+    assert stages == JNP_STAGES
+    smap = pp.stage_map()
+    kernel_stages = set()
+    for compiled in pp._EXEC_CACHE.values():
+        for module, name, opcode, op_name in _instructions(compiled.as_text()):
+            if (op_name and "fold_mix" in op_name
+                    and opcode not in pp._CONTAINER_OPS):
+                kernel_stages.add(smap.get((module, name)))
+    clear_emulation_caches()
+    # every op of the kernel (here its interpreted body) is the mix's
+    assert kernel_stages == {"modulate"}
 
 
 def test_segmented_plan_names_its_stitch():

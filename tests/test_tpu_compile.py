@@ -15,6 +15,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -116,6 +117,98 @@ def test_folded_modulation_compiles_for_v5e_without_a_matmul(one_chip, n):
     assert "reverse(" not in hlo
 
 
+def test_folded_forward_compiles_for_v5e_with_one_mix_kernel_a_layer(
+        one_chip, monkeypatch):
+    # the kernel is chosen as on a TPU (``default_backend()`` is the CPU
+    # here, where it would run interpreted)
+    from repro.kernels import fold_mix
+
+    n, depth = 500, 2
+    m = (n + 1) // 2
+    monkeypatch.setattr(pp, "_packed_hop_applies", lambda n: True)
+    monkeypatch.setattr(pp, "_mix_kernel_applies", pp._mix_kernel_takes)
+    monkeypatch.setattr(fold_mix, "_interpret", lambda: False)
+    plan = pp.PropagationPlan(df.Grid(n, 36e-6), [0.3] * (depth + 1),
+                              532e-9, gamma=1.05)
+    assert pp._folded_hop_applies(n)
+
+    def fn(phis, ur, ui):
+        return df.intensity(plan.apply(phis, jax.lax.complex(ur, ui)))
+
+    before = pp.hop_path_stats()["mix_kernel"]
+    hlo = _compiled_text(fn, one_chip, (depth, n, n), (BATCH, n, n),
+                         (BATCH, n, n))
+    assert pp.hop_path_stats()["mix_kernel"] - before == depth
+    assert all("operand_precision={highest,highest}" in line
+               for line in _matmuls(hlo))
+    # one Pallas call per modulated layer, under the modulate stage
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == depth
+    smap = pp.hlo_stage_map(hlo)
+    module = hlo.split()[1].rstrip(",")
+    for line in kernels:
+        assert "fold_mix" in line
+        name = pp._HLO_INSTR.match(line).group(1)
+        assert smap[(module, name)] == "modulate"
+    # between a layer's last inverse dot and the next forward dot the
+    # blocks go through the kernel alone: no loop pass of the program (the
+    # scan is unrolled at this depth) writes them, and nothing copies or
+    # transposes them
+    blocks = f"f32[4,2,{BATCH},{m},{m}]"
+    entry = hlo[hlo.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    assert "while(" not in entry
+    for line in entry.splitlines():
+        head = line.split(", metadata")[0]
+        if "kind=kLoop" in head:
+            assert blocks not in head.split(" fusion(")[0]
+        if re.search(r" (copy|transpose)\(", head):
+            assert blocks not in head
+
+
+@pytest.mark.parametrize("manual", [False, True],
+                         ids=["compiler-partitioned", "shard_map"])
+def test_folded_forward_over_four_chips_compiles_for_v5e(topo, monkeypatch,
+                                                        manual):
+    # a Pallas kernel cannot be partitioned by the compiler: a program
+    # sharded over the mesh (the train steps' activation_sharding) keeps
+    # the jnp mix, and a shard_map body runs the kernel on its shard
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.kernels import fold_mix
+    from repro.runtime import sharding as shd
+
+    n, depth = 400, 1
+    monkeypatch.setattr(pp, "_packed_hop_applies", lambda n: True)
+    monkeypatch.setattr(pp, "_mix_kernel_applies", pp._mix_kernel_takes)
+    monkeypatch.setattr(fold_mix, "_interpret", lambda: False)
+    plan = pp.PropagationPlan(df.Grid(n, 36e-6), [0.3] * (depth + 1),
+                              532e-9)
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
+    fwd = lambda p, ur, ui: df.intensity(plan.apply(p, jax.lax.complex(ur,
+                                                                       ui)))
+    if manual:
+        fwd = jax.shard_map(fwd, mesh=mesh,
+                            in_specs=(P(), P("data"), P("data")),
+                            out_specs=P("data"), check_vma=False)
+
+    def fn(p, ur, ui):
+        with shd.activation_sharding(mesh):
+            return fwd(p, ur, ui)
+
+    rows = NamedSharding(mesh, P("data"))
+    args = [jax.ShapeDtypeStruct((depth, n, n), jnp.float32,
+                                 sharding=NamedSharding(mesh, P()))] + [
+        jax.ShapeDtypeStruct((BATCH, n, n), jnp.float32, sharding=rows)
+    ] * 2
+    before = pp.hop_path_stats()["mix_kernel"]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    kernels = pp.hop_path_stats()["mix_kernel"] - before
+    assert kernels == (depth if manual else 0)
+    assert hlo.count('custom_call_target="tpu_custom_call"') == kernels
+
+
 @pytest.mark.parametrize("n", WIDTHS)
 def test_readout_compiles_for_v5e_at_highest_precision(one_chip, n):
     def fn(ur, ui, masks):
@@ -135,6 +228,8 @@ def test_phase_gradient_compiles_for_v5e_on_the_packed_hop(one_chip,
     # pair (XLA's TPU FFT lowers to convolutions of another shape)
     n, depth = 200, 2
     monkeypatch.setattr(pp, "_packed_hop_applies", lambda n: True)
+    # the mix kernel chosen as on a TPU: unfolded, no layer reaches it
+    monkeypatch.setattr(pp, "_mix_kernel_applies", pp._mix_kernel_takes)
     plan = pp.PropagationPlan(df.Grid(n, 36e-6), [0.3] * (depth + 1),
                               532e-9, gamma=1.12)
     assert not pp._folded_hop_applies(n)
@@ -143,8 +238,11 @@ def test_phase_gradient_compiles_for_v5e_on_the_packed_hop(one_chip,
         return jnp.sum(df.intensity(plan.apply(phis,
                                                jax.lax.complex(ur, ui))))
 
+    before = pp.hop_path_stats()["mix_kernel"]
     hlo = _compiled_text(jax.grad(loss), one_chip, (depth, n, n),
                          (BATCH, n, n), (BATCH, n, n))
+    assert pp.hop_path_stats()["mix_kernel"] == before
+    assert "tpu_custom_call" not in hlo
     matmuls = _matmuls(hlo)
     # four stages a hop: depth + 1 forward hops, and the adjoints of all
     # but the first (the phases' gradient needs no hop before the first
